@@ -30,24 +30,6 @@ import jax as _jax
 
 _jax.config.update("jax_enable_x64", True)
 
-# Persistent compilation cache: a SQL engine re-JITs the same operator
-# kernels in every process; first-compile on TPU is tens of seconds.
-import os as _os
-
-_plats = str(getattr(_jax.config, "jax_platforms", None)
-             or _os.environ.get("JAX_PLATFORMS", "") or "")
-if "cpu" in _plats.split(","):
-    # NO persistent cache on the CPU simulator: XLA:CPU executable
-    # serialization (the AOT path the cache uses) embeds host machine
-    # features and has SIGSEGV'd in both serialize and deserialize on
-    # this image; CPU compiles are cheap enough to redo per process.
-    pass
-elif not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-    _cache = f"/tmp/spark_rapids_tpu_jit_cache_{_os.getuid()}"
-    _os.makedirs(_cache, exist_ok=True)
-    _jax.config.update("jax_compilation_cache_dir", _cache)
-    _jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-
 from spark_rapids_tpu.config import RapidsConf, conf  # noqa: F401
 from spark_rapids_tpu.types import (  # noqa: F401
     DataType, BooleanType, Int8Type, Int16Type, Int32Type, Int64Type,

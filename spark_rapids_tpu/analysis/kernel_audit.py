@@ -1,8 +1,8 @@
 """Kernel cost auditor: per-dispatch FLOPs/bytes accounting at trace time.
 
-BENCH_r04 put the engine at ~1% of the HBM roofline on its wins and
-~0.05% on its losses, and nothing in the system could say WHY: the
-trace/attribution layer (PR 9) decomposes wall time, but no surface
+The engine's hand-estimated bandwidth sat far below the HBM roofline and
+nothing in the system could say WHY: the trace/attribution layer (PR 9)
+decomposes wall time, but no surface
 knew how many bytes or FLOPs a dispatch actually moves, whether a
 kernel is bandwidth-, compute- or overhead-bound, or how many bytes the
 shape-bucket ladder (PR 10) wastes as padding. This module is the
@@ -182,6 +182,17 @@ def enabled() -> bool:
     return _ENABLED
 
 
+def device_is_v5e() -> bool:
+    """Whether the default device is a TPU v5e, the one device whose
+    peaks this repo carries (819 GB/s HBM, 197 TFLOP/s bf16 — the
+    spark.rapids.obs.audit.peak* defaults). A SHARE of those peaks is
+    reported only there; anywhere else it is None, never a v5e roofline
+    of another device's seconds."""
+    import jax
+    kind = jax.devices()[0].device_kind.lower()
+    return "v5 lite" in kind or "v5e" in kind
+
+
 def configure(conf) -> None:
     """Apply the session conf (called from prepare_execution, the
     faults.from_conf slot): arm/disarm the audit and publish the
@@ -328,17 +339,19 @@ def _sds_of(leaf):
     return leaf  # static leaves replay as themselves
 
 
-def _observe_trace(entry_key: Tuple, jfn_box: dict, args, kwargs) -> None:
+def _observe_trace(entry_key: Tuple, jfn_box: dict, args, kwargs) -> bool:
     """The trace-time body of both wrappers: dedupe by shape signature,
     record input plane bytes + row capacity, queue the deferred
-    resolution. Runs ONLY while jax traces (or re-traces) the entry."""
+    resolution. Runs ONLY while jax traces (or re-traces) the entry.
+    Returns True when the shape is NEW to the audit (False for a
+    re-trace of an audited shape, e.g. after jax dropped its caches)."""
     import jax
     leaves = jax.tree_util.tree_leaves((args, kwargs))
     sig = tuple(_leaf_sig(x) for x in leaves)
     with _LOCK:
         shapes = _RECORDS.setdefault(entry_key, {})
         if sig in shapes:
-            return
+            return False
         rec = {
             "in_bytes": sum(_leaf_bytes(x) for x in leaves),
             "row_capacity": max([_leading_dim(x) for x in leaves] or [0]),
@@ -348,10 +361,11 @@ def _observe_trace(entry_key: Tuple, jfn_box: dict, args, kwargs) -> None:
         shapes[sig] = rec
         _STATS["audited_shapes"] += 1
         if getattr(_TLS, "resolving", 0):
-            return  # a lowering replay re-traced the body: the record
-            # exists for dedup, but resolution is already in flight
+            return True  # a lowering replay re-traced the body: the
+            # record exists for dedup, but resolution is already in flight
         sds = jax.tree_util.tree_map(_sds_of, (args, kwargs))
         _PENDING.append((entry_key, sig, jfn_box, sds[0], sds[1]))
+    return True
 
 
 def wrap_traced(exec_class: str, key: Tuple, fp: Tuple,
@@ -399,8 +413,11 @@ def wrap_kernel(fn: Callable) -> Tuple[Callable, Callable]:
     def traced(*args, **kwargs):
         if _ENABLED:
             try:
-                _observe_trace(entry_key, jfn_box, args, kwargs)
-                _note_kernel_trace(entry_key)
+                # one observation per audited SHAPE: a re-trace of a
+                # shape already on record (jax evicted or dropped its
+                # trace cache) is not credited again
+                if _observe_trace(entry_key, jfn_box, args, kwargs):
+                    _note_kernel_trace(entry_key)
             except Exception as e:  # noqa: BLE001 - the audit must
                 # never fail a trace
                 _finding(f"trace observation failed for "
@@ -811,6 +828,7 @@ def roofline(summary: Optional[dict], snaps: Optional[Dict[str, dict]],
     # it identically so the roofline denominator matches the
     # attribution bucket by construction
     _attr.subtract_compile(bucket_ns, (extra or {}).get("compile", 0))
+    shares = device_is_v5e()
     groups = {}
     for gname in ("device_compute", "shuffle"):
         gbytes = gflops = gin = gdisp = gwaste = 0.0
@@ -846,10 +864,10 @@ def roofline(summary: Optional[dict], snaps: Optional[Dict[str, dict]],
             "achieved_gflops": round(achieved_gflops, 4),
             "roofline_pct_bw": round(100.0 * achieved_gbps
                                      / _PEAK_GBPS, 4)
-            if _PEAK_GBPS else None,
+            if shares and _PEAK_GBPS else None,
             "roofline_pct_flops": round(100.0 * achieved_gflops
                                         / _PEAK_GFLOPS, 4)
-            if _PEAK_GFLOPS else None,
+            if shares and _PEAK_GFLOPS else None,
             "bound": bound,
             "padding_waste_ratio": round(gwaste / gin, 4)
             if gin else 0.0,
@@ -872,7 +890,7 @@ def roofline(summary: Optional[dict], snaps: Optional[Dict[str, dict]],
             if tot_secs > 0 else 0.0,
             "roofline_pct_bw": round(100.0 * tot_bytes / tot_secs / 1e9
                                      / _PEAK_GBPS, 4)
-            if tot_secs > 0 and _PEAK_GBPS else 0.0,
+            if shares and tot_secs > 0 and _PEAK_GBPS else None,
         },
         "kernels": {family: {
             "bucket": family_bucket(family),
@@ -895,6 +913,11 @@ def roofline(summary: Optional[dict], snaps: Optional[Dict[str, dict]],
     return doc
 
 
+def _pct_text(pct: Optional[float]) -> str:
+    """A roofline share, or n/a off the v5e (device_is_v5e)."""
+    return "   n/a" if pct is None else f"{pct:>6.3f}%"
+
+
 def render_text(doc: Optional[dict], width: int = 24) -> List[str]:
     """Roofline lines for explain(mode="analyze"), the render_text
     pattern of attribution."""
@@ -904,12 +927,12 @@ def render_text(doc: Optional[dict], width: int = 24) -> List[str]:
              f"{doc['peak_gflops']:g} GFLOP/s) --"]
     for gname in sorted(doc.get("groups", {})):
         g = doc["groups"][gname]
-        pct = g.get("roofline_pct_bw") or 0.0
+        pct = g.get("roofline_pct_bw")
         bar = "#" * max(1, int(min(pct, 100.0) / 100.0 * width)) \
-            if pct > 0 else ""
+            if pct else ""
         lines.append(
             f"  {gname:<15} {g['seconds']:>8.3f}s "
-            f"{g['achieved_gbps']:>9.2f} GB/s ({pct:>6.3f}% roofline) "
+            f"{g['achieved_gbps']:>9.2f} GB/s ({_pct_text(pct)} roofline) "
             f"{g['achieved_gflops']:>9.2f} GFLOP/s  {g['bound']}-bound"
             f"  waste<={g['padding_waste_ratio'] * 100:.0f}%"
             + (f"  {bar}" if bar else ""))
@@ -918,7 +941,7 @@ def render_text(doc: Optional[dict], width: int = 24) -> List[str]:
         lines.append(
             f"  {'total':<15} {t['seconds']:>8.3f}s "
             f"{t['achieved_gbps']:>9.2f} GB/s "
-            f"({t['roofline_pct_bw']:>6.3f}% roofline) "
+            f"({_pct_text(t['roofline_pct_bw'])} roofline) "
             f"over {sum(g['dispatches'] for g in doc['groups'].values())}"
             f" audited dispatches")
     sh = doc.get("shards")

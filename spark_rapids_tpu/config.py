@@ -825,16 +825,18 @@ PIPELINE_DEPTH = conf_int(
 
 COMPILE_CACHE_DIR = conf_str(
     "spark.rapids.compile.cacheDir", "",
-    "When set, enable jax's persistent compilation cache in this "
-    "directory (jax_compilation_cache_dir with the minimum-entry "
-    "thresholds zeroed): compiled XLA executables are reused ACROSS "
-    "processes, so a restarted engine pays trace + deserialize instead "
-    "of a full backend compile on its first run of a known computation. "
-    "Process-global — the first session to configure it wins (jax "
-    "config is global); tools/compile_smoke.py CI-gates that the "
-    "cross-process hits actually happen. Empty disables the persistent "
-    "layer (the in-process warm-trace cache in runtime/compile_cache.py "
-    "is always on).", commonly_used=True)
+    "Directory of jax's persistent compilation cache (entry thresholds "
+    "zeroed): compiled XLA executables are reused ACROSS processes, so "
+    "a restarted engine pays trace + deserialize instead of a full "
+    "backend compile on its first run of a known computation. "
+    "JAX_COMPILATION_CACHE_DIR, when the environment sets it, wins over "
+    "this conf (logged once). Empty means the default: a fixed "
+    ".jax_cache directory inside the checkout on an accelerator, no "
+    "persistent layer on the CPU simulator. Process-global — the first "
+    "session to place it wins (jax config is global); "
+    "tools/compile_smoke.py CI-gates that the cross-process hits "
+    "actually happen. The in-process warm-trace cache in "
+    "runtime/compile_cache.py is always on.", commonly_used=True)
 
 COMPILE_WARMUP_ENABLED = conf_bool(
     "spark.rapids.compile.warmup.enabled", False,

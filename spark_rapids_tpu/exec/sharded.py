@@ -48,12 +48,8 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
 
 from spark_rapids_tpu import config as C
 from spark_rapids_tpu import types as T
@@ -64,6 +60,7 @@ from spark_rapids_tpu.exec.stage_fusion import (_ReplaySourceExec,
                                                 fused_stage_cls)
 from spark_rapids_tpu.parallel import mesh as MESH
 from spark_rapids_tpu.runtime import metrics as M
+from spark_rapids_tpu.runtime import obs as OBS
 from spark_rapids_tpu.runtime import trace as TR
 
 log = logging.getLogger("spark_rapids_tpu")
@@ -346,6 +343,7 @@ def make_sharded_stage_exec():
                         # re-execute the source (stage_fusion fallback
                         # discipline, lifted one level)
                         self._failed = True
+                        OBS.note_exec_fallback("sharded_stage")
                         log.warning(
                             "sharded stage trace failed for %s; falling "
                             "back to the single-device fused path",

@@ -53,17 +53,15 @@ def enabled() -> bool:
 
 
 def _interpret() -> bool:
+    """Every pallas_call site's interpret flag: Mosaic on the chip, the
+    Pallas interpreter on the CPU simulator (the suite's differential
+    check). A chip run asserts this is False (chip_smoke.py)."""
     return jax.default_backend() != "tpu"
 
 
 def _x64_off():
-    """Context manager tracing in 32-bit mode. `jax.enable_x64` is only
-    public API on newer jax; older builds (this container's 0.4.x) spell
-    it jax.experimental.enable_x64."""
-    ctx = getattr(jax, "enable_x64", None)
-    if ctx is None:
-        from jax.experimental import enable_x64 as ctx
-    return ctx(False)
+    """Context manager tracing in 32-bit mode."""
+    return jax.enable_x64(False)
 
 
 def pallas_supported(n: int) -> bool:

@@ -43,10 +43,6 @@ MAX_GROUP_ROWS = 1 << 16
 SHIFTS = (40, 32, 24, 16, 8, 0)
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _kernel_factory(P: int):
     from jax.experimental import pallas as pl
 
@@ -99,7 +95,7 @@ def segsum_window(gid: jax.Array, payload: jax.Array, outcap: int
     assert n % TILE == 0 and outcap % (2 * TILE) == 0, (n, outcap)
     T = n // TILE
     bases = jnp.clip(gid[::TILE] // TILE, 0, outcap // TILE - 2)
-    from spark_rapids_tpu.ops.pallas_kernels import _x64_off
+    from spark_rapids_tpu.ops.pallas_kernels import _interpret, _x64_off
     with _x64_off():
         lo, hi = pl.pallas_call(
             _kernel_factory(P),

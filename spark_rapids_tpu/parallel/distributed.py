@@ -16,13 +16,8 @@ from typing import Callable, Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 from spark_rapids_tpu.parallel import exchange as X
 from spark_rapids_tpu.runtime import compile_cache as _cc

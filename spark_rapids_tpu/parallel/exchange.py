@@ -28,16 +28,6 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def _axis_size(a) -> int:
-    """Mesh axis size inside a shard_map trace. `lax.axis_size` is only
-    public API on newer jax; on older builds (this container's 0.4.x)
-    `lax.psum(1, axis)` constant-folds to the same static int."""
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(a)
-    return lax.psum(1, a)
-
-
 def route_rows(target: jax.Array, valid: jax.Array, num_parts: int
                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Compute the scatter layout sending each row to `target` partition.
@@ -75,7 +65,7 @@ def all_to_all_exchange(planes: Dict[str, jax.Array], valid: jax.Array,
         axis_names = (axis_names,)
     P = 1
     for a in axis_names:
-        P *= _axis_size(a)
+        P *= lax.axis_size(a)
     n = valid.shape[0]
     C = int(send_cap) if send_cap else n
     order, row_idx, col_idx = route_rows(target, valid, P)

@@ -32,11 +32,14 @@ host_pool.py. Three layers, cheapest first:
    counts the persistent compilation cache's cross-process hits and
    misses, which ``tools/compile_smoke.py`` CI-gates.
 
-The persistent layer (``spark.rapids.compile.cacheDir`` ->
-``jax_compilation_cache_dir``) makes compiled executables survive the
-process: a restarted engine pays trace + deserialize, not a backend
-compile. jax config is process-global, so the first session to
-configure it wins.
+The persistent layer (jax's compilation cache) makes compiled
+executables survive the process: a restarted engine pays trace +
+deserialize, not a backend compile. ``configure`` is the ONE place that
+decides its directory and entry thresholds: ``JAX_COMPILATION_CACHE_DIR``
+when the environment sets it (then no directory is set in code), else
+``spark.rapids.compile.cacheDir``, else a fixed ``.jax_cache`` inside
+the checkout (no default on the CPU simulator). jax config is process-global,
+so the first session to configure it wins.
 
 Pallas kernels are not jit entries — ``pl.pallas_call`` lowers inside an
 enclosing traced computation — so they cannot route through ``get``;
@@ -46,6 +49,8 @@ TPU-L010 flags the call anywhere else.
 """
 from __future__ import annotations
 
+import logging
+import os
 import threading
 import time
 from typing import Callable, Dict, Optional, Tuple
@@ -88,7 +93,18 @@ _STATS = {
 _TLS = threading.local()
 
 _MONITORING_INSTALLED = False
-_PERSISTENT_DIR: Optional[str] = None
+#: the directory ``configure`` placed the persistent cache at (None
+#: until a session places one — on the CPU simulator, possibly never)
+_PLACED: Optional[str] = None
+#: conf directories already reported as ignored (log once each)
+_IGNORED: set = set()
+
+#: the default persistent-cache directory: a FIXED path inside the
+#: checkout (the directory is part of what a chip-tool copy carries and
+#: must not move between processes) — never /tmp, a uid, a pid or a time
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 #: the kernel cost auditor (analysis/kernel_audit.py) when armed, else
 #: None: get() notes every keyed resolution (one call per dispatch) and
@@ -269,7 +285,9 @@ def stats() -> Dict[str, int]:
     compile document and the smoke gates read this)."""
     out = dict(_STATS)
     out["entries"] = len(_CACHE)
-    out["persistent_dir"] = _PERSISTENT_DIR
+    # the directory jax is ACTUALLY using (environment, conf or the
+    # default), not only one this module set
+    out["persistent_dir"] = jax.config.jax_compilation_cache_dir or None
     return out
 
 
@@ -370,44 +388,62 @@ def _install_monitoring() -> None:
     with _LOCK:
         if _MONITORING_INSTALLED:
             return
-        try:
-            jax.monitoring.register_event_duration_secs_listener(
-                _on_compile_duration)
-            jax.monitoring.register_event_listener(_on_cache_event)
-        except Exception:  # noqa: BLE001 - an older jax without
-            pass  # monitoring still gets the keyed-layer counters
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_duration)
+        jax.monitoring.register_event_listener(_on_cache_event)
         _MONITORING_INSTALLED = True
 
 
 _install_monitoring()
 
 
+def _on_cpu_simulator() -> bool:
+    """True when jax's default backend is the CPU. Asks jax itself (this
+    initialises the backend, which a session is about to use anyway): a
+    platform list such as "tpu,cpu" names the CPU without running on
+    it."""
+    return jax.default_backend() == "cpu"
+
+
 def configure(conf) -> None:
-    """Apply the session's persistent-cache conf (idempotent; called
-    from TpuSession.prepare_execution). jax config is process-global:
-    the first configured directory wins, and later sessions naming a
-    DIFFERENT directory keep the first (logged once)."""
-    global _PERSISTENT_DIR
+    """Place the persistent compilation cache (idempotent; called from
+    every TpuSession.__init__) — the ONE site that decides its directory
+    and entry thresholds. Precedence: ``JAX_COMPILATION_CACHE_DIR`` (jax
+    reads it itself; no directory is set in code and
+    ``spark.rapids.compile.cacheDir`` only logs that the environment
+    wins), then the conf, then DEFAULT_CACHE_DIR. jax config is
+    process-global: the first placement wins, and a later session naming
+    a DIFFERENT directory keeps the first (logged once)."""
+    global _PLACED
     from spark_rapids_tpu import config as C
+    log = logging.getLogger("spark_rapids_tpu")
     d = str(conf.get(C.COMPILE_CACHE_DIR) or "").strip()
-    if not d:
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+    if d and d != (env or _PLACED or d) and d not in _IGNORED:
+        _IGNORED.add(d)
+        log.warning(
+            "spark.rapids.compile.cacheDir=%s ignored: %s", d,
+            f"JAX_COMPILATION_CACHE_DIR={env} wins" if env else
+            f"the process persistent cache is already {_PLACED}")
+    if _PLACED:
         return
-    if _PERSISTENT_DIR is not None:
-        if d != _PERSISTENT_DIR:
-            import logging
-            logging.getLogger("spark_rapids_tpu").warning(
-                "spark.rapids.compile.cacheDir=%s ignored: the process "
-                "persistent cache is already %s", d, _PERSISTENT_DIR)
-        return
-    import os
-    os.makedirs(d, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", d)
-    # the engine's computations are many and individually small: cache
-    # everything (the defaults skip sub-second / sub-size entries,
-    # which is most of an analytic plan's kernel zoo)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    _PERSISTENT_DIR = d
+    if env:
+        d = env
+    elif not d and not _on_cpu_simulator():
+        d = DEFAULT_CACHE_DIR
+    # no default on the CPU simulator: the suite's compile-count
+    # assertions need every process to start cold, and CPU compiles are
+    # cheap enough to redo (an explicit conf or env dir still applies)
+    if d:
+        if not env:
+            os.makedirs(d, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", d)
+        # the engine's computations are many and individually small:
+        # cache everything (jax's defaults skip sub-second / sub-size
+        # entries, which is most of an analytic plan's kernel zoo)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        _PLACED = d
 
 
 def doc() -> Dict[str, object]:

@@ -480,14 +480,18 @@ def _device_budget_from(conf) -> int:
     runtime can report; budgetBytes remains the explicit ceiling."""
     budget = conf.get(C.DEVICE_MEMORY_BUDGET)
     frac = conf.get(C.DEVICE_MEMORY_FRACTION)
-    try:
-        import jax
-        stats = jax.devices()[0].memory_stats() or {}
-        total = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
-        if total:
-            budget = min(budget, int(total * frac))
-    except Exception:  # noqa: BLE001 - stats unavailable on some backends
-        pass
+    import jax
+    dev = jax.devices()[0]
+    stats = dev.memory_stats() or {}
+    total = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
+    if total:
+        budget = min(budget, int(total * frac))
+    elif dev.platform == "tpu":
+        # the CPU simulator reports no HBM; a chip that cannot say how
+        # much it has must not silently run under the constant
+        raise RuntimeError(
+            f"{dev.device_kind}: memory_stats() reports no HBM limit "
+            f"({sorted(stats)}); cannot size the device memory budget")
     return budget
 
 

@@ -112,6 +112,62 @@ _TLS = threading.local()
 NESTED = "nested"
 
 
+def _count(name: str, help_: str, labels: Dict[str, str]) -> None:
+    st = _STATE
+    if st is not None:
+        st.registry.counter(name, help_, labels=labels).inc()
+
+
+def _counts(name: str, label: str, roster) -> Dict[str, int]:
+    """Per-label values of a rostered counter (zeros with obs off)."""
+    st = _STATE
+    return {v: 0 if st is None else st.registry.counter(
+        name, labels={label: v}).value for v in roster}
+
+
+#: the per-stage catch-and-replay sites: a stage whose trace, compile or
+#: setup failed keeps the query correct on a slower path (unfused chain,
+#: single-device stage, synchronous pipeline) — counted here so a run on
+#: real hardware can FAIL on a dead fast path instead of reading logs
+EXEC_FALLBACK_SITES = ("fused_stage", "absorbed_chain", "sharded_stage",
+                       "pipeline")
+_EXEC_FALLBACK_HELP = ("Stages that fell back to their slower replay path "
+                       "after a trace/compile/setup failure")
+
+
+def note_exec_fallback(site: str) -> None:
+    """Count one catch-and-replay fallback at `site`."""
+    _count("rapids_stage_fallbacks_total", _EXEC_FALLBACK_HELP,
+           {"site": site})
+
+
+def exec_fallbacks() -> int:
+    """Fallbacks counted so far across every site (0 with obs off)."""
+    return sum(_counts("rapids_stage_fallbacks_total", "site",
+                       EXEC_FALLBACK_SITES).values())
+
+
+#: which Pallas sorted-window aggregation path a traced group-by took
+#: (exec/tpu_nodes._AggKernels._bucket_scatter_agg): the eligibility
+#: gates are data-dependent, so only a run says whether a query's shapes
+#: ever reach the kernel. Counted at TRACE time — zero per-dispatch cost
+PALLAS_SEGSUM_PATHS = ("whole", "chunked")
+_PALLAS_SEGSUM_HELP = ("Group-by traces routed into the Pallas sorted-"
+                       "window segmented sum, by path")
+
+
+def note_pallas_segsum(path: str) -> None:
+    """Count one group-by trace that took the Pallas segsum `path`."""
+    _count("rapids_pallas_segsum_traces_total", _PALLAS_SEGSUM_HELP,
+           {"path": path})
+
+
+def pallas_segsum_traces() -> Dict[str, int]:
+    """Per-path trace counts so far (zeros with obs off)."""
+    return _counts("rapids_pallas_segsum_traces_total", "path",
+                   PALLAS_SEGSUM_PATHS)
+
+
 def _preregister(reg: MetricsRegistry) -> None:
     """Create the roster instruments up front so a scrape before the
     first task/query still renders them (at zero) — an empty /metrics
@@ -131,6 +187,12 @@ def _preregister(reg: MetricsRegistry) -> None:
                 "(spark.rapids.query.maxConcurrent)")
     reg.counter("rapids_faults_injected_total",
                 "Injected faults fired (spark.rapids.debug.faults)")
+    for site in EXEC_FALLBACK_SITES:
+        reg.counter("rapids_stage_fallbacks_total", _EXEC_FALLBACK_HELP,
+                    labels={"site": site})
+    for path in PALLAS_SEGSUM_PATHS:
+        reg.counter("rapids_pallas_segsum_traces_total",
+                    _PALLAS_SEGSUM_HELP, labels={"path": path})
     reg.counter("rapids_watchdog_dispatch_timeouts_total",
                 "Device dispatches that exceeded the watchdog deadline")
     reg.counter("rapids_breaker_transitions_total",
@@ -575,7 +637,7 @@ def on_query_end(token, *, session, plan, status: str,
                 reg.gauge("rapids_roofline_achieved_gbps", labels=lbl
                           ).set(g.get("achieved_gbps") or 0.0)
                 reg.gauge("rapids_roofline_pct", labels=lbl
-                          ).set(g.get("roofline_pct_bw") or 0.0)
+                          ).set(_share(g.get("roofline_pct_bw")))
                 reg.gauge("rapids_roofline_achieved_gflops", labels=lbl
                           ).set(g.get("achieved_gflops") or 0.0)
                 reg.gauge("rapids_roofline_padding_waste_ratio",
@@ -586,7 +648,7 @@ def on_query_end(token, *, session, plan, status: str,
                       labels={"group": "total"}
                       ).set(tot.get("achieved_gbps") or 0.0)
             reg.gauge("rapids_roofline_pct", labels={"group": "total"}
-                      ).set(tot.get("roofline_pct_bw") or 0.0)
+                      ).set(_share(tot.get("roofline_pct_bw")))
         digest = None
         try:
             digest = plan_digest(plan)
@@ -683,6 +745,12 @@ def on_query_end(token, *, session, plan, status: str,
     finally:
         with st._lock:
             st._active -= 1
+
+
+def _share(pct) -> float:
+    """A roofline share as a gauge value: NaN where the doc carries
+    None (a device whose peaks are unknown), never a made-up 0."""
+    return float("nan") if pct is None else pct
 
 
 def _publish_exec_rollups(reg: MetricsRegistry, snaps: Dict[str, dict]

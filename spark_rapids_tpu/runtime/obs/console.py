@@ -149,14 +149,16 @@ def render_console(queries_doc: dict,
             "<th class='num'>padding waste &le;</th></tr>")
         for gname in sorted(roofline["groups"]):
             g = roofline["groups"][gname]
-            pct = g.get("roofline_pct_bw") or 0.0
+            pct = g.get("roofline_pct_bw")
+            pct_txt = "n/a" if pct is None else f"{pct:.3f}%"
+            pct = pct or 0.0
             body.append(
                 f"<tr><td>{_esc(gname)}</td>"
                 f"<td class='num'>{g.get('seconds', 0):.3f}</td>"
                 f"<td class='num'>{g.get('achieved_gbps', 0):.2f}</td>"
                 f"<td class='num'><span class='pbar'><span "
                 f"style='width:{min(pct, 100):.1f}%'></span></span> "
-                f"{pct:.3f}%</td>"
+                f"{pct_txt}</td>"
                 f"<td class='num'>{g.get('achieved_gflops', 0):.2f}</td>"
                 f"<td>{_esc(g.get('bound', ''))}</td>"
                 f"<td class='num'>"

@@ -395,6 +395,8 @@ def make_pipeline_exec():
             except Exception:  # noqa: BLE001 - per-stage fallback: a
                 # pipeline setup failure must degrade to the synchronous
                 # path, never fail the query
+                from spark_rapids_tpu.runtime import obs as _obs
+                _obs.note_exec_fallback("pipeline")
                 log.warning("pipeline setup failed for %s; running "
                             "synchronously", self.name(), exc_info=True)
                 depth_m.set(0)

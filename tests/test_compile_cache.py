@@ -182,6 +182,114 @@ def test_healthz_compile_document():
 
 
 # ---------------------------------------------------------------------------
+# placing the persistent cache: ONE site, the environment wins
+# ---------------------------------------------------------------------------
+
+_CACHE_OPTS = ("jax_compilation_cache_dir",
+               "jax_persistent_cache_min_compile_time_secs",
+               "jax_persistent_cache_min_entry_size_bytes")
+
+
+@pytest.fixture
+def cache_placement(monkeypatch):
+    """An unplaced persistent cache on a pretend accelerator, every
+    jax.config.update recorded, and jax's cache options put back as they
+    were (no compile happens while a test holds a directory, so nothing
+    is ever written)."""
+    import jax
+    saved = {k: getattr(jax.config, k) for k in _CACHE_OPTS}
+    monkeypatch.setattr(CC, "_PLACED", None)
+    monkeypatch.setattr(CC, "_IGNORED", set())
+    monkeypatch.setattr(CC, "_on_cpu_simulator", lambda: False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    calls = []
+    real = jax.config.update
+
+    def recording(name, value):
+        calls.append((name, value))
+        return real(name, value)
+
+    monkeypatch.setattr(jax.config, "update", recording)
+    had_default = os.path.isdir(CC.DEFAULT_CACHE_DIR)
+    yield calls
+    for k, v in saved.items():
+        real(k, v)
+    if not had_default and os.path.isdir(CC.DEFAULT_CACHE_DIR):
+        os.rmdir(CC.DEFAULT_CACHE_DIR)  # placed, never written to
+
+
+def test_env_cache_dir_wins_and_no_directory_is_set_in_code(
+        cache_placement, monkeypatch, tmp_path, caplog):
+    env_dir = str(tmp_path / "from_env")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    with caplog.at_level("WARNING", logger="spark_rapids_tpu"):
+        TpuSession({"spark.rapids.compile.cacheDir":
+                    str(tmp_path / "from_conf")})
+        TpuSession({"spark.rapids.compile.cacheDir":
+                    str(tmp_path / "from_conf")})
+        TpuSession()
+    set_opts = dict(cache_placement)
+    assert "jax_compilation_cache_dir" not in set_opts
+    assert not (tmp_path / "from_conf").exists()
+    # the entry thresholds are set in the same place in this case too
+    assert set_opts["jax_persistent_cache_min_compile_time_secs"] == 0.0
+    assert set_opts["jax_persistent_cache_min_entry_size_bytes"] == -1
+    assert CC._PLACED == env_dir
+    ignored = [r for r in caplog.records
+               if "JAX_COMPILATION_CACHE_DIR" in r.getMessage()]
+    assert len(ignored) == 1  # logged once, not per session
+
+
+def test_default_cache_dir_is_fixed_inside_the_checkout(cache_placement):
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert CC.DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    TpuSession()
+    assert ("jax_compilation_cache_dir", CC.DEFAULT_CACHE_DIR) \
+        in cache_placement
+    assert CC.stats()["persistent_dir"] == CC.DEFAULT_CACHE_DIR
+    assert CC.doc()["persistent_dir"] == CC.DEFAULT_CACHE_DIR
+    set_opts = dict(cache_placement)
+    assert set_opts["jax_persistent_cache_min_compile_time_secs"] == 0.0
+    assert set_opts["jax_persistent_cache_min_entry_size_bytes"] == -1
+
+
+def test_conf_cache_dir_applies_when_env_is_unset(cache_placement,
+                                                   tmp_path):
+    d = str(tmp_path / "from_conf")
+    TpuSession({"spark.rapids.compile.cacheDir": d})
+    assert ("jax_compilation_cache_dir", d) in cache_placement
+    assert CC.stats()["persistent_dir"] == d
+    # first placement wins: a later default session changes nothing
+    del cache_placement[:]
+    TpuSession()
+    assert cache_placement == []
+
+
+def test_cpu_simulator_keeps_no_default_cache(cache_placement,
+                                              monkeypatch):
+    monkeypatch.setattr(CC, "_on_cpu_simulator", lambda: True)
+    TpuSession()
+    assert cache_placement == [] and CC._PLACED is None
+    assert CC.stats()["persistent_dir"] is None
+
+
+def test_nothing_else_sets_the_cache_directory():
+    """The package's only jax_compilation_cache_dir write is in
+    compile_cache.configure; __init__ places nothing."""
+    pkg = os.path.dirname(os.path.abspath(CC.__file__))
+    pkg = os.path.dirname(pkg)
+    hits = []
+    for root, _dirs, files in os.walk(pkg):
+        for f in files:
+            if f.endswith(".py"):
+                path = os.path.join(root, f)
+                with open(path) as fh:
+                    if 'update("jax_compilation_cache_dir"' in fh.read():
+                        hits.append(os.path.relpath(path, pkg))
+    assert hits == [os.path.join("runtime", "compile_cache.py")]
+
+
+# ---------------------------------------------------------------------------
 # bucket-padding correctness: fused/unfused parity at boundary shapes
 # ---------------------------------------------------------------------------
 

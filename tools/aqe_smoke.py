@@ -24,6 +24,9 @@ Three gates, CI-blocking (tools/ci_check.sh):
 
 Run:  python tools/aqe_smoke.py [--rows 60000] [--reps 5]
                                 [--tolerance 0.02]
+
+CPU gate: runs on the CPU backend (JAX_PLATFORMS defaults to cpu here);
+no time it prints is a measurement of the chip.
 """
 from __future__ import annotations
 

@@ -32,6 +32,9 @@ Four gates:
 
 --quick replaces the full golden replay with a prefix replay against
 the committed file (for local iteration; CI runs full).
+
+CPU gate: runs on the CPU backend (JAX_PLATFORMS defaults to cpu here);
+no time it prints is a measurement of the chip.
 """
 from __future__ import annotations
 

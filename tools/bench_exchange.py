@@ -14,6 +14,9 @@ Run:  python tools/bench_exchange.py [--rows 200000] [--nout 4] [--reps 3]
 
 Prints per-mode wall-clock and a JSON summary line; exits nonzero if the
 two modes disagree on query results (they must be identical).
+
+CPU gate: runs on the CPU backend (JAX_PLATFORMS defaults to cpu here);
+no time it prints is a measurement of the chip.
 """
 from __future__ import annotations
 

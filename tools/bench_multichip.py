@@ -35,6 +35,9 @@ MULTICHIP_r06.json (replacing round 5's literal ``ok: true``).
 
 Usage: python tools/bench_multichip.py [--rows 200000] [--sim-ms 5]
            [--out MULTICHIP_r06.json]
+
+CPU gate: runs on the CPU backend (JAX_PLATFORMS defaults to cpu here);
+no time it prints is a measurement of the chip.
 """
 import argparse
 import hashlib

@@ -25,6 +25,9 @@ Drives the query server over its real HTTP surface and records:
   hot-path overhead stays <2% by count x delta.
 
 Usage: python tools/bench_serving.py [--clients 8] [--out SERVING_r02.json]
+
+CPU gate: runs on the CPU backend (JAX_PLATFORMS defaults to cpu here);
+no time it prints is a measurement of the chip.
 """
 from __future__ import annotations
 

@@ -40,6 +40,9 @@ always-on lifecycle checkpoint (count x delta, same bar).
 Run:  python tools/chaos_smoke.py [--seed 20260803] [--sf 0.002]
           [--max-rounds 14] [--min-faults 200] [--min-sites 6]
           [--cancel-runs 20] [--deadline 480] [--tolerance 0.02]
+
+CPU gate: runs on the CPU backend (JAX_PLATFORMS defaults to cpu here);
+no time it prints is a measurement of the chip.
 """
 from __future__ import annotations
 

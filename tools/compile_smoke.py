@@ -28,6 +28,9 @@ driven over probe-shaped join/agg/window SQL.
 
 Run:  python tools/compile_smoke.py [--tolerance 0.02] [--min-drop 5]
 Internal: --worker cold|warm --dir D (subprocess modes).
+
+CPU gate: runs on the CPU backend (JAX_PLATFORMS defaults to cpu here);
+no time it prints is a measurement of the chip.
 """
 from __future__ import annotations
 

@@ -17,6 +17,9 @@ Parquet decode path.
    overhead must stay under --tolerance (2%) of the drive.
 
 Usage: python tools/decode_smoke.py [--rows 200000] [--tolerance 0.02]
+
+CPU gate: runs on the CPU backend (JAX_PLATFORMS defaults to cpu here);
+no time it prints is a measurement of the chip.
 """
 import argparse
 import json

@@ -30,7 +30,8 @@ otherwise), ``gen_tables(SF=0.002, seed=7)``, the compile cache AND
 audit record table cleared together (``clear_for_cold_audit``), and
 queries executed in sorted name order. Accounting is shape-complete
 (every traced shape is audited), so within that replay the signatures
-are thread-order and process independent; two consecutive generator
+are thread-order and process independent (jax's executables are
+released every RELEASE_EVERY queries — see the constant); two consecutive generator
 runs must produce byte-identical cost_signatures —
 ``tools/audit_smoke.py`` gates exactly that. The generator ABORTS on
 any audit finding (an unresolvable cost analysis or a dispatch of an
@@ -72,6 +73,17 @@ SEED = 7
 #: generated under a drifted default would silently pin different plans
 #: than CI converts. Recorded in both artifact headers.
 ADAPTIVE = "true"
+
+#: part of the cost-pass recipe: drop jax's compiled executables
+#: (jax.clear_caches) before every RELEASE_EVERY-th query. XLA:CPU under
+#: jaxlib 0.9.0 holds ~24 memory mappings per live executable, and the
+#: 98-query pass in ONE process runs into the kernel's default
+#: vm.max_map_count (65530) near query 65 ("LLVM compilation error:
+#: Cannot allocate memory"). A release changes no signature: keyed
+#: entries tally per dispatch, and the audit credits a module kernel's
+#: trace once per audited SHAPE, not per re-trace. The replay in
+#: tests/test_kernel_audit.py releases at the same points.
+RELEASE_EVERY = 20
 
 OUT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
                    "tests", "golden_plans", "dispatch_budgets.json")
@@ -134,7 +146,9 @@ def build_cost_signatures(limit=None, queries=None):
     if limit:
         names = names[:int(limit)]
     sigs = {}
-    for qn in names:
+    for i, qn in enumerate(names):
+        if i and i % RELEASE_EVERY == 0:
+            jax.clear_caches()
         df = nds.QUERIES[qn](sess, d)
         df.collect()
         sig = KA.query_signature(sess.last_audit())
@@ -154,6 +168,7 @@ def signature_doc(sigs) -> dict:
     from spark_rapids_tpu.analysis.kernel_audit import KERNEL_PRIMITIVES
     return {"_generator": "tools/gen_dispatch_budgets.py",
             "_sf": SF, "_seed": SEED, "_adaptive": ADAPTIVE,
+            "_release_every": RELEASE_EVERY,
             "kernel_primitives": sorted(KERNEL_PRIMITIVES),
             "cost_signatures": sigs}
 

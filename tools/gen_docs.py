@@ -163,6 +163,23 @@ def gen_metrics_md() -> str:
     for name, doc in SITES.items():
         star = " (data site)" if name in BYTE_SITES else ""
         lines.append(f"| `{name}`{star} | {doc} |")
+    from spark_rapids_tpu.runtime.obs import (EXEC_FALLBACK_SITES,
+                                              PALLAS_SEGSUM_PATHS)
+    lines += [
+        "",
+        "## Stage fallbacks (runtime/obs EXEC_FALLBACK_SITES)",
+        "",
+        "A stage whose trace, compile or setup fails keeps the query",
+        "correct on a slower replay path. Each such fallback counts into",
+        "`rapids_stage_fallbacks_total{site=...}` (sites: "
+        + ", ".join(f"`{s}`" for s in EXEC_FALLBACK_SITES) + "), so a",
+        "run on real hardware can fail on a dead fast path without",
+        "reading logs (`chip_smoke.py` does).",
+        "`rapids_pallas_segsum_traces_total{path=...}` (paths: "
+        + ", ".join(f"`{p}`" for p in PALLAS_SEGSUM_PATHS) + ") counts,",
+        "at trace time, the group-by traces whose eligibility gates",
+        "routed them into the Pallas sorted-window segmented sum.",
+    ]
     from spark_rapids_tpu.runtime.obs.live import STATES
     lines += [
         "",

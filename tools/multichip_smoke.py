@@ -15,6 +15,9 @@ the ICI mesh.
    must stay under --tolerance (2%) of the drive.
 
 Usage: python tools/multichip_smoke.py [--rows 50000] [--tolerance 0.02]
+
+CPU gate: runs on the CPU backend (JAX_PLATFORMS defaults to cpu here);
+no time it prints is a measurement of the chip.
 """
 import argparse
 import json

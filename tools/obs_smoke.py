@@ -30,6 +30,9 @@ The live-layer CI gate (tools/ci_check.sh):
    is one global read — measured per-call and gated.
 
 Run:  python tools/obs_smoke.py
+
+CPU gate: runs on the CPU backend (JAX_PLATFORMS defaults to cpu here);
+no time it prints is a measurement of the chip.
 """
 from __future__ import annotations
 

@@ -4,6 +4,9 @@ recorded, producer time observed — i.e. host work ran on the pool and
 overlapped the consumer), a LIMIT early exit must cancel the producer,
 and neither path may leak a thread. Fast (<15s); wired into
 tools/ci_check.sh.
+
+CPU gate: runs on the CPU backend (JAX_PLATFORMS defaults to cpu here);
+no time it prints is a measurement of the chip.
 """
 from __future__ import annotations
 

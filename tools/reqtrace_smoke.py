@@ -27,6 +27,9 @@
 
 Usage: python tools/reqtrace_smoke.py [--hits 240] [--ratio 0.05]
                                       [--tolerance 0.02]
+
+CPU gate: runs on the CPU backend (JAX_PLATFORMS defaults to cpu here);
+no time it prints is a measurement of the chip.
 """
 from __future__ import annotations
 

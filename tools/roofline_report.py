@@ -75,14 +75,15 @@ def summarize(records):
             "device_s": tot.get("seconds", 0.0),
             "gb_moved": round(tot.get("bytes_accessed", 0) / 1e9, 4),
             "achieved_gbps": tot.get("achieved_gbps", 0.0),
-            "roofline_pct": tot.get("roofline_pct_bw", 0.0),
+            # None when the record was not taken on a v5e
+            "roofline_pct": tot.get("roofline_pct_bw"),
             "bound": "+".join(bounds) or "?",
             "padding_waste_max": round(waste, 3),
             "adaptive": adaptive or "-",
             "dispatches_saved": saved,
             "top_kernel": top_kernel,
         })
-    rows.sort(key=lambda r: r["roofline_pct"])
+    rows.sort(key=lambda r: r["roofline_pct"] or 0.0)
     return rows
 
 
@@ -96,12 +97,14 @@ def render(rows, top: int) -> str:
         lines.append(
             f"{str(r['query_id']):>6} {r['wall_s']:>8.3f} "
             f"{r['device_s']:>8.3f} {r['gb_moved']:>8.3f} "
-            f"{r['achieved_gbps']:>8.2f} {r['roofline_pct']:>7.3f} "
+            f"{r['achieved_gbps']:>8.2f} "
+            + (f"{r['roofline_pct']:>7.3f} " if r["roofline_pct"] is not None
+               else f"{'n/a':>7} ") +
             f"{r['padding_waste_max'] * 100:>7.0f}% "
             f"{r['bound']:<18} {r['adaptive']:<28} {r['top_kernel']}")
     if rows:
         import math
-        pcts = [r["roofline_pct"] for r in rows if r["roofline_pct"] > 0]
+        pcts = [r["roofline_pct"] for r in rows if r["roofline_pct"]]
         if pcts:
             geo = math.exp(sum(math.log(p) for p in pcts) / len(pcts))
             lines.append(f"geomean roofline share: {geo:.4f}% over "

@@ -23,6 +23,9 @@ stays clean under instrumentation).
 
 Run:  python tools/sanitizer_smoke.py [--rows 400000] [--batch 2048]
                                       [--reps 9] [--tolerance 0.02]
+
+CPU gate: runs on the CPU backend (JAX_PLATFORMS defaults to cpu here);
+no time it prints is a measurement of the chip.
 """
 from __future__ import annotations
 

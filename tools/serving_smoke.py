@@ -24,6 +24,9 @@
 
 Usage: python tools/serving_smoke.py [--clients 4] [--tolerance 0.02]
 Internal: --worker seed|replica --dir D (subprocess modes).
+
+CPU gate: runs on the CPU backend (JAX_PLATFORMS defaults to cpu here);
+no time it prints is a measurement of the chip.
 """
 from __future__ import annotations
 
