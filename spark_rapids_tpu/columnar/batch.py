@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.runtime import shapes as _shapes
+from spark_rapids_tpu.runtime.obs.phases import device_wait
 
 MIN_CAPACITY = 8
 
@@ -70,7 +71,7 @@ class LazyRowCount:
 
     def materialize(self) -> int:
         if self._val is None:
-            self._val = int(self._dev)
+            self._val = host_int(self._dev)
         return self._val
 
     @property
@@ -132,6 +133,14 @@ def traced_rows(n):
     return n.traced() if isinstance(n, LazyRowCount) else n
 
 
+def host_int(dev) -> int:
+    """A device scalar as a host int: a sync, so the query's phase
+    account times it as device wait (runtime/obs/phases.py). Hand it the
+    already-enqueued scalar: `host_int(jnp.sum(x))`."""
+    with device_wait():
+        return int(dev)
+
+
 def rows_int(n) -> int:
     """num_rows as a host int (synchronizes if lazy)."""
     return int(n)
@@ -145,7 +154,8 @@ def materialize_counts(batches: Sequence["ColumnarBatch"]) -> None:
     if not lazies:
         return
     import jax as _jax
-    vals = _jax.device_get([lz._dev for lz in lazies])
+    with device_wait():
+        vals = _jax.device_get([lz._dev for lz in lazies])
     for lz, v in zip(lazies, vals):
         lz._val = int(v)
 
@@ -568,7 +578,8 @@ def fetch_batch_host(batch: ColumnarBatch) -> ColumnarBatch:
     per-plane np.asarray costs a round trip each). Returns a batch whose
     planes are host numpy arrays; the lazy row count rides along."""
     leaves, treedef = jax.tree_util.tree_flatten(batch)
-    host = jax.device_get(leaves)
+    with device_wait():
+        host = jax.device_get(leaves)
     out = jax.tree_util.tree_unflatten(treedef, host)
     n = int(out.num_rows)
     if isinstance(batch.num_rows, LazyRowCount):

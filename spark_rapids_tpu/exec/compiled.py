@@ -21,7 +21,9 @@ import jax
 import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
-from spark_rapids_tpu.columnar.batch import ColumnVector, ColumnarBatch
+from spark_rapids_tpu.columnar.batch import (
+    ColumnVector, ColumnarBatch, host_int,
+)
 from spark_rapids_tpu.expr.core import EvalCtx, Expression, SparkException
 from spark_rapids_tpu.runtime import compile_cache as _cc
 
@@ -99,16 +101,12 @@ def run_stage(exprs: Sequence[Expression], batch: ColumnarBatch,
     from spark_rapids_tpu.columnar.batch import traced_rows
     from spark_rapids_tpu.exec import fuse
     from spark_rapids_tpu.runtime import lifecycle as _lc
-    from spark_rapids_tpu.runtime import trace as TR
     _lc.check_current()  # run_stage is the OTHER per-batch dispatch path
     fuse.notify_dispatch(("run_stage", fp))  # dispatch-budget hook
     col_planes = [_planes_of(c) for c in batch.columns]
-    with TR.span("compiled.run_stage", cat="dispatch", level=TR.DEBUG,
-                 args={"exprs": len(exprs)}):
-        out_planes, err = fn(col_planes,
-                             jnp.asarray(traced_rows(batch.num_rows),
-                                         jnp.int32),
-                             batch.live_mask())
+    out_planes, err = fn(col_planes,
+                         jnp.asarray(traced_rows(batch.num_rows), jnp.int32),
+                         batch.live_mask())
     raise_errors(err)
     outs = [_col_from_planes(p, dt) for p, dt in zip(out_planes, out_dtypes)]
     carry_bounds(exprs, batch.columns, outs)
@@ -130,7 +128,7 @@ def raise_errors(err: Dict[str, jax.Array]) -> None:
     the stage ran in ANSI mode and produced error masks."""
     if err:
         for code, mask in err.items():
-            if bool(jnp.any(mask)):
+            if host_int(jnp.any(mask)):
                 raise SparkException(f"[{code}] ANSI mode error in stage")
 
 

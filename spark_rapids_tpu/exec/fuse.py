@@ -29,6 +29,7 @@ from spark_rapids_tpu.runtime import compile_cache as _cc
 from spark_rapids_tpu.runtime import faults as _faults
 from spark_rapids_tpu.runtime import lifecycle as _lc
 from spark_rapids_tpu.runtime import watchdog as _watchdog
+from spark_rapids_tpu.runtime.obs import phases as _ph
 
 #: test/diagnostic hook called with the fuse key once per device dispatch
 #: issued through fused() (the dispatch-budget regression harness; see
@@ -44,7 +45,8 @@ def set_dispatch_hook(hook: Optional[Callable[[Tuple], None]]) -> None:
 
 def notify_dispatch(key: Tuple) -> None:
     """Report a device dispatch issued outside fused() (compiled.run_stage)
-    to the budget hook."""
+    to the phase account's counter and the budget hook."""
+    _ph.keyed_dispatches += 1
     if _DISPATCH_HOOK is not None:
         _DISPATCH_HOOK(key)
 
@@ -73,14 +75,14 @@ def fused(key: Tuple, builder: Callable[[], Callable]) -> Callable:
 
         def checked(*args, **kwargs):
             _lc.check_current()
+            _ph.keyed_dispatches += 1  # the query's phase account reads it
             return fn(*args, **kwargs)
 
         return checked
 
     def counted(*args, **kwargs):
         _lc.check_current()
-        if _DISPATCH_HOOK is not None:
-            notify_dispatch(key)
+        notify_dispatch(key)
         with _watchdog.guard("device.dispatch"):
             # inside the guard so a wedge-kind fault is exactly what the
             # watchdog exists to detect

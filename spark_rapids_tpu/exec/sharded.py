@@ -363,8 +363,6 @@ def make_sharded_stage_exec():
                                      args={"stage_id": self.stage_id,
                                            "n_shards": m,
                                            "live_slots": n_live})
-                        TR.instant("shardedDispatch", cat="dispatch",
-                                   args={"stage_id": self.stage_id})
                     for errs in errs_all:
                         compiled.raise_errors(errs)
                     disp.add(1)

@@ -46,6 +46,7 @@ from spark_rapids_tpu.runtime import faults as FLT
 from spark_rapids_tpu.runtime import lifecycle as LC
 from spark_rapids_tpu.runtime import metrics as M
 from spark_rapids_tpu.runtime import trace as TR
+from spark_rapids_tpu.runtime.obs.phases import device_wait
 from spark_rapids_tpu.runtime.semaphore import get_semaphore
 from spark_rapids_tpu.runtime.task import TaskContext
 
@@ -81,10 +82,11 @@ class TpuExec:
 
     def span(self, metric):
         """Trace span + the paired GpuMetric timer as ONE instrumentation
-        point (the NvtxWithMetrics contract): tracing off returns the
-        metric's own timer; tracing on additionally emits a
-        `ExecName.metricName` complete event on this task's track and
-        forwards the range to jax.profiler.TraceAnnotation."""
+        point (the NvtxWithMetrics contract): with no sink it returns the
+        metric's own timer; a tracer gets an `ExecName.metricName`
+        complete event on this task's track, the flight ring an entry,
+        and a running jax.profiler capture a
+        `rapids.ExecName.metricName` TraceAnnotation (trace._sinks)."""
         return TR.exec_span(self, metric)
 
     def _acquire(self, ctx: TaskContext) -> None:
@@ -119,6 +121,7 @@ class InMemoryScanExec(TpuExec):
         out_rows = self.metrics.metric(M.NUM_OUTPUT_ROWS)
         out_batches = self.metrics.metric(M.NUM_OUTPUT_BATCHES)
         copy_t = self.metrics.metric(M.COPY_TO_DEVICE_TIME)
+        up_bytes = self.metrics.metric(M.UPLOAD_BYTES)
         off = 0
         while off < n or (n == 0 and off == 0):
             take = min(max_rows, n - off)
@@ -127,6 +130,7 @@ class InMemoryScanExec(TpuExec):
             FLT.site("scan.decode")
             with self.span(copy_t):
                 b = from_arrow(chunk)
+            up_bytes.add(b.device_memory_size())
             yield b
             out_rows.add(take)
             out_batches.add(1)
@@ -177,6 +181,7 @@ class ParquetScanExec(TpuExec):
         path = self.plan.paths[fidx]
         decode_t = self.metrics.metric(M.DECODE_TIME)
         copy_t = self.metrics.metric(M.COPY_TO_DEVICE_TIME)
+        up_bytes = self.metrics.metric(M.UPLOAD_BYTES)
         out_rows = self.metrics.metric(M.NUM_OUTPUT_ROWS)
         rg_total = self.metrics.metric(M.NUM_ROW_GROUPS)
         rg_pruned = self.metrics.metric(M.NUM_ROW_GROUPS_PRUNED)
@@ -220,6 +225,7 @@ class ParquetScanExec(TpuExec):
                 self._acquire(ctx)
                 with self.span(copy_t):
                     b = from_arrow(chunk)
+                up_bytes.add(b.device_memory_size())
                 yield b
                 out_rows.add(chunk.num_rows)
                 off += max(chunk.num_rows, 1)
@@ -372,6 +378,7 @@ class EncodedParquetSourceExec(TpuExec):
         path = self.plan.paths[fidx]
         decode_t = self.metrics.metric(M.DECODE_TIME)
         copy_t = self.metrics.metric(M.COPY_TO_DEVICE_TIME)
+        up_bytes = self.metrics.metric(M.UPLOAD_BYTES)
         out_rows = self.metrics.metric(M.NUM_OUTPUT_ROWS)
         out_batches = self.metrics.metric(M.NUM_OUTPUT_BATCHES)
         rg_total = self.metrics.metric(M.NUM_ROW_GROUPS)
@@ -401,6 +408,7 @@ class EncodedParquetSourceExec(TpuExec):
             self._acquire(ctx)
             with self.span(copy_t):
                 b = from_arrow(tbl)
+            up_bytes.add(b.device_memory_size())
             cols = [ENC.EncodedColumn("decoded", c.dtype, {}, (), cv=c,
                                       bounds=c.bounds) for c in b.columns]
             yield ENC.EncodedBatch(cols, rows_int(b.num_rows), b.capacity)
@@ -439,6 +447,7 @@ class EncodedParquetSourceExec(TpuExec):
                     decoded[i] = column_from_arrow(arr, fields[i].dtype,
                                                    hb.cap)
                 eb = ENC.upload(hb, decoded)
+            up_bytes.add(eb.device_memory_size())
             eb.columns.extend(
                 self._partition_columns(fidx, hb.num_rows, hb.cap))
             enc_bytes.add(hb.encoded_bytes)
@@ -499,6 +508,7 @@ class TextScanExec(TpuExec):
     def execute_partition(self, ctx, pidx):
         decode_t = self.metrics.metric(M.DECODE_TIME)
         copy_t = self.metrics.metric(M.COPY_TO_DEVICE_TIME)
+        up_bytes = self.metrics.metric(M.UPLOAD_BYTES)
         out_rows = self.metrics.metric(M.NUM_OUTPUT_ROWS)
         FLT.site("scan.decode")
         with self.span(decode_t):
@@ -512,6 +522,7 @@ class TextScanExec(TpuExec):
             self._acquire(ctx)
             with self.span(copy_t):
                 b = from_arrow(chunk)
+            up_bytes.add(b.device_memory_size())
             yield b
             out_rows.add(take)
             off += max(take, 1)
@@ -584,7 +595,8 @@ def _attach_column_stats(batch: ColumnarBatch) -> None:
         pending.extend([lo, hi])
     if not idxs:
         return
-    vals = jax.device_get(pending)
+    with device_wait():
+        vals = jax.device_get(pending)
     for j, i in enumerate(idxs):
         lo, hi = int(vals[2 * j]), int(vals[2 * j + 1])
         if lo <= hi:
@@ -924,6 +936,7 @@ class ShuffleFileScanExec(TpuExec):
             read_partition_batches,
         )
         copy_t = self.metrics.metric(M.COPY_TO_DEVICE_TIME)
+        up_bytes = self.metrics.metric(M.UPLOAD_BYTES)
         out_rows = self.metrics.metric(M.NUM_OUTPUT_ROWS)
         self._acquire(ctx)
         it = read_partition_batches(self.plan.root, pidx)
@@ -932,6 +945,7 @@ class ShuffleFileScanExec(TpuExec):
                 batch = next(it, None)
             if batch is None:
                 return
+            up_bytes.add(batch.device_memory_size())
             out_rows.add(rows_int(batch.num_rows))
             yield batch
 
@@ -1306,7 +1320,9 @@ class SortExec(TpuExec):
                 keys.append((k, nl, o.ascending, o.resolved_nulls_first()))
                 pi += 1
         n = int(keys[0][0].shape[0])
-        perm = np.asarray(K.lexsort_indices(keys, n))[:n]
+        perm_d = K.lexsort_indices(keys, n)
+        with device_wait():
+            perm = np.asarray(perm_d)[:n]
         table = pa.concat_tables(tables) if len(tables) > 1 else tables[0]
         sorted_table = table.take(perm)
         step = self.conf.get(C.MAX_READER_BATCH_SIZE_ROWS)
@@ -1365,7 +1381,8 @@ def _probe_pack_spec(key_cols, live, key_exprs=None):
             probe = fuse.fused(("radix_probe", tuple(kinds)),
                                lambda: R.probe_ranges)
             ranges = probe(key_cols, live)
-            ranges_host = np.asarray(jax.device_get(ranges))
+            with device_wait():
+                ranges_host = np.asarray(jax.device_get(ranges))
     else:
         ranges = jnp.zeros(2 * len(key_cols), jnp.int64)
         ranges_host = np.zeros(2 * len(key_cols), np.int64)

@@ -66,13 +66,10 @@ class TrafficController:
     def acquire(self, nbytes: int) -> None:
         import time
 
-        from spark_rapids_tpu.runtime import trace
         t0 = time.perf_counter_ns()
-        blocked = False
         warned = False
         with self._cv:
             while self._inflight > 0 and self._inflight + nbytes > self.limit:
-                blocked = True
                 if self.stall_warn_s is not None and not warned:
                     waited = (time.perf_counter_ns() - t0) / 1e9
                     if waited >= self.stall_warn_s:
@@ -102,10 +99,6 @@ class TrafficController:
                 from spark_rapids_tpu.runtime import lifecycle as _lc
                 _lc.check_current()
             self._inflight += nbytes
-        if blocked:
-            trace.instant("asyncWriteThrottled", cat="io", args={
-                "blocked_ns": time.perf_counter_ns() - t0,
-                "bytes": nbytes})
 
     def release(self, nbytes: int) -> None:
         with self._cv:
@@ -147,11 +140,8 @@ class ThrottlingExecutor:
             self._slots.acquire()
 
         def run():
-            from spark_rapids_tpu.runtime import trace
             try:
-                with trace.span("asyncWrite", cat="io", level=trace.DEBUG,
-                                args={"bytes": nbytes}):
-                    return fn(*args)
+                return fn(*args)
             finally:
                 if self._slots is not None:
                     self._slots.release()

@@ -17,7 +17,9 @@ import jax
 import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
-from spark_rapids_tpu.columnar.batch import ColumnVector, ColumnarBatch, round_capacity
+from spark_rapids_tpu.columnar.batch import (
+    ColumnVector, ColumnarBatch, host_int, round_capacity,
+)
 from spark_rapids_tpu.ops import kernels as K
 
 
@@ -48,7 +50,7 @@ def group_segments(key_cols: List[ColumnVector], num_rows: int, live=None
 
 
 def num_groups(boundary: jax.Array) -> int:
-    return int(jnp.sum(boundary.astype(jnp.int32)))
+    return host_int(jnp.sum(boundary.astype(jnp.int32)))
 
 
 def _float_minmax_prep(op: str, values: jax.Array, valid: jax.Array):

@@ -28,7 +28,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from spark_rapids_tpu.columnar.batch import ColumnVector, round_capacity
+from spark_rapids_tpu.columnar.batch import (
+    ColumnVector, host_int, round_capacity,
+)
+from spark_rapids_tpu.runtime.obs.phases import device_wait
 from spark_rapids_tpu.ops import kernels as K
 from spark_rapids_tpu.runtime import compile_cache as _cc
 
@@ -111,14 +114,15 @@ def prepare_dense_build(build_keys: List[ColumnVector], build_rows: int,
     bmin_d = jnp.min(jnp.where(b_in, bv, jnp.int64(2**62)))
     bmax_d = jnp.max(jnp.where(b_in, bv, jnp.int64(-2**62)))
     nbuild_d = jnp.sum(b_in.astype(jnp.int32))
-    bmin, bmax, nbuild = (int(x) for x in
-                          jax.device_get([bmin_d, bmax_d, nbuild_d]))
+    with device_wait():
+        bmin, bmax, nbuild = (int(x) for x in
+                              jax.device_get([bmin_d, bmax_d, nbuild_d]))
     span = bmax - bmin + 1
     if nbuild <= 0 or not (0 < span <= DENSE_KEY_RANGE_LIMIT):
         return None
     starts, sorted_orig = _dense_table(bv, b_in, bcap, jnp.int64(bmin), span)
     cnt = starts[1:] - starts[:-1]
-    max_dup = int(jnp.max(cnt)) if span > 0 else 0
+    max_dup = host_int(jnp.max(cnt)) if span > 0 else 0
     return DenseBuildTable(starts, sorted_orig, jnp.int64(bmin), span,
                            max_dup, bcap, build_rows)
 
@@ -201,7 +205,7 @@ def join_pairs(build_keys: List[ColumnVector], build_rows: int,
     sorted_orig = jnp.where(bidx >= 0, bidx, -1)[order]
 
     lo, hi = _merge_rank_ranges(sorted_h, bcount, ph, p_in)
-    total = int(jnp.sum((hi - lo).astype(jnp.int64)))
+    total = host_int(jnp.sum((hi - lo).astype(jnp.int64)))
 
     probe_i, build_pos = K.expand_ranges(lo, hi, total)
     build_i = jnp.where(build_pos >= 0,
@@ -256,7 +260,7 @@ def _dense_int_pairs(table: DenseBuildTable, pv, p_in, pcap):
         out_b = jnp.where(idx >= 0,
                           sorted_orig[jnp.clip(bpos, 0, bcap - 1)], -1)
         return out_p, out_b, match_count
-    total = int(jnp.sum(counts.astype(jnp.int64)))
+    total = host_int(jnp.sum(counts.astype(jnp.int64)))
     probe_i, build_pos = K.expand_ranges(lo, hi, total)
     build_i = jnp.where(build_pos >= 0,
                         sorted_orig[jnp.clip(build_pos, 0, bcap - 1)], -1)

@@ -23,7 +23,7 @@ from jax import lax
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import (
-    ColumnVector, ColumnarBatch, LazyRowCount, materialize_counts,
+    ColumnVector, ColumnarBatch, LazyRowCount, host_int, materialize_counts,
     round_capacity, traced_rows,
 )
 from spark_rapids_tpu.runtime import compile_cache as _cc
@@ -307,7 +307,7 @@ def string_chunk_count(col: ColumnVector) -> int:
     (HOST-side: one device scalar fetch — call at sort boundaries, never
     inside jit). Rounded up to a power of two to bound kernel variants."""
     off = col.data["dict_offsets"] if col.is_dict else col.data["offsets"]
-    mx = int(jnp.max(off[1:] - off[:-1]))
+    mx = host_int(jnp.max(off[1:] - off[:-1]))
     return round_capacity(max(1, -(-mx // 8)), minimum=1)
 
 

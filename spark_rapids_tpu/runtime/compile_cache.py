@@ -350,14 +350,6 @@ def _on_compile_duration(event: str, duration_secs: float, **kw) -> None:
         # into device_compute and hides exactly the recompiles the
         # shape-bucketing policy exists to kill
         _attr.record("compile", ns)
-        if duration_secs >= 0.001:
-            try:
-                from spark_rapids_tpu.runtime import trace as _tr
-                _tr.instant("xlaCompile", cat="compile",
-                            args={"seconds": round(duration_secs, 4)},
-                            level=_tr.MODERATE)
-            except Exception:  # noqa: BLE001 - tracing is advisory
-                pass
 
 
 def _on_cache_event(event: str, **kw) -> None:

@@ -217,24 +217,8 @@ class HostTaskPool:
 
     def submit(self, fn: Callable, *args) -> Future:
         depth = self._depth()
-        from spark_rapids_tpu.runtime import trace
-        tr = trace.active()
-        if tr is not None and tr.level >= trace.DEBUG:
-            # queue-time observability: how long the task sat behind other
-            # host work before a worker picked it up (DEBUG level; the
-            # wrapper exists only while a trace is live)
-            import time as _time
-            enq = _time.perf_counter_ns()
-            inner, name = fn, getattr(fn, "__name__", "task")
-
-            def fn(*a):  # noqa: F811 - traced wrapper replaces fn
-                trace.instant("hostPoolDequeue", cat="host_pool", args={
-                    "queue_us": (_time.perf_counter_ns() - enq) / 1000.0,
-                    "tier": depth, "fn": name},
-                    level=trace.DEBUG)
-                return inner(*a)
-        # cross-thread query correlation (OUTERMOST wrapper, so even the
-        # dequeue instant above runs bound): pool workers are shared
+        # cross-thread query correlation (OUTERMOST wrapper): pool workers
+        # are shared
         # across queries, so every submission captures the SUBMITTER's
         # bound query id and re-binds it (with restore) around the work
         # — exchange materialization, scan prefetch, serde, async

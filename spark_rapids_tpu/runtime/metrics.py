@@ -30,6 +30,11 @@ ENCODED_BYTES = "encodedBytes"
 #: decoded plane bytes a device-decode scan produced on device — what
 #: the host path would have uploaded instead
 DECODED_BYTES = "decodedBytes"
+#: bytes of the device arrays a scan's upload built (the planes handed to
+#: the device inside its copyToDeviceTime spans, from their shapes: no
+#: sync): encoded planes plus host-decoded fallback columns on the
+#: device-decode scan, decoded planes everywhere else
+UPLOAD_BYTES = "uploadBytes"
 #: columns a device-decode scan host-decoded instead (unsupported
 #: type/encoding/codec; per-column reasons in explain/history)
 NUM_DECODE_FALLBACK_COLUMNS = "numDecodeFallbackColumns"

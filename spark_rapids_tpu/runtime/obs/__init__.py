@@ -25,11 +25,12 @@ actions produce history records.
 """
 from __future__ import annotations
 
+import collections
 import threading
 import time
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, List, Optional
 
-from spark_rapids_tpu.runtime.obs import (attribution, flight, live,
+from spark_rapids_tpu.runtime.obs import (attribution, flight, live, phases,
                                           reqtrace, sampler)
 from spark_rapids_tpu.runtime.obs.history import (  # noqa: F401 (re-export)
     QueryHistoryStore, build_query_record, conf_delta, plan_digest,
@@ -87,7 +88,12 @@ class ObsState:
         self._lock = threading.Lock()
         self._query_seq = 0
         self._active = 0  # top-level queries currently running
-        self.last_query: Optional[dict] = None
+        #: the phase-account records of the newest top-level actions
+        #: (runtime/obs/phases.py says what one holds), oldest first;
+        #: appended and copied under _lock
+        self.recent: collections.deque = collections.deque(
+            maxlen=phases.RING_SIZE)
+        self._record_seq = 0
         #: the most recent SLO breach: digest, breach doc, attribution
         #: summary, flight-dump path (the /healthz slow-query surface)
         self.last_slow: Optional[dict] = None
@@ -98,6 +104,12 @@ class ObsState:
         #: pid-derived) — stamped on every history record so a shared
         #: historyDir splits per replica (tools/fleet_report.py)
         self.replica_id: str = ""
+
+    @property
+    def last_query(self) -> Optional[dict]:
+        """The newest record of `recent` (the /healthz last_completed)."""
+        with self._lock:
+            return self.recent[-1] if self.recent else None
 
 
 #: per-thread collect depth: a re-entrant collect on the SAME thread is
@@ -557,6 +569,33 @@ def on_query_start(plan_digest: Optional[str] = None,
     return token
 
 
+def publish_query_record(rec: dict) -> None:
+    """Append one finished top-level action's phase-account record
+    (phases.QueryPhases.record) to the ring; obs off: nothing is kept."""
+    st = _STATE
+    if st is None:
+        return
+    slow = st.last_slow  # on_query_end ran before: the breach is there
+    if slow is not None and slow["query_id"] == rec["query_id"]:
+        rec["slo_breach"] = True
+    with st._lock:
+        st._record_seq += 1
+        rec["seq"] = st._record_seq
+        st.recent.append(rec)
+
+
+def recent_queries(n: Optional[int] = None) -> List[dict]:
+    """The records of the newest `n` top-level actions (all the ring
+    holds, at most phases.RING_SIZE, when None), oldest first; empty
+    when obs is off. runtime/obs/phases.py documents a record."""
+    st = _STATE
+    if st is None:
+        return []
+    with st._lock:
+        recs = list(st.recent)
+    return recs if n is None else recs[max(len(recs) - n, 0):]
+
+
 def wants_rollups() -> bool:
     """Does a consumer (endpoint or history store) exist for per-exec
     rollups? The epilogue uses this to decide whether the metric
@@ -729,16 +768,6 @@ def on_query_end(token, *, session, plan, status: str,
                 trace_id=rctx.trace_id if rctx is not None else None,
                 mesh=mesh_doc)
             st.history.append(rec)
-        st.last_query = {
-            "query_id": token, "status": status,
-            "wall_ms": round(duration_ns / 1e6, 3),
-            "error_class": type(error).__name__ if error else None,
-            "finished_unix": time.time(),
-        }
-        if degraded_reason is not None:
-            st.last_query["degraded_reason"] = degraded_reason
-        if breach is not None:
-            st.last_query["slo_breach"] = True
         return rec
     except Exception:  # noqa: BLE001 - observability never fails a query
         return None

@@ -48,8 +48,14 @@ BUCKETS: Dict[str, str] = {
     "compile": "XLA compilation: the first execution of a newly built "
                "fused/stage computation (fuse-cache miss; includes that "
                "first batch's compute — compile dominates it 10x+)",
-    "device_compute": "device operator work: every exec *Time metric not "
-                      "classified into another bucket",
+    "device_compute": "host wall time inside exec operator spans (every "
+                      "exec *Time metric not classified into another "
+                      "bucket): the enqueue time of asynchronous "
+                      "dispatches plus whatever device wait a host sync "
+                      "inside the span holds; NOT the device's busy time "
+                      "(on the chip it read 18 ms a Parquet Q6 query "
+                      "whose device worked 3.1 s, and 6.7 s a Parquet Q3 "
+                      "query whose device worked 5.7 s; PERF.md, PR 26)",
     "host_decode": "host-side scan decode and H2D/D2H transfer time "
                    "(tpuDecodeTime, copyToDeviceTime, copyFromDeviceTime)",
     "shuffle": "exchange work: partitioning kernels plus every *Time "
@@ -62,8 +68,10 @@ BUCKETS: Dict[str, str] = {
                      "attempts (retryBlockTime task accumulator)",
     "spill": "spill time device->host and host->disk (spillToHostTime, "
              "spillToDiskTime task accumulators)",
-    "other": "unattributed wall-time remainder: planning, driver glue, "
-             "result assembly (zero when concurrency-scaled)",
+    "other": "unattributed wall-time remainder: admission, planning, "
+             "host time of query.execute outside every exec span, result "
+             "assembly (zero when concurrency-scaled); the document's "
+             "other_phases splits it by the phase account's clocks",
 }
 
 #: *Time metrics that are overlapped upstream work or nested inside
@@ -263,14 +271,17 @@ def subtract_compile(totals: Dict[str, int], compile_ns: int) -> None:
 
 
 def attribute(snaps: Optional[Dict[str, dict]], duration_ns: int,
-              extra: Optional[Dict[str, int]] = None) -> Optional[dict]:
+              extra: Optional[Dict[str, int]] = None,
+              phases: Optional[Dict[str, int]] = None) -> Optional[dict]:
     """Decompose one query's wall time into the bucket roster.
 
     `snaps` is a last_metrics()-shaped {exec_key: {metric: value}}
-    snapshot; `extra` the direct-record aggregate from finish(). Returns
-    the attribution document (buckets in seconds, fractions of wall,
-    measured total and concurrency factor) or None for a zero-duration
-    query."""
+    snapshot (the epilogue passes the phase account's peeked one);
+    `extra` the direct-record aggregate from finish(); `phases` the
+    phase account's clocks in ns (runtime/obs/phases.py), which split
+    the `other` bucket. Returns the attribution document (buckets in
+    seconds, fractions of wall, measured total and concurrency factor)
+    or None for a zero-duration query."""
     wall_ns = int(duration_ns)
     if wall_ns <= 0:
         return None
@@ -328,6 +339,18 @@ def attribute(snaps: Optional[Dict[str, dict]], duration_ns: int,
         # keyed only when present so default-path documents (and every
         # golden artifact derived from them) stay byte-identical
         doc["views"] = views
+    if phases:
+        # `other` by the phases that no exec timer covers: admission and
+        # planning as the account clocked them, the rest being host time
+        # inside query.execute outside every exec span plus the glue
+        # between the spans (zero throughout when concurrency-scaled)
+        rem, split = totals["other"], {}
+        for p in ("admit", "plan"):
+            split[p] = min(rem, int(phases.get(p, 0)))
+            rem -= split[p]
+        split["execute"] = rem
+        doc["other_phases"] = {p: round(v / 1e9, 9)
+                               for p, v in split.items()}
     return doc
 
 

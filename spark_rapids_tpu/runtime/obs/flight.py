@@ -77,33 +77,6 @@ class _Ring:
         self.label = label
 
 
-class _FlightSpan:
-    """The hot-path span when tracing is off but the recorder is on:
-    times the block ONCE, feeds the paired GpuMetric (the same
-    NvtxWithMetrics contract trace._Span honors) and stores one ring
-    entry."""
-
-    __slots__ = ("rec", "name", "cat", "metric", "t0")
-
-    def __init__(self, rec: "FlightRecorder", name: str, metric, cat: str):
-        self.rec = rec
-        self.name = name
-        self.cat = cat
-        self.metric = metric
-
-    def __enter__(self):
-        self.t0 = time.perf_counter_ns()
-        return self
-
-    def __exit__(self, *exc):
-        dur = time.perf_counter_ns() - self.t0
-        m = self.metric
-        if m is not None:
-            m.add(dur)
-        self.rec.record(self.name, self.cat, self.t0, dur)
-        return False
-
-
 class FlightRecorder:
     """Process-wide recorder: per-thread rings + the dump machinery."""
 
@@ -136,9 +109,6 @@ class FlightRecorder:
             self._rings.append(r)
         self._tls.ring = r
         return r
-
-    def span(self, name: str, metric, cat: str) -> _FlightSpan:
-        return _FlightSpan(self, name, metric, cat)
 
     def record(self, name: str, cat: str, t0_ns: int, dur_ns: int,
                args: Optional[dict] = None) -> None:

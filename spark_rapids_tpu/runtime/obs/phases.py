@@ -1,0 +1,198 @@
+"""The per-query phase account: where one top-level action's wall went.
+
+`TpuSession.collect` opens a `QueryPhases` for every top-level action and
+times its seams through the one instrumentation point
+(`trace.metric_span`, level ESSENTIAL, category `query`), so each phase is
+at once a span in every trace sink (the `rapids.query.*` annotations of a
+profiler capture among them) and a clock of this account. At the end of
+the epilogue the account becomes one record in the obs ring
+(`obs.recent_queries`), written with no device sync:
+
+    seq, query_id, status     the ring's sequence number, the live id
+    t0_ns, wall_ns            collect entry (perf_counter_ns, the flight
+                              ring's clock) to the record being written
+    phases_ns                 parse     sql.parse: TpuSession.sql, BEFORE
+                                        the action (it rides on the plan;
+                                        0 for a DataFrame-API plan)
+                              admit     query.admit: digest, live
+                                        registration, recorders opened,
+                                        lifecycle admission
+                              plan      query.plan: prepare_execution
+                              execute   query.execute: run_partitions
+                              fetch     query.fetch: per-batch compaction
+                                        and to_arrow (the download, then
+                                        the host building the table);
+                                        INSIDE execute, summed over task
+                                        threads
+                              epilogue  query.epilogue: finish_action and
+                                        _finish_action
+                              unspanned wall_ns - (admit + plan + execute
+                                        + epilogue): the glue between
+    timers_ns                 the exec tree's *Time metrics summed by name
+                              (GpuMetric.peek: host integers, never a
+                              lazy device count), plus the task
+                              accumulators attribution.finish() returns
+                              (semaphore_wait, compile, retry_backoff,
+                              spill), plus deviceWaitTime (below)
+    counters                  keyed_dispatches, upload_bytes (uploadBytes)
+    wall_ms, error_class, finished_unix[, degraded_reason, slo_breach]
+                              what ObsState.last_query always carried
+
+`timers_ns.deviceWaitTime` is the host time blocked on the device where
+the engine itself brings a device value to the host (`device_wait()`):
+the three doors of columnar/batch.py, which are a forced LazyRowCount and
+every other scalar a host decision needs (`host_int`: join and group
+sizes, string widths, ANSI error flags), the counts' bulk fetch
+(`materialize_counts`) and a batch's download (`fetch_batch_host`, under
+every `to_arrow`, so under every query.fetch); and the small arrays the
+sort, the radix group-by and the bounds probe read back
+(exec/tpu_nodes.py). Summed over threads. Still outside it, in
+query.execute's host time: the read-backs of the exchange, window and
+partitioning execs (an `np.asarray`/`device_get` inside one exec).
+Process-wide like `keyed_dispatches`.
+"""
+from __future__ import annotations
+
+import time
+from typing import Dict, Optional
+
+from spark_rapids_tpu.runtime.metrics import (
+    ESSENTIAL, UPLOAD_BYTES, GpuMetric, walk_exec_tree,
+)
+
+#: phase -> the span that times it
+SPANS = {"parse": "sql.parse", "admit": "query.admit",
+         "plan": "query.plan", "execute": "query.execute",
+         "fetch": "query.fetch", "epilogue": "query.epilogue"}
+
+#: records the obs ring keeps (the newest top-level actions)
+RING_SIZE = 256
+
+#: programs started through fuse.fused() closures and compiled.run_stage,
+#: process-wide and monotonic. A plain int bumped without a lock (the
+#: compile_cache._STATS pattern: a lost update costs a count).
+keyed_dispatches = 0
+
+
+#: host nanoseconds inside device_wait() blocks, process-wide and
+#: monotonic like keyed_dispatches
+device_wait_ns = 0
+
+
+class _DeviceWait:
+    """One block in which the host waits for a device value."""
+
+    __slots__ = ("t0",)
+
+    def __enter__(self):
+        self.t0 = time.perf_counter_ns()
+
+    def __exit__(self, *exc):
+        global device_wait_ns
+        device_wait_ns += time.perf_counter_ns() - self.t0
+        return False
+
+
+def device_wait() -> _DeviceWait:
+    return _DeviceWait()
+
+
+def clock(phase: str) -> GpuMetric:
+    return GpuMetric(phase, ESSENTIAL)
+
+
+def span(phase: str, clk: GpuMetric):
+    """The span of `phase`, feeding `clk` (one timed block for both)."""
+    from spark_rapids_tpu.runtime import trace as TR
+    return TR.metric_span(SPANS[phase], clk, cat="query", level=ESSENTIAL)
+
+
+class QueryPhases:
+    """One top-level action's account (module docstring)."""
+
+    __slots__ = ("t0_ns", "parse_ns", "clocks", "dispatches0", "wait0",
+                 "exec_root", "_peeked")
+
+    def __init__(self, plan):
+        self.t0_ns = time.perf_counter_ns()
+        self.parse_ns = int(getattr(plan, "_sql_parse_ns", 0))
+        self.clocks = {p: clock(p) for p in SPANS if p != "parse"}
+        self.dispatches0 = keyed_dispatches
+        self.wait0 = device_wait_ns
+        self.exec_root = None
+        self._peeked: Optional[Dict[str, dict]] = None
+
+    def span(self, phase: str):
+        return span(phase, self.clocks[phase])
+
+    def attach(self, exec_root) -> None:
+        self.exec_root = exec_root
+
+    def phases_ns(self) -> Dict[str, int]:
+        out = {"parse": self.parse_ns}
+        out.update((p, c.peek()) for p, c in self.clocks.items())
+        return out
+
+    def peek_metrics(self) -> Dict[str, dict]:
+        """{exec_key: {metric: value}} of this action's exec tree, in
+        last_metrics() shape but through GpuMetric.peek: the ONE walk of
+        a query's epilogue, shared by attribution and the record."""
+        if self._peeked is None:
+            self._peeked = {} if self.exec_root is None else {
+                key: node.metrics.peek_snapshot()
+                for key, node, _d, _role, _sid in walk_exec_tree(
+                    self.exec_root)}
+        return self._peeked
+
+    def record(self, query_id, status: str, duration_ns: int,
+               error: Optional[BaseException] = None,
+               degraded_reason: Optional[str] = None,
+               extra: Optional[Dict[str, int]] = None) -> dict:
+        """The ring record; `extra` is attribution.finish()'s aggregate."""
+        wall_ns = time.perf_counter_ns() - self.t0_ns
+        phases = self.phases_ns()
+        phases["unspanned"] = wall_ns - sum(
+            phases[p] for p in ("admit", "plan", "execute", "epilogue"))
+        timers: Dict[str, int] = {}
+        upload = 0
+        for snap in self.peek_metrics().values():
+            for name, v in snap.items():
+                if name.endswith("Time"):
+                    timers[name] = timers.get(name, 0) + v
+                elif name == UPLOAD_BYTES:
+                    upload += v
+        for bucket, ns in (extra or {}).items():
+            timers[bucket] = timers.get(bucket, 0) + int(ns)
+        timers["deviceWaitTime"] = device_wait_ns - self.wait0
+        rec = {
+            "seq": None,  # set by the ring
+            "query_id": query_id, "status": status,
+            "t0_ns": self.t0_ns, "wall_ns": wall_ns,
+            "phases_ns": phases, "timers_ns": timers,
+            "counters": {
+                "keyed_dispatches": keyed_dispatches - self.dispatches0,
+                "upload_bytes": upload},
+            "wall_ms": round(duration_ns / 1e6, 3),
+            "error_class": type(error).__name__ if error else None,
+            "finished_unix": time.time(),
+        }
+        if degraded_reason is not None:
+            rec["degraded_reason"] = degraded_reason
+        return rec
+
+
+class _NestedPhases:
+    """A nested collect's account: it stays inside its parent's
+    query.execute, so it times nothing and keeps nothing."""
+
+    __slots__ = ()
+
+    def span(self, phase: str):
+        from spark_rapids_tpu.runtime import trace as TR
+        return TR._NULL
+
+    def attach(self, exec_root) -> None:
+        pass
+
+
+NESTED = _NestedPhases()

@@ -216,35 +216,6 @@ class _ReqSpan:
         return False
 
 
-class _HookSpan:
-    """The engine-span fallback when the flight recorder is off but
-    reqtrace is armed (trace.py's metric_span/exec_span/span hand out
-    this instead of the bare metric timer): times the block once, feeds
-    the paired GpuMetric, and feeds the request ring."""
-
-    __slots__ = ("rec", "name", "cat", "metric", "t0")
-
-    def __init__(self, rec: "ReqTraceRecorder", name: str, metric,
-                 cat: str):
-        self.rec = rec
-        self.name = name
-        self.cat = cat
-        self.metric = metric
-
-    def __enter__(self):
-        self.t0 = time.perf_counter_ns()
-        return self
-
-    def __exit__(self, *exc):
-        dur = time.perf_counter_ns() - self.t0
-        m = self.metric
-        if m is not None:
-            m.add(dur)
-        self.rec.feed(self.name, self.cat, self.t0, dur, None,
-                      _live.current_query_id())
-        return False
-
-
 class ReqTraceRecorder:
     """Process-wide per-request recorder: context minting, the feed hot
     path, the end-of-request verdict, and the export machinery."""
@@ -293,8 +264,12 @@ class ReqTraceRecorder:
         ctx.record(name, cat, t0_ns, dur_ns, args, qid,
                    threading.get_ident() & 0x7FFFFFFF)
 
-    def span(self, name: str, metric, cat: str) -> _HookSpan:
-        return _HookSpan(self, name, metric, cat)
+    def record(self, name: str, cat: str, t0_ns: int, dur_ns: int,
+               args: Optional[dict] = None) -> None:
+        """The ring-sink signature trace.py's one span class calls (the
+        flight recorder's, whose own record() feeds this ring when it is
+        on): feed() tagged with the thread's bound query id."""
+        self.feed(name, cat, t0_ns, dur_ns, args, _live.current_query_id())
 
     def request_span(self, ctx: RequestContext, name: str) -> _ReqSpan:
         return _ReqSpan(ctx, name)

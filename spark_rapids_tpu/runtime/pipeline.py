@@ -266,7 +266,6 @@ class PipelinedIterator:
     # -- consumer side -----------------------------------------------------
 
     def __iter__(self):
-        from spark_rapids_tpu.runtime import trace
         while True:
             self._ensure_refill()
             try:
@@ -281,10 +280,6 @@ class PipelinedIterator:
                 dt = time.perf_counter_ns() - t0
                 if self._stall is not None:
                     self._stall.add(dt)
-                if trace.active() is not None:
-                    trace.instant("pipelineStall", cat="pipeline", args={
-                        "label": self._label, "stall_us": dt / 1000.0},
-                        level=trace.DEBUG)
             if item is _DONE:
                 return
             if isinstance(item, _ProducerError):

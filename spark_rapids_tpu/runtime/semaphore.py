@@ -171,9 +171,6 @@ class TpuSemaphore:
         task_ctx.metric("semaphoreHoldTime").add(
             time.perf_counter_ns() - t_acq)
         self._sem.release(1)
-        if trace.active() is not None:
-            trace.instant("semaphoreRelease", cat="semaphore",
-                          args={"task_id": tid})
 
     @property
     def available(self) -> int:

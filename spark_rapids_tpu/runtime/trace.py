@@ -9,25 +9,33 @@ per-query artifacts under a configured directory), and GpuTaskMetrics
 offline spark-rapids-tools profiling report; tools/profiler_report.py is
 that report's analog here).
 
-Output format: Chrome trace-event JSON (Perfetto / chrome://tracing
-loadable). One track per task thread (tid = task id while a TaskContext
-is bound, thread ident otherwise, named by a thread_name metadata event),
-complete events ("ph":"X") for spans, instant events ("ph":"i") for
-semaphore acquire/release, spill (device→host→disk, bytes), retry and
-split-retry, host-pool queueing, and fused-stage dispatches. Spans also
-forward to jax.profiler.TraceAnnotation so an XProf capture under
-spark.rapids.profile.dir shows the same operator names on its TraceMe
-timeline.
+One instrumentation point, three sinks (`_sinks` resolves them once per
+event; every entry point below goes through it):
 
-Overhead discipline: tracing is OFF by default and the off path is one
-module-global read + branch per span — `metric_span` then returns the
-GpuMetric's own timer (exactly the pre-trace hot path) and `instant`
+- the per-query Tracer (spark.rapids.sql.trace.*: enabled, path, level,
+  taskMetrics — see config.py): Chrome trace-event JSON (Perfetto /
+  chrome://tracing loadable). One track per task thread (tid = task id
+  while a TaskContext is bound, thread ident otherwise, named by a
+  thread_name metadata event), complete events ("ph":"X") for spans,
+  instant events ("ph":"i") for semaphore acquire/release, spill
+  (device→host→disk, bytes), retry and split-retry, host-pool queueing,
+  and fused-stage dispatches;
+- the always-on bounded rings (runtime/obs/flight.py, reqtrace.py);
+- the profiler: while a jax.profiler capture is running (the
+  benchmark's --trace 1, spark.rapids.profile.dir, or anyone's
+  start_trace) every span and instant that passes its level filter
+  opens a jax.profiler.TraceAnnotation named `rapids.<span name>`,
+  whatever spark.rapids.sql.trace.enabled says, so the engine's spans
+  sit on the device trace's clock (an interval handed over after the
+  fact, emit_span, cannot be backdated there and stays off this sink).
+
+Overhead discipline: with no tracer, no ring and no capture a span costs
+its level check, three module-global reads and one
+TraceAnnotation.is_enabled() (about 20 ns) — `metric_span` then returns
+the GpuMetric's own timer (exactly the pre-trace hot path) and `instant`
 returns immediately. Levels reuse the metric levels (ESSENTIAL <
-MODERATE < DEBUG): a span/instant above the configured level costs the
-same as tracing off.
-
-Config surface (spark.rapids.sql.trace.*): enabled, path, level,
-taskMetrics — see config.py.
+MODERATE < DEBUG): a DEBUG event with no DEBUG tracer installed costs
+two reads and no is_enabled().
 """
 from __future__ import annotations
 
@@ -69,6 +77,14 @@ from spark_rapids_tpu.runtime.obs import reqtrace as _reqtrace  # noqa: E402
 # one trace (nested collects, pool threads) stay attributable
 from spark_rapids_tpu.runtime.obs import live as _live  # noqa: E402
 
+#: the profiler sink: while a jax.profiler capture is running, every
+#: span/instant that passes its level filter opens one of these, named
+#: PROFILER_PREFIX + the span's name, so the engine's events sit on the
+#: device trace's clock and a trace reducer picks them with one test
+from jax.profiler import TraceAnnotation as _ANNOTATION  # noqa: E402
+
+PROFILER_PREFIX = "rapids."
+
 _TRACER: "Optional[Tracer]" = None
 _STATE_LOCK = _san.lock("trace.state")
 _QUERY_SEQ = 0
@@ -107,12 +123,6 @@ class Tracer:
         self._events: List[dict] = []
         self._task_records: List[dict] = []
         self._named_tids: set = set()
-        # TraceAnnotation forwarding (XProf interplay): resolved once
-        try:
-            import jax.profiler as _jp
-            self._annotation = _jp.TraceAnnotation
-        except Exception:  # noqa: BLE001 - profiler optional
-            self._annotation = None
 
     # -- clocks ------------------------------------------------------------
 
@@ -238,57 +248,39 @@ class Tracer:
 
 class _Span:
     """A live span: times the block ONCE, feeds the paired GpuMetric (the
-    NvtxWithMetrics contract) and emits a complete event; forwards the
-    range to jax.profiler.TraceAnnotation when available."""
+    NvtxWithMetrics contract) and hands the interval to every sink that
+    `_sinks` resolved for it: the tracer's buffer, the bounded ring, and
+    a `rapids.`-prefixed TraceAnnotation on the profiler's clock."""
 
-    __slots__ = ("tracer", "name", "metric", "cat", "args", "t0", "_ann",
-                 "level")
+    __slots__ = ("sinks", "name", "metric", "cat", "args", "t0", "_ann")
 
-    def __init__(self, tracer: Tracer, name: str, metric, cat: str,
-                 args: Optional[dict], level: int = MODERATE):
-        self.tracer = tracer
+    def __init__(self, sinks: tuple, name: str, metric, cat: str,
+                 args: Optional[dict]):
+        self.sinks = sinks
         self.name = name
         self.metric = metric
         self.cat = cat
-        self.args = dict(args) if args else {}
-        self._ann = None
-        self.level = level
+        self.args = args
 
     def __enter__(self):
-        ann_cls = self.tracer._annotation
-        if ann_cls is not None:
-            try:
-                self._ann = ann_cls(self.name)
-                self._ann.__enter__()
-            except Exception:  # noqa: BLE001 - never fail the query
-                self._ann = None
+        ann = self.sinks[2]
+        if ann is not None:
+            self._ann = ann(PROFILER_PREFIX + self.name)
+            self._ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter_ns() - self.t0
-        if self._ann is not None:
-            try:
-                self._ann.__exit__(*exc)
-            except Exception:  # noqa: BLE001
-                pass
+        tr, ring, ann = self.sinks
+        if ann is not None:
+            self._ann.__exit__(*exc)
         if self.metric is not None:
             self.metric.add(dur)
-        self.tracer.complete(self.name, self.t0, dur, self.cat,
-                             self.args or None)
-        # traced spans also feed the flight ring so a dump taken while
-        # tracing is on still covers the current query — same DEBUG
-        # filter as every other flight entry point, or a DEBUG-level
-        # tracer would flush the bounded ring with serde chatter
-        fr = _flight._REC
-        if fr is not None and self.level < DEBUG:
-            fr.record(self.name, self.cat, self.t0, dur,
-                      self.args or None)
-        elif self.level < DEBUG:
-            rr = _reqtrace._REC
-            if rr is not None:
-                rr.feed(self.name, self.cat, self.t0, dur,
-                        self.args or None, _live.current_query_id())
+        if tr is not None:
+            tr.complete(self.name, self.t0, dur, self.cat, self.args)
+        if ring is not None:
+            ring.record(self.name, self.cat, self.t0, dur, self.args)
         return False
 
 
@@ -300,30 +292,49 @@ def active() -> Optional[Tracer]:
     return _TRACER
 
 
+def _sinks(level: int) -> Optional[tuple]:
+    """THE sink cascade, resolved once per event: (tracer, ring,
+    annotation class) with None for each sink that does not want an
+    event of `level`, or None when nobody does (the caller then takes
+    its pre-trace path).
+
+    - tracer: installed (spark.rapids.sql.trace.enabled) and `level`
+      within its configured level;
+    - ring: the flight recorder, else the per-request recorder while a
+      request is bound (with the flight recorder on, its record() feeds
+      the request ring itself). DEBUG events never enter a bounded ring:
+      serde chatter would flush the interesting events;
+    - annotation: a jax.profiler capture is running. The filter is the
+      tracer's when one is installed, the ring's (below DEBUG) otherwise,
+      whatever spark.rapids.sql.trace.enabled says."""
+    tr = _TRACER
+    if tr is not None and level > tr.level:
+        tr = None
+    ring = None
+    if level < DEBUG:
+        ring = _flight._REC
+        if ring is None:
+            rr = _reqtrace._REC
+            if rr is not None and _live.current_request() is not None:
+                ring = rr
+    elif tr is None:
+        return None
+    ann = _ANNOTATION if _ANNOTATION.is_enabled() else None
+    if tr is None and ring is None and ann is None:
+        return None
+    return tr, ring, ann
+
+
 def metric_span(name: str, metric, cat: str = "exec",
                 args: Optional[dict] = None, level: Optional[int] = None):
     """THE instrumentation point: one timed block feeding both the
-    GpuMetric and the trace. Tracing off (or the event filtered by
-    level) returns the metric's own nanosecond timer — the exact
-    pre-trace hot path."""
-    tr = _TRACER
-    if tr is None or (level if level is not None
-                      else getattr(metric, "level", MODERATE)) > tr.level:
-        fr = _flight._REC
-        if fr is not None and (level if level is not None
-                               else getattr(metric, "level",
-                                            MODERATE)) < DEBUG:
-            return fr.span(name, metric, cat)
-        rr = _reqtrace._REC
-        if fr is None and rr is not None \
-                and (level if level is not None
-                     else getattr(metric, "level", MODERATE)) < DEBUG \
-                and _live.current_request() is not None:
-            return rr.span(name, metric, cat)
+    GpuMetric and the trace. With no sink for its level it returns the
+    metric's own nanosecond timer, the exact pre-trace hot path."""
+    sinks = _sinks(level if level is not None
+                   else getattr(metric, "level", MODERATE))
+    if sinks is None:
         return metric.ns() if metric is not None else _NULL
-    return _Span(tr, name, metric, cat, args,
-                 level=(level if level is not None
-                        else getattr(metric, "level", MODERATE)))
+    return _Span(sinks, name, metric, cat, args)
 
 
 def exec_span(node, metric, name: Optional[str] = None):
@@ -331,73 +342,54 @@ def exec_span(node, metric, name: Optional[str] = None):
     `ExecName.metricName`. Carries the node's lore id when LORE dumping
     is active so a hot span can be replayed with lore.replay (the
     LORE↔trace cross-link)."""
-    tr = _TRACER
-    if tr is None or metric.level > tr.level:
-        fr = _flight._REC
-        if fr is not None and metric.level < DEBUG:
-            return fr.span(name or f"{node.name()}.{metric.name}",
-                           metric, "exec")
-        rr = _reqtrace._REC
-        if fr is None and rr is not None and metric.level < DEBUG \
-                and _live.current_request() is not None:
-            return rr.span(name or f"{node.name()}.{metric.name}",
-                           metric, "exec")
+    sinks = _sinks(metric.level)
+    if sinks is None:
         return metric.ns()
     args = None
-    lid = getattr(node, "lore_id", None)
-    if lid is not None:
-        args = {"lore_id": lid}
-    return _Span(tr, name or f"{node.name()}.{metric.name}", metric,
-                 "exec", args, level=metric.level)
+    if sinks[0] is not None:
+        lid = getattr(node, "lore_id", None)
+        if lid is not None:
+            args = {"lore_id": lid}
+    return _Span(sinks, name or f"{node.name()}.{metric.name}", metric,
+                 "exec", args)
 
 
 def span(name: str, cat: str = "runtime", args: Optional[dict] = None,
          level: int = MODERATE):
-    """Metric-less span (serde, async writes, report-only ranges)."""
-    tr = _TRACER
-    if tr is None or level > tr.level:
-        fr = _flight._REC
-        if fr is not None and level < DEBUG:
-            return fr.span(name, None, cat)
-        rr = _reqtrace._REC
-        if fr is None and rr is not None and level < DEBUG \
-                and _live.current_request() is not None:
-            return rr.span(name, None, cat)
-        return _NULL
-    return _Span(tr, name, None, cat, args, level=level)
+    """Metric-less span (planner passes, report-only ranges)."""
+    sinks = _sinks(level)
+    return _NULL if sinks is None else _Span(sinks, name, None, cat, args)
 
 
 def instant(name: str, cat: str = "runtime", args: Optional[dict] = None,
             level: int = MODERATE) -> None:
-    tr = _TRACER
-    if tr is not None and level <= tr.level:
+    sinks = _sinks(level)
+    if sinks is None:
+        return
+    tr, ring, ann = sinks
+    if tr is not None:
         tr.instant(name, cat, args)
-    fr = _flight._REC
-    if fr is not None and level < DEBUG:
-        fr.instant(name, cat, args)
-    elif level < DEBUG:
-        rr = _reqtrace._REC
-        if rr is not None:
-            rr.feed(name, cat, time.perf_counter_ns(), -1, args,
-                    _live.current_query_id())
+    if ring is not None:
+        ring.record(name, cat, time.perf_counter_ns(), -1, args)
+    if ann is not None:
+        with ann(PROFILER_PREFIX + name):
+            pass  # a marker on the profiler's timeline
 
 
 def emit_span(name: str, t0_ns: int, dur_ns: int, cat: str = "exec",
               args: Optional[dict] = None, level: int = MODERATE) -> None:
     """Record an already-measured interval as a complete event (for call
     sites that must own the timing, e.g. the fused-stage dispatch whose
-    duration also splits across member metrics)."""
-    tr = _TRACER
-    if tr is not None and level <= tr.level:
+    duration also splits across member metrics). Tracer and ring only:
+    the profiler cannot backdate an interval, so it gets none."""
+    sinks = _sinks(level)
+    if sinks is None:
+        return
+    tr, ring, _ann = sinks
+    if tr is not None:
         tr.complete(name, t0_ns, dur_ns, cat, args)
-    fr = _flight._REC
-    if fr is not None and level < DEBUG:
-        fr.record(name, cat, t0_ns, dur_ns, args)
-    elif level < DEBUG:
-        rr = _reqtrace._REC
-        if rr is not None:
-            rr.feed(name, cat, t0_ns, dur_ns, args,
-                    _live.current_query_id())
+    if ring is not None:
+        ring.record(name, cat, t0_ns, dur_ns, args)
 
 
 def on_task_complete(ctx) -> None:

@@ -396,65 +396,56 @@ def serialize_batch(batch: ColumnarBatch, codec: str = "auto") -> bytes:
 
     from spark_rapids_tpu.ops import kernels as K
     from spark_rapids_tpu.columnar.batch import fetch_batch_host
-    from spark_rapids_tpu.runtime import trace as TR
-    with TR.span("shuffle.serialize", cat="shuffle",
-                 level=TR.DEBUG) as sp:
-        if batch.row_mask is not None:
-            batch = K.compact_batch(batch)
-        host = fetch_batch_host(batch)
-        n = int(host.num_rows)
-        planes: List[np.ndarray] = []
-        cols = [_describe_column(c, n, planes) for c in host.columns]
-        meta = json.dumps({"n": n, "cols": cols}).encode()
-        frame = _pack_frame(meta, planes)
-        cid = codec_id(codec)
-        if cid == CODEC_ZSTD:
-            import zstandard
-            payload = zstandard.ZstdCompressor(level=1).compress(frame)
-        elif cid == CODEC_ZLIB:
-            payload = zlib.compress(frame, 1)
-        else:
-            payload = frame
-        head = bytes([cid])
-        crc = zlib.crc32(payload, zlib.crc32(head)) & 0xFFFFFFFF
-        out = head + struct.pack("<I", crc) + payload
-        if sp is not None:
-            sp.args.update(rows=n, frame_bytes=len(frame),
-                           wire_bytes=len(out))
-        return out
+    if batch.row_mask is not None:
+        batch = K.compact_batch(batch)
+    host = fetch_batch_host(batch)
+    n = int(host.num_rows)
+    planes: List[np.ndarray] = []
+    cols = [_describe_column(c, n, planes) for c in host.columns]
+    meta = json.dumps({"n": n, "cols": cols}).encode()
+    frame = _pack_frame(meta, planes)
+    cid = codec_id(codec)
+    if cid == CODEC_ZSTD:
+        import zstandard
+        payload = zstandard.ZstdCompressor(level=1).compress(frame)
+    elif cid == CODEC_ZLIB:
+        payload = zlib.compress(frame, 1)
+    else:
+        payload = frame
+    head = bytes([cid])
+    crc = zlib.crc32(payload, zlib.crc32(head)) & 0xFFFFFFFF
+    out = head + struct.pack("<I", crc) + payload
+    return out
 
 
 def deserialize_batch(data: bytes, verify: bool = True) -> ColumnarBatch:
     """Wire bytes -> device batch (planes re-padded to capacity buckets)."""
     import zlib
 
-    from spark_rapids_tpu.runtime import trace as TR
-    with TR.span("shuffle.deserialize", cat="shuffle", level=TR.DEBUG,
-                 args={"wire_bytes": len(data)}):
-        if len(data) < _WIRE_HEADER:
+    if len(data) < _WIRE_HEADER:
+        raise ShuffleCorruptionError(
+            f"short shuffle blob ({len(data)} bytes)")
+    cid = data[0]
+    (want,) = struct.unpack_from("<I", data, 1)
+    payload = data[_WIRE_HEADER:]
+    if verify:
+        got = zlib.crc32(payload, zlib.crc32(data[:1])) & 0xFFFFFFFF
+        if got != want:
             raise ShuffleCorruptionError(
-                f"short shuffle blob ({len(data)} bytes)")
-        cid = data[0]
-        (want,) = struct.unpack_from("<I", data, 1)
-        payload = data[_WIRE_HEADER:]
-        if verify:
-            got = zlib.crc32(payload, zlib.crc32(data[:1])) & 0xFFFFFFFF
-            if got != want:
-                raise ShuffleCorruptionError(
-                    f"shuffle blob CRC mismatch (stored {want:#010x}, "
-                    f"computed {got:#010x}, {len(data)} wire bytes)")
-        if cid == CODEC_ZSTD:
-            import zstandard
-            frame = zstandard.ZstdDecompressor().decompress(payload)
-        elif cid == CODEC_ZLIB:
-            frame = zlib.decompress(payload)
-        elif cid == CODEC_NONE:
-            frame = payload
-        else:
-            raise ValueError(f"unknown codec id {cid}")
-        meta, bufs = _unpack_frame(frame, verify=verify)
-        desc = json.loads(meta.decode())
-        n = desc["n"]
-        cap = round_capacity(max(n, 1))
-        cols = [_rebuild_column(d, bufs, n, cap) for d in desc["cols"]]
-        return ColumnarBatch(cols, n)
+                f"shuffle blob CRC mismatch (stored {want:#010x}, "
+                f"computed {got:#010x}, {len(data)} wire bytes)")
+    if cid == CODEC_ZSTD:
+        import zstandard
+        frame = zstandard.ZstdDecompressor().decompress(payload)
+    elif cid == CODEC_ZLIB:
+        frame = zlib.decompress(payload)
+    elif cid == CODEC_NONE:
+        frame = payload
+    else:
+        raise ValueError(f"unknown codec id {cid}")
+    meta, bufs = _unpack_frame(frame, verify=verify)
+    desc = json.loads(meta.decode())
+    n = desc["n"]
+    cap = round_capacity(max(n, 1))
+    cols = [_rebuild_column(d, bufs, n, cap) for d in desc["cols"]]
+    return ColumnarBatch(cols, n)

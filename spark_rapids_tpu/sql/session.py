@@ -145,13 +145,18 @@ class TpuSession:
     def sql(self, query: str):
         """Run a SQL string over registered temp views (the analytic
         subset grammar — sql/parser.py)."""
+        from spark_rapids_tpu.runtime.obs import phases as PH
         from spark_rapids_tpu.sql.parser import parse_sql
-        df = parse_sql(query, self)
+        parse_clk = PH.clock("parse")
+        with PH.span("parse", parse_clk):
+            df = parse_sql(query, self)
         try:
             # the replayable spec: history records carry the SQL text so
             # AOT warmup (runtime/warmup.py) can re-execute recurring
             # plans at session start
             df.plan._sql_text = query
+            # and the parse's nanoseconds, for the action's phase account
+            df.plan._sql_parse_ns = parse_clk.peek()
         except Exception:  # noqa: BLE001 - a slotted plan node just
             pass  # isn't warmup-replayable
         return df
@@ -297,6 +302,7 @@ class TpuSession:
         from spark_rapids_tpu.runtime import lifecycle as LC
         from spark_rapids_tpu.runtime import obs as OBS
         from spark_rapids_tpu.runtime import trace as TR
+        from spark_rapids_tpu.runtime.obs import phases as PH
         # structured trace per action (spark.rapids.sql.trace.*): spans +
         # instants + the task event log, finalized with this action's
         # metrics snapshot so the offline report can reconcile the two.
@@ -310,70 +316,74 @@ class TpuSession:
             # paths looking like this one's. A same-session outer collect
             # restores its own paths when it finalizes.
             self.last_trace_paths = None
-        # live-observability token: None when obs is off or this is a
-        # nested collect (only top-level actions publish + make history).
-        # The digest is computed UP FRONT (a cheap logical-tree hash) so
-        # the live registry and the queryStart marker can carry it while
-        # the query is still running — a hung query's flight dump needs
-        # its t0 and identity without waiting for the epilogue
-        start_digest = None
-        if getattr(_COLLECT_DEPTH, "d", 0) == 0:
-            try:
-                start_digest = OBS.plan_digest(plan)
-            except Exception:  # noqa: BLE001 - an undigestable plan
-                pass  # still runs and registers
-        ot = OBS.on_query_start(plan_digest=start_digest,
-                                sql=getattr(plan, "_sql_text", None))
-        if getattr(_COLLECT_DEPTH, "d", 0) == 0:
-            # queryStart instant for EVERY top-level action, traced or
-            # not (the flight ring records it too): ring timelines of a
-            # hung or failed query get a t0 marker with the query's
-            # identity, pairing with the queryError/queryDegraded
-            # epilogue markers
-            try:
-                TR.instant("queryStart", cat="query", args={
-                    "query_id": ot if isinstance(ot, int) else None,
-                    "plan_digest": start_digest},
-                    level=TR.ESSENTIAL)
-            except Exception:  # noqa: BLE001 - a marker failure must
-                pass  # not fail the query
-
-        if qt is not None or (ot is not None and ot is not OBS.NESTED):
-            # drop the PREVIOUS action's exec tree before this one runs:
-            # a failure before convert_plan rebuilds it must publish
-            # nothing — republishing the old tree's (unchanged) metrics
-            # would double the registry counters and attach the previous
-            # query's plan to this query's history record
-            self._last_exec = None
-            self._last_meta = None
-        t0 = _time.perf_counter_ns()
-        wall0 = _time.time()
-        error: Optional[BaseException] = None
-        status = "ok"
-        degraded_reason: Optional[str] = None
-        cancel_reason: Optional[str] = None
-        tok = None  # this action's CancelToken (top-level only)
-        # degradation is a TOP-LEVEL policy: a nested collect (broadcast
-        # materialization inside a running device query) must propagate
-        # its failure to the outer query, which then degrades whole
+        # degradation is a TOP-LEVEL policy, and so is the phase account
+        # (runtime/obs/phases.py): a nested collect (broadcast
+        # materialization inside a running device query) propagates its
+        # failure to the outer query, which then degrades whole, and its
+        # time stays inside the outer query's query.execute
         depth = getattr(_COLLECT_DEPTH, "d", 0)
-        _COLLECT_DEPTH.d = depth + 1
-        if depth == 0:
-            # open the per-query attribution aggregate (compile timing,
-            # task accumulators) — runs regardless of obs state so
-            # explain(mode="analyze") always has a breakdown
-            from spark_rapids_tpu.runtime.obs import attribution as ATTR
-            ATTR.on_query_start()
-            # and the kernel cost auditor's dispatch tally (one global
-            # read when the audit is off; the conf rides along so a
-            # mid-session enable covers THIS query)
-            from spark_rapids_tpu.analysis import kernel_audit as KA
-            KA.on_query_start(self.conf)
-            # and the adaptive decision recorder: every AQE decision this
-            # query makes (conversion, skew split, build reuse, measured
-            # cost) lands in one per-query doc
-            from spark_rapids_tpu.exec import adaptive as AQ
-            AQ.on_query_start(self.conf)
+        ph = PH.QueryPhases(plan) if depth == 0 else PH.NESTED
+        with ph.span("admit"):
+            # live-observability token: None when obs is off or this is a
+            # nested collect (only top-level actions publish + make history).
+            # The digest is computed UP FRONT (a cheap logical-tree hash) so
+            # the live registry and the queryStart marker can carry it while
+            # the query is still running — a hung query's flight dump needs
+            # its t0 and identity without waiting for the epilogue
+            start_digest = None
+            if depth == 0:
+                try:
+                    start_digest = OBS.plan_digest(plan)
+                except Exception:  # noqa: BLE001 - an undigestable plan
+                    pass  # still runs and registers
+            ot = OBS.on_query_start(plan_digest=start_digest,
+                                    sql=getattr(plan, "_sql_text", None))
+            if depth == 0:
+                # queryStart instant for EVERY top-level action, traced or
+                # not (the flight ring records it too): ring timelines of a
+                # hung or failed query get a t0 marker with the query's
+                # identity, pairing with the queryError/queryDegraded
+                # epilogue markers
+                try:
+                    TR.instant("queryStart", cat="query", args={
+                        "query_id": ot if isinstance(ot, int) else None,
+                        "plan_digest": start_digest},
+                        level=TR.ESSENTIAL)
+                except Exception:  # noqa: BLE001 - a marker failure must
+                    pass  # not fail the query
+
+            if qt is not None or (ot is not None and ot is not OBS.NESTED):
+                # drop the PREVIOUS action's exec tree before this one runs:
+                # a failure before convert_plan rebuilds it must publish
+                # nothing — republishing the old tree's (unchanged) metrics
+                # would double the registry counters and attach the previous
+                # query's plan to this query's history record
+                self._last_exec = None
+                self._last_meta = None
+            t0 = _time.perf_counter_ns()
+            wall0 = _time.time()
+            error: Optional[BaseException] = None
+            status = "ok"
+            degraded_reason: Optional[str] = None
+            cancel_reason: Optional[str] = None
+            tok = None  # this action's CancelToken (top-level only)
+            _COLLECT_DEPTH.d = depth + 1
+            if depth == 0:
+                # open the per-query attribution aggregate (compile timing,
+                # task accumulators) — runs regardless of obs state so
+                # explain(mode="analyze") always has a breakdown
+                from spark_rapids_tpu.runtime.obs import attribution as ATTR
+                ATTR.on_query_start()
+                # and the kernel cost auditor's dispatch tally (one global
+                # read when the audit is off; the conf rides along so a
+                # mid-session enable covers THIS query)
+                from spark_rapids_tpu.analysis import kernel_audit as KA
+                KA.on_query_start(self.conf)
+                # and the adaptive decision recorder: every AQE decision this
+                # query makes (conversion, skew split, build reuse, measured
+                # cost) lands in one per-query doc
+                from spark_rapids_tpu.exec import adaptive as AQ
+                AQ.on_query_start(self.conf)
         cpu_gate_failed = False
         try:
             if depth == 0:
@@ -385,20 +395,21 @@ class TpuSession:
                 # when spark.rapids.query.maxConcurrent is saturated —
                 # raising QueryRejectedError (queue full / wait timeout)
                 # or QueryCancelledError (cancelled while queued)
-                tok = LC.begin_action(
-                    ot if isinstance(ot, int) else None, self.conf,
-                    timeout_seconds=timeout_seconds)
-                LC.admit(tok, self.conf)
-                if isinstance(ot, int):
-                    try:
-                        from spark_rapids_tpu.runtime.obs import (
-                            live as _live,
-                        )
-                        qc = _live.get(ot)
-                        if qc is not None:
-                            qc.transition("planning")
-                    except Exception:  # noqa: BLE001 - registry is
-                        pass  # advisory
+                with ph.span("admit"):
+                    tok = LC.begin_action(
+                        ot if isinstance(ot, int) else None, self.conf,
+                        timeout_seconds=timeout_seconds)
+                    LC.admit(tok, self.conf)
+                    if isinstance(ot, int):
+                        try:
+                            from spark_rapids_tpu.runtime.obs import (
+                                live as _live,
+                            )
+                            qc = _live.get(ot)
+                            if qc is not None:
+                                qc.transition("planning")
+                        except Exception:  # noqa: BLE001 - registry is
+                            pass  # advisory
             if depth == 0 and self._fallback_enabled():
                 from spark_rapids_tpu.runtime import watchdog as WD
                 brk = WD.peek_breaker()
@@ -427,9 +438,9 @@ class TpuSession:
                 # this capture so both timelines share operator names
                 import jax
                 with jax.profiler.trace(prof_dir):
-                    result = self._collect_inner(plan)
+                    result = self._collect_inner(plan, ph)
             else:
-                result = self._collect_inner(plan)
+                result = self._collect_inner(plan, ph)
             if depth == 0:
                 self._record_device_success()
             return result
@@ -452,22 +463,37 @@ class TpuSession:
             return fallback
         finally:
             _COLLECT_DEPTH.d = depth
-            #: (status, reason) of the most recent top-level action —
-            #: ok / failed / degraded / cancelled (chaos + serving
-            #: callers read this without needing the obs registry)
-            if depth == 0:
-                self.last_action_status = (
-                    status, degraded_reason or cancel_reason)
-                # the token leaves the registry BEFORE the epilogue so
-                # metric snapshots / history writes can never re-raise
-                # the cancel; its admission slot releases here too
-                LC.finish_action(tok, status)
-            self._finish_action(plan, qt, ot, error,
-                                _time.perf_counter_ns() - t0, wall0,
-                                status=status,
-                                degraded_reason=degraded_reason,
-                                cancel_reason=cancel_reason,
-                                top_level=depth == 0)
+            duration_ns = _time.perf_counter_ns() - t0
+            with ph.span("epilogue"):
+                #: (status, reason) of the most recent top-level action —
+                #: ok / failed / degraded / cancelled (chaos + serving
+                #: callers read this without needing the obs registry)
+                if depth == 0:
+                    self.last_action_status = (
+                        status, degraded_reason or cancel_reason)
+                    # the token leaves the registry BEFORE the epilogue so
+                    # metric snapshots / history writes can never re-raise
+                    # the cancel; its admission slot releases here too
+                    LC.finish_action(tok, status)
+                self._finish_action(plan, qt, ot, error, duration_ns,
+                                    wall0, status=status,
+                                    degraded_reason=degraded_reason,
+                                    cancel_reason=cancel_reason,
+                                    top_level=depth == 0, phases=ph)
+            if depth == 0 and isinstance(ot, int):
+                # the epilogue's last line: this action's record enters
+                # the obs ring (host integers only, no device sync)
+                try:
+                    OBS.publish_query_record(ph.record(
+                        ot, status, duration_ns, error=error,
+                        degraded_reason=degraded_reason,
+                        extra=getattr(self, "_last_attr_extra", None)))
+                except Exception:  # noqa: BLE001 - observability must
+                    # never fail (or mask the real error of) a query
+                    import logging
+                    logging.getLogger("spark_rapids_tpu").warning(
+                        "failed to record the query's phase account",
+                        exc_info=True)
 
     def _fallback_enabled(self) -> bool:
         return bool(self.conf.get(C.FALLBACK_CPU_ENABLED))
@@ -540,7 +566,7 @@ class TpuSession:
                        wall0, status: Optional[str] = None,
                        degraded_reason: Optional[str] = None,
                        cancel_reason: Optional[str] = None,
-                       top_level: bool = False) -> None:
+                       top_level: bool = False, phases=None) -> None:
         """Query epilogue: finalize the trace (success OR failure),
         compute the wall-time attribution, trigger a flight-recorder
         dump on failure/degradation, and publish the action to the live
@@ -600,13 +626,18 @@ class TpuSession:
                 self._last_attr_extra = None
             self._last_duration_ns = duration_ns
             self._last_attribution = None
-            if lm is not None:
-                try:
-                    self._last_attribution = ATTR.attribute(
-                        lm, duration_ns, extra=self._last_attr_extra)
-                except Exception:  # noqa: BLE001
-                    log.warning("failed to attribute query time",
-                                exc_info=True)
+            # attributed for EVERY query, from the phase account's peeked
+            # timers (host integers: no lazy count resolves, unlike the
+            # snapshot above), so last_attribution() and /healthz have a
+            # breakdown whether or not a snapshot consumer exists
+            try:
+                self._last_attribution = ATTR.attribute(
+                    phases.peek_metrics(), duration_ns,
+                    extra=self._last_attr_extra,
+                    phases=phases.phases_ns())
+            except Exception:  # noqa: BLE001
+                log.warning("failed to attribute query time",
+                            exc_info=True)
             # kernel cost audit: close the dispatch tally, resolve any
             # pending cost analyses (trace-time audits deferred off the
             # dispatch path), and join with the attribution's device
@@ -731,7 +762,7 @@ class TpuSession:
             out.extend(res)
         return out
 
-    def _collect_inner(self, plan: P.PlanNode) -> pa.Table:
+    def _collect_inner(self, plan: P.PlanNode, ph) -> pa.Table:
         if self.conf.get(C.SQL_MODE).lower() == "explainonly":
             # plan + tag + report only; execution stays on the CPU backend
             # with no device required (reference RapidsConf "explainOnly")
@@ -745,7 +776,9 @@ class TpuSession:
             logging.getLogger("spark_rapids_tpu").info(
                 "\n%s", meta.explain(all_ops=True))
             return execute_cpu(plan, self.conf.get(C.ANSI_ENABLED))
-        exec_root, meta = self.prepare_execution(plan)
+        with ph.span("plan"):
+            exec_root, meta = self.prepare_execution(plan)
+        ph.attach(exec_root)
         explain_mode = self.conf.get(C.SQL_EXPLAIN).upper()
         if explain_mode in ("NOT_ON_TPU", "ALL"):
             text = meta.explain(all_ops=explain_mode == "ALL")
@@ -758,12 +791,14 @@ class TpuSession:
             # compact sparse masked batches ON DEVICE before the download:
             # the transfer moves full planes, and a bucket-agg output can
             # be a few-percent-occupied 4M-capacity batch
-            if b.row_mask is not None and b.capacity > 16384:
-                from spark_rapids_tpu.ops import kernels as K
-                b = K.compact_batch(b)
-            return to_arrow(b, names)
+            with ph.span("fetch"):
+                if b.row_mask is not None and b.capacity > 16384:
+                    from spark_rapids_tpu.ops import kernels as K
+                    b = K.compact_batch(b)
+                return to_arrow(b, names)
 
-        tables = self.run_partitions(exec_root, fetch)
+        with ph.span("execute"):
+            tables = self.run_partitions(exec_root, fetch)
         if not tables:
             fields = [pa.field(f.name, T.to_arrow(f.dtype))
                       for f in plan.schema.fields]

@@ -77,10 +77,12 @@ def test_ring_is_bounded_and_keeps_newest(tmp_path):
 
 
 def test_flight_span_feeds_metric_and_ring(tmp_path):
-    rec = flight.FlightRecorder(capacity=64, out_dir=str(tmp_path),
-                                min_interval_s=0.0)
+    # the recorder has no span class of its own: trace.py's one span is
+    # what feeds the paired metric and the ring
+    from spark_rapids_tpu.runtime import trace
+    rec = flight.install(capacity=64, out_dir=str(tmp_path))
     m = GpuMetric("opTime")
-    with rec.span("Exec.opTime", m, "exec"):
+    with trace.metric_span("Exec.opTime", m, "exec"):
         time.sleep(0.002)
     assert m.value >= 2_000_000  # the paired GpuMetric still times
     events = PR.validate_chrome_trace(rec.dump("test"))

@@ -636,3 +636,170 @@ def test_healthz_endpoint_free_port_scrape_via_urllib():
     req = urllib.request.Request(f"http://127.0.0.1:{port}/metrics")
     with urllib.request.urlopen(req, timeout=5) as r:
         assert "text/plain" in r.headers["Content-Type"]
+
+
+# ---------------------------------------------------------------------------
+# the per-query phase account (runtime/obs/phases.py) and its ring
+# ---------------------------------------------------------------------------
+
+_WALL_PHASES = ("admit", "plan", "execute", "epilogue", "unspanned")
+
+
+def test_recent_queries_one_record_per_top_level_action():
+    from spark_rapids_tpu.sql.session import nested_action_scope
+    s = TpuSession()
+    s.create_or_replace_temp_view("t", s.create_dataframe(_table()))
+    assert obs.recent_queries() == []
+    s.sql("select k, sum(v) as sv from t where v > 10 group by k").collect()
+    _query(s)
+    with nested_action_scope():  # as a broadcast materialization runs
+        _query(s)
+    recs = obs.recent_queries()
+    assert [r["seq"] for r in recs] == [1, 2]
+    assert all(r["status"] == "ok" for r in recs)
+    assert obs.recent_queries(1) == recs[1:]
+    assert obs.state().last_query is recs[-1]
+    assert obs.healthz()["queries"]["last_completed"]["seq"] == 2
+    for r in recs:
+        p = r["phases_ns"]
+        assert set(p) == {"parse", "fetch", *_WALL_PHASES}
+        # the phases of the action and the glue between them ARE its wall
+        assert sum(p[k] for k in _WALL_PHASES) == r["wall_ns"]
+        assert all(p[k] > 0 for k in ("admit", "plan", "execute", "fetch",
+                                      "epilogue"))
+        assert p["unspanned"] >= 0
+        assert r["wall_ns"] >= r["wall_ms"] * 1e6 * 0.999  # + the epilogue
+        assert r["timers_ns"]["copyToDeviceTime"] > 0
+        assert set(r["counters"]) == {"keyed_dispatches", "upload_bytes"}
+        assert r["counters"]["upload_bytes"] > 0  # the in-memory scan's
+    # the parse rides on the plan: a SQL action has it, a DataFrame's not
+    assert recs[0]["phases_ns"]["parse"] > 0
+    assert recs[1]["phases_ns"]["parse"] == 0
+    # query.fetch is inside query.execute (one partition: one thread)
+    assert recs[0]["phases_ns"]["fetch"] <= recs[0]["phases_ns"]["execute"]
+
+
+def test_device_wait_is_the_time_in_the_download_doors():
+    """timers_ns.deviceWaitTime: a scalar a host decision needs (a forced
+    LazyRowCount among them), the counts' bulk fetch, a batch's download
+    (so the one inside every query.fetch)."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar.batch import (
+        ColumnarBatch, LazyRowCount, fetch_batch_host, materialize_counts)
+    from spark_rapids_tpu.runtime.obs import phases
+    s = TpuSession()
+    s.create_or_replace_temp_view("t", s.create_dataframe(_table()))
+    s.sql("select k, sum(v) as sv from t where v > 10 group by k").collect()
+    rec = obs.recent_queries(1)[0]
+    wait, p = rec["timers_ns"]["deviceWaitTime"], rec["phases_ns"]
+    assert 0 < wait <= p["execute"]  # one partition: one thread
+
+    def grows(door):
+        before = phases.device_wait_ns
+        door()
+        return phases.device_wait_ns - before
+
+    from spark_rapids_tpu.columnar.batch import host_int
+    assert grows(lambda: host_int(jnp.asarray(9))) > 0
+    n = LazyRowCount(jnp.asarray(7))
+    assert grows(lambda: int(n)) > 0 and int(n) == 7
+    assert grows(lambda: int(n)) == 0  # forced once
+    lazy = ColumnarBatch([], LazyRowCount(jnp.asarray(5)))
+    assert grows(lambda: materialize_counts([lazy])) > 0
+    assert grows(lambda: materialize_counts([lazy])) == 0
+    assert grows(lambda: fetch_batch_host(
+        ColumnarBatch([], LazyRowCount(jnp.asarray(3))))) > 0
+
+
+def test_a_breaching_query_says_so_in_its_record():
+    s = TpuSession({"spark.rapids.obs.slo.latencySeconds": "0.000001"})
+    _query(s)
+    assert obs.recent_queries(1)[0]["slo_breach"] is True
+    assert obs.healthz()["queries"]["last_completed"]["slo_breach"] is True
+    s2 = TpuSession({"spark.rapids.obs.slo.latencySeconds": "1000"})
+    _query(s2)
+    assert "slo_breach" not in obs.recent_queries(1)[0]
+
+
+def test_ring_is_bounded_to_the_newest():
+    from spark_rapids_tpu.runtime.obs import phases
+    TpuSession()  # installs obs
+    for i in range(phases.RING_SIZE + 5):
+        obs.publish_query_record({"i": i, "query_id": i})
+    recs = obs.recent_queries()
+    assert len(recs) == phases.RING_SIZE
+    assert recs[0]["i"] == 5 and recs[-1]["seq"] == phases.RING_SIZE + 5
+    assert [r["i"] for r in obs.recent_queries(2)] == [
+        phases.RING_SIZE + 3, phases.RING_SIZE + 4]
+
+
+def test_keyed_dispatches_equals_the_dispatch_hook():
+    from spark_rapids_tpu.exec import fuse
+    s = TpuSession()
+    t = _table()
+    _query(s, t)  # compile
+    hooked = []
+    fuse.set_dispatch_hook(hooked.append)
+    try:
+        _query(s, t)
+    finally:
+        fuse.set_dispatch_hook(None)
+    with_hook = obs.recent_queries(1)[0]["counters"]["keyed_dispatches"]
+    assert with_hook == len(hooked) > 0
+    # and the hook-less closure (the production path) counts the same
+    _query(s, t)
+    assert obs.recent_queries(1)[0]["counters"]["keyed_dispatches"] == \
+        with_hook
+
+
+def test_writing_the_record_resolves_no_lazy_count(monkeypatch):
+    """The epilogue of a query nobody scrapes (no endpoint, no history,
+    no tracer) stays sync-free: the record, and the attribution it now
+    always feeds, read timers through peek()."""
+    from spark_rapids_tpu.runtime.metrics import GpuMetric, walk_exec_tree
+    resolved = []
+    value = GpuMetric.value.fget
+
+    def spying(self):
+        if self._deferred:
+            resolved.append(self.name)
+        return value(self)
+
+    monkeypatch.setattr(GpuMetric, "value", property(spying))
+    s = TpuSession()
+    _query(s)
+    assert len(obs.recent_queries()) == 1
+    assert s._last_attribution is not None
+    assert resolved == []
+    # not vacuous: the tree does hold counts nobody fetched
+    assert any(m._deferred for _k, node, *_ in walk_exec_tree(s._last_exec)
+               for m in node.metrics.metrics.values())
+
+
+def test_attribution_for_every_query_with_other_split():
+    s = TpuSession()
+    s.create_or_replace_temp_view("t", s.create_dataframe(_table()))
+    assert not obs.wants_rollups()  # no snapshot consumer
+    s.sql("select k, sum(v) as sv from t where v > 10 group by k").collect()
+    doc = s.last_attribution()
+    assert doc is s._last_attribution
+    assert abs(sum(doc["buckets"].values()) - doc["wall_seconds"]) < 1e-6
+    # `other` split by the phases no exec timer covers, in clock order
+    split, other = doc["other_phases"], doc["buckets"]["other"]
+    assert set(split) == {"admit", "plan", "execute"}
+    assert abs(sum(split.values()) - other) < 1e-6
+    p = obs.recent_queries(1)[0]["phases_ns"]
+    admit = min(p["admit"] / 1e9, other)
+    assert split["admit"] == pytest.approx(admit, abs=1e-8)
+    assert split["plan"] == pytest.approx(
+        min(p["plan"] / 1e9, other - admit), abs=1e-8)
+    # and it reaches /metrics without an endpoint or a history store
+    snap = obs.state().registry.snapshot()
+    assert snap['rapids_query_seconds_bucket{phase="device_compute"}'] > 0
+
+
+def test_obs_off_keeps_no_ring():
+    s = TpuSession({"spark.rapids.obs.enabled": "false"})
+    _query(s)
+    assert obs.state() is None and obs.recent_queries() == []
+    assert s.last_attribution() is not None  # the account still feeds it

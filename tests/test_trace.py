@@ -371,3 +371,118 @@ def test_disabled_path_returns_plain_metric_timer():
     assert isinstance(cm, _Timer), "disabled path must be the raw timer"
     assert isinstance(trace.span("y"), trace._NullSpan)
     trace.instant("z")  # must be a no-op, not an error
+
+
+# ---------------------------------------------------------------------------
+# the profiler sink: spans on the device trace's clock
+# ---------------------------------------------------------------------------
+
+def _sql_session(**conf):
+    s = TpuSession({"spark.rapids.sql.reader.batchSizeRows": "1024", **conf})
+    s.create_or_replace_temp_view("t", s.create_dataframe(_table()))
+    return s
+
+
+_SQL = "select k, sum(v) as sv from t where v > 10 group by k"
+
+
+def test_profiler_capture_holds_engine_spans_with_tracing_off(tmp_path):
+    """spark.rapids.sql.trace.enabled stays off: a running jax.profiler
+    capture alone puts the phase spans and the exec spans, `rapids.`-
+    prefixed, in the host plane of the profiler's own trace."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    s = _sql_session()
+    s.sql(_SQL).collect()  # compile outside the capture
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        s.sql(_SQL).collect()
+    finally:
+        jax.profiler.stop_trace()
+    assert s.last_trace_paths is None and trace.active() is None
+    files = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = ProfileData.from_file(max(files, key=os.path.getmtime))
+    names = {e.name for plane in data.planes
+             if not plane.name.startswith("/device:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith(trace.PROFILER_PREFIX)}
+    for phase in ("sql.parse", "query.admit", "query.plan", "query.execute",
+                  "query.fetch", "query.epilogue", "queryStart"):
+        assert "rapids." + phase in names, (phase, sorted(names))
+    execs = {n for n in names if n.endswith("Time")}
+    assert execs, sorted(names)  # at least one rapids.<Exec>.<metric>
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_no_capture_constructs_no_annotation(tmp_path, monkeypatch, traced):
+    """With no capture running a span costs one is_enabled() check: no
+    TraceAnnotation is constructed, tracer installed or not."""
+    built = []
+
+    class _Probe:
+        def __init__(self, name, **kw):
+            built.append(name)
+
+        @staticmethod
+        def is_enabled():
+            return False
+
+    monkeypatch.setattr(trace, "_ANNOTATION", _Probe)
+    conf = {"spark.rapids.sql.trace.enabled": "true",
+            "spark.rapids.sql.trace.path": str(tmp_path)} if traced else {}
+    s = _sql_session(**conf)
+    s.sql(_SQL).collect()
+    assert (s.last_trace_paths is not None) == traced
+    assert built == []
+
+
+def test_capture_annotates_every_entry_point(monkeypatch):
+    """One resolver, so one place opens the annotation: span, metric_span,
+    exec_span and instant all do, by the `rapids.` name, with no tracer
+    and no ring installed; a DEBUG event does not, nor does emit_span (an
+    interval measured earlier cannot be backdated on the profiler)."""
+    from spark_rapids_tpu.runtime.metrics import GpuMetric
+    opened = []
+
+    class _Probe:
+        def __init__(self, name, **kw):
+            self.name, self.kw = name, kw
+
+        def __enter__(self):
+            opened.append((self.name, self.kw))
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        @staticmethod
+        def is_enabled():
+            return True
+
+    class _Node:
+        lore_id = None
+
+        def name(self):
+            return "XExec"
+
+    monkeypatch.setattr(trace, "_ANNOTATION", _Probe)
+    assert trace.active() is None
+    m = GpuMetric("opTime")
+    with trace.span("a"):
+        pass
+    with trace.metric_span("b", m):
+        pass
+    with trace.exec_span(_Node(), m):
+        pass
+    trace.instant("c")
+    trace.emit_span("d", 0, 7)
+    with trace.span("quiet", level=trace.DEBUG):
+        pass
+    assert opened == [("rapids.a", {}), ("rapids.b", {}),
+                      ("rapids.XExec.opTime", {}), ("rapids.c", {})]
+    assert m.value > 0  # the paired metric still times

@@ -144,6 +144,23 @@ def gen_metrics_md() -> str:
     ]
     for name, doc in BUCKETS.items():
         lines.append(f"| `{name}` | {doc} |")
+    from spark_rapids_tpu.runtime.obs import phases as PH
+    lines += [
+        "",
+        "## Query phases (runtime/obs/phases.py SPANS)",
+        "",
+        "The seams of a top-level action, each one span (level ESSENTIAL,",
+        "category `query`; `rapids.<span>` in a profiler capture) and one",
+        "clock of the per-query phase account, whose records",
+        "`obs.recent_queries()` returns (newest " + str(PH.RING_SIZE) + ";",
+        "`phases_ns`, `timers_ns`, `counters`: the module's docstring says",
+        "what each holds).",
+        "",
+        "| Phase | Span |",
+        "|---|---|",
+    ]
+    for phase, span_name in PH.SPANS.items():
+        lines.append(f"| `{phase}` | `{span_name}` |")
     from spark_rapids_tpu.runtime.faults import BYTE_SITES, KINDS, SITES
     lines += [
         "",

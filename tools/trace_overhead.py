@@ -25,6 +25,22 @@ trace-ENABLED run must produce Chrome-trace-event JSON that validates
 
 Run:  python tools/trace_overhead.py [--rows 400000] [--batch 2048]
                                      [--reps 9] [--tolerance 0.02]
+
+`--query` reports instead what the instrumentation costs ONE
+resident-shaped query (a cached table through TpuSession.sql, Q6's
+shape) under a default session with no profiler capture running: the
+entry-point calls of one warm query times each entry point's per-call
+cost on that path (the flight ring on, as it is by default), plus what
+the per-query phase account (runtime/obs/phases.py) adds to every query
+beside its spans, timed in a loop over the finished query's exec tree:
+the account's clocks, the one peek walk, the attribution it now feeds
+for every query with its /metrics counters, the record and the ring, and
+the device_wait() blocks of the query. It uses the public entry points
+only, so a copy of this file under the parent's tools/ gives the figure
+before a change to the instrumentation (where the package has no
+account, that part reads 0):
+
+    python tools/trace_overhead.py --query
 """
 from __future__ import annotations
 
@@ -42,16 +58,15 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
-import bench_fusion as BF  # noqa: E402
-
 _ENTRY_POINTS = ("exec_span", "metric_span", "span", "instant")
+_QUERY_ENTRY_POINTS = _ENTRY_POINTS + ("emit_span",)
 
 
-def _count_calls(trace, drive):
+def _count_calls(trace, drive, names=_ENTRY_POINTS):
     """One drive with counting wrappers on the instrumentation entry
     points (tracing stays disabled; the wrappers call through)."""
-    counts = {n: 0 for n in _ENTRY_POINTS}
-    saved = {n: getattr(trace, n) for n in _ENTRY_POINTS}
+    counts = {n: 0 for n in names}
+    saved = {n: getattr(trace, n) for n in names}
 
     def wrap(name):
         inner = saved[name]
@@ -62,11 +77,11 @@ def _count_calls(trace, drive):
         return counted
 
     try:
-        for n in _ENTRY_POINTS:
+        for n in names:
             setattr(trace, n, wrap(n))
         drive()
     finally:
-        for n in _ENTRY_POINTS:
+        for n in names:
             setattr(trace, n, saved[n])
     return counts
 
@@ -84,11 +99,8 @@ def _per_call_deltas(trace, iters=100_000):
 
     node, m = _Node(), GpuMetric("opTime")
 
-    def loop(fn):
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            fn()
-        return (time.perf_counter() - t0) / iters
+    def best(fn):
+        return _timed(fn, iters) / 1e6  # seconds a call, best of three
 
     def bare_timer():
         with m.ns():
@@ -105,13 +117,13 @@ def _per_call_deltas(trace, iters=100_000):
         with trace.metric_span("x", m):
             pass
 
-    base_timer = min(loop(bare_timer) for _ in range(3))
-    base_empty = min(loop(nothing) for _ in range(3))
+    base_timer = best(bare_timer)
+    base_empty = best(nothing)
     costs = {
-        "exec_span": min(loop(exec_span_full) for _ in range(3)),
-        "metric_span": min(loop(metric_span_full) for _ in range(3)),
-        "span": min(loop(lambda: trace.span("x")) for _ in range(3)),
-        "instant": min(loop(lambda: trace.instant("x")) for _ in range(3)),
+        "exec_span": best(exec_span_full),
+        "metric_span": best(metric_span_full),
+        "span": best(lambda: trace.span("x")),
+        "instant": best(lambda: trace.instant("x")),
     }
     return {
         "exec_span": max(costs["exec_span"] - base_timer, 0.0),
@@ -121,14 +133,138 @@ def _per_call_deltas(trace, iters=100_000):
     }
 
 
+def query_report(rows: int, iters: int = 100_000) -> dict:
+    """What the instrumentation costs one resident-shaped query on the
+    default no-capture path (module docstring)."""
+    import numpy as np
+    import pyarrow as pa
+
+    from spark_rapids_tpu.runtime import trace
+    from spark_rapids_tpu.runtime.metrics import ESSENTIAL, GpuMetric
+    from spark_rapids_tpu.sql.session import TpuSession
+
+    rng = np.random.default_rng(5)
+    sess = TpuSession()  # defaults: tracing off, flight ring and obs on
+    sess.create_or_replace_temp_view("lineitem", sess.create_dataframe(
+        pa.table({"q": rng.uniform(1, 50, rows),
+                  "p": rng.uniform(900, 105000, rows),
+                  "d": rng.uniform(0, 0.1, rows),
+                  "s": rng.integers(8036, 10562, rows)})).cache())
+    text = ("select sum(p * d) as revenue from lineitem where s >= 8766 "
+            "and s < 9131 and d >= 0.05 and d <= 0.07 and q < 24")
+
+    def query():
+        return sess.sql(text).to_pydict()
+
+    for _ in range(3):
+        query()  # warm: compiled, cached, steady
+    counts = _count_calls(trace, query, _QUERY_ENTRY_POINTS)
+
+    class _Node:
+        lore_id = None
+
+        def name(self):
+            return "X"
+
+    node = _Node()
+    moderate, essential = GpuMetric("opTime"), GpuMetric("x", ESSENTIAL)
+
+    def loop(fn):
+        return _timed(fn, iters) * 1e3  # ns a call
+
+    def exec_span():
+        with trace.exec_span(node, moderate):
+            pass
+
+    def metric_span():
+        with trace.metric_span("x", essential, "query", level=ESSENTIAL):
+            pass
+
+    per_call = {
+        "exec_span": loop(exec_span), "metric_span": loop(metric_span),
+        "span": loop(lambda: trace.span("x")),
+        "instant": loop(lambda: trace.instant("x")),
+        "emit_span": loop(lambda: trace.emit_span("x", 0, 1)),
+    }
+    spans_us = sum(counts[n] * per_call[n] for n in counts) / 1e3
+    account_us, waits = 0.0, 0
+    try:
+        from spark_rapids_tpu.runtime.obs import phases as PH
+    except ImportError:
+        PH = None  # a package from before the phase account
+    if PH is not None:
+        from spark_rapids_tpu.runtime import obs
+        from spark_rapids_tpu.runtime.obs import attribution as ATTR
+        plan, root = sess.sql(text).plan, sess._last_exec
+        wall_ns, reg = sess._last_duration_ns, obs.state().registry
+
+        def account():  # all a query pays for it but the spans above
+            ph = PH.QueryPhases(plan)
+            ph.attach(root)
+            doc = ATTR.attribute(ph.peek_metrics(), wall_ns,
+                                 phases=ph.phases_ns())
+            for phase, secs in doc["buckets"].items():
+                if secs:  # obs.on_query_end's feed, now every query's
+                    reg.float_counter("rapids_query_seconds_bucket",
+                                      labels={"phase": phase}).inc(secs)
+            obs.publish_query_record(ph.record(1, "ok", wall_ns))
+
+        def wait():
+            with PH.device_wait():
+                pass
+
+        enter = PH._DeviceWait.__enter__
+
+        def counted(self):
+            nonlocal waits
+            waits += 1
+            return enter(self)
+
+        PH._DeviceWait.__enter__ = counted
+        try:
+            query()
+        finally:
+            PH._DeviceWait.__enter__ = enter
+        account_us = _timed(account, 2000) + waits * _timed(wait, iters)
+    t0 = time.perf_counter()
+    for _ in range(20):
+        query()
+    return {"query": "q6-shaped, resident, no capture, defaults",
+            "rows": rows, "spans_per_query": counts,
+            "device_waits_per_query": waits,
+            "per_call_ns": {n: round(v, 1) for n, v in per_call.items()},
+            "spans_us_per_query": round(spans_us, 2),
+            "account_us_per_query": round(account_us, 2),
+            "instrumentation_us_per_query": round(spans_us + account_us, 2),
+            "cpu_query_ms": round((time.perf_counter() - t0) / 20 * 1e3, 3)}
+
+
+def _timed(fn, iters: int) -> float:
+    """Best-of-three microseconds a call."""
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            fn()
+        best = min(best, (time.perf_counter() - t0) / iters)
+    return best * 1e6
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
+    ap.add_argument("--query", action="store_true",
+                    help="report the cost of the instrumentation to one "
+                         "resident-shaped query instead of the smoke")
     ap.add_argument("--rows", type=int, default=400_000)
     ap.add_argument("--batch", type=int, default=2048)
     ap.add_argument("--reps", type=int, default=9)
     ap.add_argument("--tolerance", type=float, default=0.02)
     args = ap.parse_args()
+    if args.query:
+        print(json.dumps(query_report(args.rows)))
+        return 0
 
+    import bench_fusion as BF
     from spark_rapids_tpu.runtime import trace
 
     t = BF._table(args.rows)
