@@ -100,7 +100,8 @@ KERNEL_PRIMITIVES: Dict[str, str] = {
     "ops/repartition.py": "single-dispatch counting-sort shuffle "
                           "partitioning kernel",
     "ops/pallas_decode.py": "pallas parquet-decode bit-slice kernel "
-                            "(dictionary/RLE unpack) — sanctioned "
+                            "(dictionary/RLE unpack; run tables spread "
+                            "over rows by a prefix sum) — sanctioned "
                             "pallas module",
     "ops/pallas_kernels.py": "hand-tiled pallas kernels (murmur3, "
                              "sort tiles) — sanctioned pallas module",

@@ -21,10 +21,13 @@ Layering:
   byte-serial. Everything O(rows) ships encoded.
 - **RLE/bit-packed hybrids become run tables.** A hybrid stream parses
   into per-run records (output start/length, RLE value or bit-pool
-  offset, bit width) whose host cost is O(#runs), not O(#values). The
-  device expands runs with a vectorized searchsorted + bit-gather
+  offset, bit width) whose host cost is O(#runs), not O(#values). They
+  ship as four small planes: each run's first row (`start`) and three
+  attributes that are constant over the run (`a`, `b`, `c`: row i is the
+  `b` bits at bit `a + i*b` of the pool, plus `c`). The device writes
+  each attribute's step at the run starts and takes a prefix sum
   (pallas_decode.expand_runs) — the prefix-sum formulation of cuDF's
-  warp-cooperative RLE decoder.
+  warp-cooperative RLE decoder, with no per-row search.
 - **Per-column fallback, not per-file.** A column whose physical type /
   encoding / codec is outside the supported set host-decodes through
   the existing pyarrow path into a ready ColumnVector that rides INSIDE
@@ -71,9 +74,7 @@ _PHYS = {"INT32": (4, np.dtype("<i4")), "INT64": (8, np.dtype("<i8")),
          "FLOAT": (4, np.dtype("<f4")), "DOUBLE": (8, np.dtype("<f8")),
          "BOOLEAN": (0, np.dtype(np.bool_))}
 
-#: int32 sentinel padding run-table cum planes so searchsorted never
-#: lands a live row in the padded tail
-_CUM_SENTINEL = np.int32(2**31 - 1)
+_I32_MAX = 2**31 - 1
 
 
 class Unsupported(Exception):
@@ -336,12 +337,11 @@ class _Delta:
     stream boundaries, so multi-page and coalesced multi-group chunks
     decode in one pass."""
 
-    __slots__ = ("s_start", "s_count", "s_first", "s_mbbase",
+    __slots__ = ("s_start", "s_first", "s_mbbase",
                  "mb_width", "mb_bitbase", "mb_min", "pool", "vpm", "total")
 
     def __init__(self):
         self.s_start: List[int] = []
-        self.s_count: List[int] = []
         self.s_first: List[int] = []
         self.s_mbbase: List[int] = []
         self.mb_width: List[int] = []
@@ -369,7 +369,6 @@ def _parse_delta(view, pos: int, end: int, expected: int, dl: _Delta,
     if total != expected:
         raise Unsupported("delta stream count mismatch")
     dl.s_start.append(dl.total)
-    dl.s_count.append(total)
     dl.s_first.append(first)
     dl.s_mbbase.append(len(dl.mb_width))
     dl.total += total
@@ -537,41 +536,44 @@ def _pool_plane(pool: bytearray) -> np.ndarray:
     return out
 
 
-def _run_planes(runs: _Runs, prefix: str = "",
-                with_width: bool = True) -> Dict[str, np.ndarray]:
-    s = len(runs.start)
-    # s + 1: at least one sentinel slot so positions past the encoded
-    # total always land on a zero pad run, never a live run's tail
-    cap = _shapes.bucket_rows(s + 1, 8, 4)
-    cum = np.cumsum(np.asarray(runs.length, np.int64)).astype(np.int32) \
-        if s else np.zeros(0, np.int32)
-    planes = {
-        prefix + "cum": _pad32(cum, cap, _CUM_SENTINEL),
-        prefix + "start": _pad32(np.asarray(runs.start, np.int32), cap),
-        prefix + "val": _pad32(np.asarray(runs.value, np.int32), cap),
-        prefix + "packed": _pad32(np.asarray(runs.packed, np.bool_), cap,
-                                  False),
-        prefix + "bitbase": _pad32(np.asarray(runs.bitbase, np.int64), cap),
-        prefix + "pool": _pool_plane(runs.pool),
+def _start_plane(start: Sequence[int], total: int, cap: int) -> np.ndarray:
+    """Run starts as the device's scatter indices: the runs' first rows,
+    one closing entry at `total` (the rows past it take zeros), and a
+    pad that lies past any row capacity, so the device drops it."""
+    out = np.full(cap, _I32_MAX, np.int32)
+    out[: len(start)] = start
+    out[len(start)] = total
+    return out
+
+
+def _run_planes(runs: _Runs, prefix: str = "") -> Dict[str, np.ndarray]:
+    start = np.asarray(runs.start, np.int64)
+    packed = np.asarray(runs.packed, np.bool_)
+    # row i of a run is the b bits at bit a + i*b of the pool, plus c;
+    # an RLE run reads no bits (a = b = 0) and c is its value
+    b = np.where(packed, np.asarray(runs.width, np.int64), 0)
+    a = np.where(packed, np.asarray(runs.bitbase, np.int64) - start * b, 0)
+    c = np.asarray(runs.value, np.int64) + np.asarray(runs.base, np.int64)
+    pool = _pool_plane(runs.pool)
+    # bit offsets are 32-bit unless the pool or the rows outgrow them
+    wide = max(pool.shape[0] * 8, runs.total * 32) > _I32_MAX
+    # + 1: the closing entry of _start_plane
+    cap = _shapes.bucket_rows(len(start) + 1, 8, 4)
+    return {
+        prefix + "start": _start_plane(start, runs.total, cap),
+        prefix + "a": _pad32(a.astype(np.int64 if wide else np.int32), cap),
+        prefix + "b": _pad32(b.astype(np.int32), cap),
+        prefix + "c": _pad32(c.astype(np.int32), cap),
+        prefix + "pool": pool,
     }
-    if with_width:
-        planes[prefix + "width"] = _pad32(
-            np.asarray(runs.width, np.int32), cap)
-        planes[prefix + "base"] = _pad32(
-            np.asarray(runs.base, np.int32), cap)
-    return planes
 
 
 def _delta_planes(dl: _Delta) -> Dict[str, np.ndarray]:
-    s = len(dl.s_start)
-    scap = _shapes.bucket_rows(s + 1, 8, 4)  # ensure a sentinel slot
+    scap = _shapes.bucket_rows(len(dl.s_start) + 1, 8, 4)  # + 1: closing
     m = len(dl.mb_width)
     mcap = _shapes.bucket_rows(m + 1, 8, 4)
-    cum = np.cumsum(np.asarray(dl.s_count, np.int64)).astype(np.int32) \
-        if s else np.zeros(0, np.int32)
     return {
-        "s_cum": _pad32(cum, scap, _CUM_SENTINEL),
-        "s_start": _pad32(np.asarray(dl.s_start, np.int32), scap),
+        "s_start": _start_plane(dl.s_start, dl.total, scap),
         "s_first": _pad32(np.asarray(dl.s_first, np.int64), scap),
         "s_mbbase": _pad32(np.asarray(dl.s_mbbase, np.int32), scap),
         "mb_width": _pad32(np.asarray(dl.mb_width, np.int32), mcap),
@@ -812,7 +814,7 @@ class _ColumnBuilder:
             planes: Dict[str, np.ndarray] = {"pool": pool}
             meta.append(("w", w))
         elif self.kind == "bool":
-            planes = _run_planes(self.runs, with_width=False)
+            planes = _run_planes(self.runs)
         elif self.kind == "dict":
             planes = _run_planes(self.runs)
             raw_dtype = self.vocab[0].dtype if self.vocab else np.dtype("<i4")
@@ -826,8 +828,7 @@ class _ColumnBuilder:
             planes = _delta_planes(self.delta)
             meta.append(("vpm", self.delta.vpm))
         if self.has_nulls:
-            planes.update(_run_planes(self.dlv, prefix="d_",
-                                      with_width=False))
+            planes.update(_run_planes(self.dlv, prefix="d_"))
         meta.append(("nulls", self.has_nulls))
         nnz_plane = np.asarray([self.nnz], np.int64)
         planes["nnz"] = nnz_plane

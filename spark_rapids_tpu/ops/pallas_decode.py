@@ -6,8 +6,10 @@ bandwidth. There, warps cooperatively expand RLE runs and gather through
 dictionaries in shared memory; here the same decode becomes vectorized
 TPU-friendly primitives over the run tables io/encoded.py extracts:
 
-- run expansion  = searchsorted(cum, iota) + per-run bit gather — the
-  prefix-sum formulation of the warp-cooperative RLE decoder
+- run expansion  = each run attribute's step written at the run starts
+  (a scatter of a few thousand entries) and a prefix sum over the rows,
+  then a per-row bit gather — the prefix-sum formulation of the
+  warp-cooperative RLE decoder; no row searches for its run
 - dictionary     = one gather through the uploaded vocab plane
 - delta          = cumsum with per-stream restarts (first-value anchors)
 - null placement = cumsum(def-levels) scatter-free gather, reproducing
@@ -19,7 +21,7 @@ The one genuinely hand-tiled inner loop is the unaligned bit-slice
 a Pallas kernel with an XLA twin, gated by the same
 spark.rapids.sql.pallas.enabled conf and block-size eligibility as
 ops/pallas_kernels.py, and the suite differentially checks the pair in
-interpret mode on CPU. Everything else (searchsorted, gathers, cumsum)
+interpret mode on CPU. Everything else (scatter, cumsum, gathers)
 stays plain jnp: XLA fuses it into the one stage-body dispatch, which is
 the point — Scan→Filter→partial-agg remains ONE dispatch per batch over
 encoded bytes.
@@ -116,72 +118,90 @@ def _gather_bits(words: jax.Array, bitoff: jax.Array, mask: jax.Array
     return bitslice_u32_lax(w0, w1, sh, mask)
 
 
+def _width_mask(width: jax.Array) -> jax.Array:
+    """The low `width` bits set, per element (width 0..32) -> uint32."""
+    wu = width.astype(jnp.uint32)
+    return jnp.where(width >= 32, _U32_MAX,
+                     (jnp.uint32(1) << (wu & np.uint32(31))) - jnp.uint32(1))
+
+
 # ---------------------------------------------------------------------------
 # run-table expansion
 # ---------------------------------------------------------------------------
 
+#: rows a prefix sum scans in one piece (`_cumsum`)
+_SCAN_BLOCK = 1024
+
+
+def _cumsum(x: jax.Array) -> jax.Array:
+    """Integer prefix sum along the last axis, in two levels: within
+    blocks of _SCAN_BLOCK rows, then over the blocks' totals. The sums
+    are jnp.cumsum's; the form is for XLA's TPU compiler, which takes 17 s
+    (int32) to 55 s (int64) over one flat cumsum of 2**20 rows and half a
+    second over this (compiled for a v5e, PR 27)."""
+    n = x.shape[-1]
+    if n <= _SCAN_BLOCK:
+        return jnp.cumsum(x, axis=-1)
+    lead = x.shape[:-1]
+    x = jnp.pad(x, [(0, 0)] * len(lead) + [(0, -n % _SCAN_BLOCK)])
+    y = jnp.cumsum(x.reshape(lead + (-1, _SCAN_BLOCK)), axis=-1)
+    total = y[..., -1]
+    before = jnp.cumsum(total, axis=-1) - total
+    return (y + before[..., None]).reshape(lead + (-1,))[..., :n]
+
+
+def _per_row(start: jax.Array, table: jax.Array, n: int) -> jax.Array:
+    """Spread per-run attributes over `n` rows: row i takes `table[...,
+    r]` of the last run r with start[r] <= i. `start` ascends from 0
+    (io/encoded.py `_start_plane`); entries at or past `n` are padding
+    and fall away. Each run's step from the run before it is written at
+    its first row, and a prefix sum carries it across the run: no row
+    searches for its run."""
+    steps = jnp.diff(table, prepend=jnp.zeros_like(table[..., :1]))
+    marks = jnp.zeros(table.shape[:-1] + (n,), table.dtype)
+    return _cumsum(marks.at[..., start].add(steps, mode="drop"))
+
+
 def expand_runs(planes: Dict[str, jax.Array], prefix: str, vcap: int
                 ) -> jax.Array:
-    """Expand an RLE/bit-packed run table to `vcap` int32 values.
-    Positions past the encoded total land on sentinel-padded run slots
-    (io/encoded.py guarantees at least one) and decode to exact 0."""
-    cum = planes[prefix + "cum"]
-    i = jnp.arange(vcap, dtype=jnp.int32)
-    seg = jnp.clip(jnp.searchsorted(cum, i, side="right").astype(jnp.int32),
-                   0, cum.shape[0] - 1)
-    s_start = planes[prefix + "start"][seg]
-    s_packed = planes[prefix + "packed"][seg]
-    s_bitbase = planes[prefix + "bitbase"][seg]
-    width = planes.get(prefix + "width")
-    if width is None:  # constant width 1 (def levels, booleans)
-        w64 = jnp.int64(1)
-        mask = jnp.full(vcap, 1, jnp.uint32)
-    else:
-        s_width = width[seg]
-        wu = s_width.astype(jnp.uint32)
-        mask = jnp.where(s_width >= 32, _U32_MAX,
-                         (jnp.uint32(1) << (wu & np.uint32(31)))
-                         - jnp.uint32(1))
-        w64 = s_width.astype(jnp.int64)
-    bitoff = s_bitbase + (i - s_start).astype(jnp.int64) * w64
-    ext = _gather_bits(_words(planes[prefix + "pool"]), bitoff, mask)
-    out = jnp.where(s_packed, ext.astype(jnp.int32),
-                    planes[prefix + "val"][seg])
-    base = planes.get(prefix + "base")
-    if base is not None:
-        out = out + base[seg]
-    return out
+    """Expand an RLE/bit-packed run table to `vcap` int32 values: row i
+    is the `b` bits at bit `a + i*b` of the pool, plus `c`. Positions
+    past the encoded total follow the table's closing entry (all zeros)
+    and decode to exact 0."""
+    start = planes[prefix + "start"]
+    a = planes[prefix + "a"]
+    b, c = _per_row(start, jnp.stack([planes[prefix + "b"],
+                                      planes[prefix + "c"]]), vcap)
+    i = jnp.arange(vcap, dtype=a.dtype)
+    bitoff = _per_row(start, a, vcap) + i * b.astype(a.dtype)
+    ext = _gather_bits(_words(planes[prefix + "pool"]), bitoff,
+                       _width_mask(b))
+    return ext.astype(jnp.int32) + c
 
 
 def _expand_delta(planes: Dict[str, jax.Array], vcap: int, vpm: int
                   ) -> jax.Array:
     """DELTA_BINARY_PACKED -> int64 values: per-element miniblock bit
     gather, then one cumsum with per-stream (page) restarts."""
-    s_cum = planes["s_cum"]
+    s_start = planes["s_start"]
     j = jnp.arange(vcap, dtype=jnp.int32)
-    seg = jnp.clip(
-        jnp.searchsorted(s_cum, j, side="right").astype(jnp.int32),
-        0, s_cum.shape[0] - 1)
-    a = planes["s_start"][seg]
+    a, mbbase = _per_row(s_start, jnp.stack([s_start, planes["s_mbbase"]]),
+                         vcap)
     rel = j - a - 1  # delta index within the stream; -1 at stream starts
-    mb = jnp.clip(planes["s_mbbase"][seg]
-                  + jnp.where(rel >= 0, rel // vpm, 0),
+    mb = jnp.clip(mbbase + jnp.where(rel >= 0, rel // vpm, 0),
                   0, planes["mb_width"].shape[0] - 1)
     within = jnp.where(rel >= 0, rel % vpm, 0)
     w = planes["mb_width"][mb]
-    wu = w.astype(jnp.uint32)
-    mask = jnp.where(w >= 32, _U32_MAX,
-                     (jnp.uint32(1) << (wu & np.uint32(31)))
-                     - jnp.uint32(1))
     bitoff = planes["mb_bitbase"][mb] \
         + within.astype(jnp.int64) * w.astype(jnp.int64)
-    ext = _gather_bits(_words(planes["pool"]), bitoff, mask)
+    ext = _gather_bits(_words(planes["pool"]), bitoff, _width_mask(w))
     d = ext.astype(jnp.int64) + planes["mb_min"][mb]
     nnz = planes["nnz"][0]
     d = jnp.where((rel >= 0) & (j < nnz), d, jnp.int64(0))
-    c = jnp.cumsum(d)
+    c = _cumsum(d)
     # value[j] = first[stream] + sum of deltas in (stream_start, j]
-    return planes["s_first"][seg] + c - c[jnp.clip(a, 0, vcap - 1)]
+    anchor = planes["s_first"] - c[jnp.clip(s_start, 0, vcap - 1)]
+    return _per_row(s_start, anchor, vcap) + c
 
 
 # ---------------------------------------------------------------------------
@@ -236,12 +256,12 @@ def _decode_column(ec, cap: int):
     # rows, and downstream kernels (bounds-trusting aggs) rely on it
     zero = jnp.zeros((), vals.dtype)
     vals = jnp.where(jnp.arange(vcap) < nnz, vals, zero)
-    if "d_cum" in planes:
+    if "d_start" in planes:
         # sparse values -> row positions via the definition levels:
         # valid rows gather the next value, null rows take fill 0
         dexp = expand_runs(planes, "d_", cap)
         valid = dexp == 1
-        pos = jnp.clip(jnp.cumsum(valid.astype(jnp.int32)) - 1, 0,
+        pos = jnp.clip(_cumsum(valid.astype(jnp.int32)) - 1, 0,
                        vcap - 1)
         data = jnp.where(valid, vals[pos], zero)
         return ColumnVector(ec.dtype, data, valid, bounds=ec.bounds)

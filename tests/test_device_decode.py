@@ -11,6 +11,8 @@ must be byte-identical (the decode path may not change a single value).
 """
 import os
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 import pyarrow.parquet as pq
@@ -204,6 +206,164 @@ def test_unit_fallback_reasons(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# run-table expansion against a plain numpy expansion of the same table
+# ---------------------------------------------------------------------------
+
+def _pack_bits(vals, w):
+    """LSB-first bit packing in groups of 8 values, as a bit-packed run."""
+    v = np.asarray(vals, np.uint64)
+    v = np.concatenate([v, np.zeros((-len(v)) % 8, np.uint64)])
+    bits = (v[:, None] >> np.arange(w, dtype=np.uint64)) & np.uint64(1)
+    return np.packbits(bits.astype(np.uint8).reshape(-1),
+                       bitorder="little").tobytes()
+
+
+def _run_table(spec):
+    """spec: [("rle", n, value, width, base) | ("packed", values, width,
+    base)] -> (io/encoded._Runs, the values it encodes as wrapped int32)."""
+    runs, want = E._Runs(), []
+    for r in spec:
+        if r[0] == "rle":
+            _, n, value, w, base = r
+            runs.add_rle(n, value, w, base)
+            want.append(np.full(n, value + base, np.int64))
+        else:
+            _, vals, w, base = r
+            runs.add_packed(len(vals), _pack_bits(vals, w), w, base)
+            want.append(np.asarray(vals, np.int64) + base)
+    flat = np.concatenate(want) if want else np.zeros(0, np.int64)
+    return runs, flat.astype(np.uint32).view(np.int32)
+
+
+def _mixed_spec(rng, w, n_runs, base=0):
+    """Alternating RLE and bit-packed runs of width `w`, odd lengths
+    included (a page's last bit-packed run is cut short of its groups)."""
+    spec = []
+    for k in range(n_runs):
+        n = int(rng.integers(1, 70))
+        if k % 2:
+            spec.append(("rle", n, int(rng.integers(0, 1 << min(w, 31))),
+                         w, base))
+        else:
+            spec.append(("packed", rng.integers(0, 1 << w, n), w, base))
+    return spec
+
+
+def _level_spec(valid):
+    """Definition levels (width 1) of a validity vector: RLE over the
+    stretches of 64 equal rows, bit-packed elsewhere."""
+    spec = []
+    for k in range(0, len(valid), 64):
+        blk = valid[k: k + 64].astype(np.int64)
+        if blk.min() == blk.max():
+            spec.append(("rle", len(blk), int(blk[0]), 1, 0))
+        else:
+            spec.append(("packed", blk, 1, 0))
+    return spec
+
+
+def _expand_cases():
+    rng = np.random.default_rng(27)
+    cases = {
+        "rle_only": [("rle", 40, 5, 3, 0), ("rle", 9, 2, 3, 0),
+                     ("rle", 1, 7, 3, 0)],
+        "packed_only": [("packed", rng.integers(0, 8, 64), 3, 0),
+                        ("packed", rng.integers(0, 8, 21), 3, 0)],
+        "mixed": _mixed_spec(rng, 12, 20),
+        "one_run": [("packed", rng.integers(0, 1 << 12, 100), 12, 0)],
+        "one_rle_run": [("rle", 100, 9, 12, 0)],
+        "no_runs": [],
+        # three row groups, each with its own dictionary (vocab base)
+        "pages_with_bases": _mixed_spec(rng, 5, 6) + _mixed_spec(
+            rng, 7, 6, base=32) + _mixed_spec(rng, 3, 6, base=160),
+        # a page whose values are all null leaves a run of no rows
+        "empty_run_between": [("rle", 10, 1, 4, 0),
+                              ("packed", np.zeros(0, np.int64), 4, 0),
+                              ("packed", rng.integers(0, 16, 12), 4, 0)],
+        # 7 runs and the closing entry fill the bucket of 8; 8 spill to 16
+        "bucket_edge_7": _mixed_spec(rng, 12, 7),
+        "bucket_edge_8": _mixed_spec(rng, 12, 8),
+        "bucket_edge_15": _mixed_spec(rng, 12, 15),
+    }
+    for w in (1, 3, 12, 17, 32):
+        cases[f"width_{w}"] = _mixed_spec(rng, w, 12)
+    for name, frac in (("nulls_0", 0.0), ("nulls_50", 0.5),
+                       ("nulls_100", 1.0)):
+        valid = rng.random(700) >= frac
+        valid[100:300] = valid[100]  # a stretch that run-length encodes
+        cases[f"levels_{name}"] = _level_spec(valid)
+    return cases
+
+
+_EXPAND_CASES = _expand_cases()
+
+
+@pytest.mark.parametrize("fit", ["tail", "exact"])
+@pytest.mark.parametrize("case", sorted(_EXPAND_CASES))
+def test_expand_runs_matches_numpy(case, fit):
+    runs, want = _run_table(_EXPAND_CASES[case])
+    prefix = "d_" if case.startswith("levels_") else ""
+    planes = E._run_planes(runs, prefix=prefix)
+    assert planes[prefix + "a"].dtype == np.int32
+    # a capacity past the encoded total (its tail is exact 0), and one
+    # that the values fill to the last row (the closing entry falls away)
+    vcap = len(want) + 37 if fit == "tail" else max(len(want), 1)
+    got = np.asarray(PD.expand_runs(
+        {k: jnp.asarray(v) for k, v in planes.items()}, prefix, vcap))
+    assert got.dtype == np.int32 and got.shape == (vcap,)
+    assert np.array_equal(got[: len(want)], want)
+    assert not np.any(got[len(want):])
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.int64])
+@pytest.mark.parametrize("shape", [(5,), (1024,), (1025,), (3000,),
+                                   (2, 5000), (1 << 15,)])
+def test_two_level_cumsum_matches_numpy(shape, dtype):
+    # blocks of _SCAN_BLOCK rows, their edge, a ragged last block, a
+    # leading axis; wrap-around included (the sums are modular)
+    x = np.random.default_rng(sum(shape)).integers(
+        -2**30, 2**30, shape).astype(dtype)
+    got = np.asarray(PD._cumsum(jnp.asarray(x)))
+    assert got.dtype == dtype
+    assert np.array_equal(got, np.cumsum(x, axis=-1, dtype=dtype))
+
+
+def test_expand_runs_wide_bit_offsets():
+    # a pool past 2**31 bits ships its offsets as int64; same values
+    runs, want = _run_table(_EXPAND_CASES["width_17"])
+    planes = E._run_planes(runs)
+    planes["a"] = planes["a"].astype(np.int64)
+    got = np.asarray(PD.expand_runs(
+        {k: jnp.asarray(v) for k, v in planes.items()}, "", len(want) + 5))
+    assert np.array_equal(got[: len(want)], want)
+    assert not np.any(got[len(want):])
+
+
+@pytest.mark.parametrize("page_bytes", [512, 2048, 1 << 20])
+def test_expand_delta_matches_numpy(tmp_path, page_bytes):
+    # several pages: each restarts its own stream (first value, miniblocks)
+    rng = np.random.default_rng(page_bytes)
+    vals = np.cumsum(rng.integers(-20, 50, 6000)).astype(np.int64)
+    path = str(tmp_path / "d.parquet")
+    pq.write_table(pa.table({"d": pa.array(vals)}), path,
+                   use_dictionary=False, data_page_size=page_bytes,
+                   column_encoding={"d": "DELTA_BINARY_PACKED"},
+                   data_page_version="1.0")
+    pf = pq.ParquetFile(path)
+    hb, = E.read_encoded_batches(path, pf.metadata, [0],
+                                 [T.StructField("d", T.Int64Type())], 1 << 20)
+    ec = hb.columns[0]
+    assert ec.kind == "delta", hb.fallback
+    meta = dict(ec.meta)
+    n_streams = int(np.sum(ec.planes["s_start"] < len(vals)))
+    assert (n_streams > 1) == (page_bytes < 1 << 20)
+    got = np.asarray(PD._expand_delta(
+        {k: jnp.asarray(v) for k, v in ec.planes.items()},
+        meta["vcap"], meta["vpm"]))
+    assert np.array_equal(got[: len(vals)], vals)
+
+
+# ---------------------------------------------------------------------------
 # session layer: the decode flag may not change a single byte
 # ---------------------------------------------------------------------------
 
@@ -331,3 +491,25 @@ def test_session_fused_single_dispatch(tmp_path):
                            fused.get("numDispatches", 0))
     if dispatches:
         assert dispatches <= max(batches, 1)
+
+
+def test_dict_decode_has_no_search_loop(monkeypatch):
+    # the compiled decode of a dictionary column: no `while` (a per-row
+    # binary search is one), and three per-row gathers at most: the two
+    # pool words and the dictionary. The Pallas bit-slice runs as a grid
+    # loop in the CPU's interpreter, so its lax twin stands in for it.
+    import re
+    monkeypatch.setattr(PD.PK, "enabled", lambda: False)
+    rng = np.random.default_rng(1)
+    runs, _ = _run_table(_mixed_spec(rng, 12, 40))
+    vcap = 4096
+    planes = {k: jnp.asarray(v) for k, v in E._run_planes(runs).items()}
+    planes["vocab"] = jnp.asarray(rng.normal(size=4096))
+    planes["nnz"] = jnp.asarray([runs.total], jnp.int64)
+    ec = E.EncodedColumn("dict", T.Float64Type(), planes,
+                         (("vcap", vcap), ("nulls", False)))
+    text = jax.jit(PD.decode_batch).lower(
+        E.EncodedBatch([ec], runs.total, vcap)).compile().as_text()
+    assert not re.search(r"\bwhile\(", text)
+    per_row = re.findall(r"= \w+\[%d[,\]][^ ]* gather\(" % vcap, text)
+    assert 1 <= len(per_row) <= 3, per_row

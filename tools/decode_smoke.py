@@ -16,10 +16,21 @@ Parquet decode path.
    during a probe drive, measure its per-call cost in a tight loop,
    overhead must stay under --tolerance (2%) of the drive.
 
-Usage: python tools/decode_smoke.py [--rows 200000] [--tolerance 0.02]
+4. Run-table expansion alone (ops/pallas_decode.expand_runs: boundary
+   marks and a prefix sum, no per-row search): two run tables over
+   --expand-rows rows, one run a 512 values (what pyarrow writes) and
+   alternating 8-value RLE and bit-packed runs (as many runs as a table
+   can hold: rows/8), each expanded on the default device, compared
+   value for value with the numpy expansion of the same table, and
+   timed (`expand_ms`, median of --reps calls that end in
+   block_until_ready).
 
-CPU gate: runs on the CPU backend (JAX_PLATFORMS defaults to cpu here);
-no time it prints is a measurement of the chip.
+Usage: python tools/decode_smoke.py [--rows 200000] [--tolerance 0.02]
+                                    [--expand-rows 131072]
+
+Here it runs on the CPU backend and no time it prints is a measurement
+of the chip; the line names the device it ran on. Through the chip tool
+(`--expand-rows 1048576`) `expand_ms` is the chip's.
 """
 import argparse
 import json
@@ -195,11 +206,62 @@ def disabled_overhead(path, reps: int) -> dict:
             "disabled_overhead_pct": round(added / best * 100, 4)}
 
 
+def expansion_times(rows: int, reps: int, fails: list) -> dict:
+    """Section 4: expand_runs alone, against numpy, on the default
+    device."""
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.io import encoded as E
+    from spark_rapids_tpu.ops import pallas_decode as PD
+
+    rng = np.random.default_rng(27)
+    width = 12
+
+    def pack(vals):
+        bits = (vals[:, None] >> np.arange(width)) & 1
+        return np.packbits(bits.astype(np.uint8).reshape(-1),
+                           bitorder="little").tobytes()
+
+    def table(run_len: int, alternate: bool):
+        runs, want = E._Runs(), []
+        while runs.total < rows:
+            n = min(run_len, rows - runs.total)
+            if alternate and len(runs.start) % 2:
+                v = int(rng.integers(0, 1 << width))
+                runs.add_rle(n, v, width, 0)
+                want.append(np.full(n, v, np.int64))
+            else:
+                vals = rng.integers(0, 1 << width, n)
+                runs.add_packed(n, pack(vals), width, 0)
+                want.append(vals)
+        return runs, np.concatenate(want).astype(np.int32)
+
+    dev = jax.devices()[0]
+    out = {"device": f"{dev.platform}:{dev.device_kind}", "rows": rows}
+    expand = jax.jit(lambda p: PD.expand_runs(p, "", rows))
+    for name, run_len, alternate in (("run_per_512", 512, False),
+                                     ("alternating_8", 8, True)):
+        runs, want = table(run_len, alternate)
+        planes = {k: jnp.asarray(v) for k, v in E._run_planes(runs).items()}
+        if not np.array_equal(np.asarray(expand(planes)), want):
+            fails.append(f"expand_runs differs from numpy on {name}")
+        times = []
+        for _ in range(max(reps, 3)):
+            t0 = time.perf_counter()
+            jax.block_until_ready(expand(planes))
+            times.append(time.perf_counter() - t0)
+        out[name] = {"runs": len(runs.start),
+                     "expand_ms": round(sorted(times)[len(times) // 2]
+                                        * 1e3, 3)}
+    return out
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=200_000)
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--tolerance", type=float, default=0.02)
+    ap.add_argument("--expand-rows", type=int, default=1 << 17)
     args = ap.parse_args()
 
     tdir = tempfile.mkdtemp(prefix="decode_smoke_")
@@ -210,6 +272,8 @@ def main() -> int:
         fails = parity_and_shift(path, result)
         overhead = disabled_overhead(path, args.reps)
         result.update(overhead)
+        result["expand"] = expansion_times(args.expand_rows, args.reps,
+                                           fails)
         print(json.dumps(result, sort_keys=True))
         pct = overhead["disabled_overhead_pct"]
         if pct > args.tolerance * 100:
