@@ -453,8 +453,15 @@ def column_from_arrow(arr, dtype: T.DataType, capacity: int) -> ColumnVector:
     return ColumnVector(dtype, data, validity)
 
 
-def from_arrow(table) -> ColumnarBatch:
-    """pyarrow Table -> device ColumnarBatch (single upload per plane)."""
+def from_arrow(table, device=None) -> ColumnarBatch:
+    """pyarrow Table -> device ColumnarBatch (single upload per plane).
+    With `device` the planes are built on that device and committed to
+    it: a cache placed over a mesh uploads each row range into place."""
+    if device is not None:
+        with jax.default_device(device):
+            batch = from_arrow(table)
+        return ColumnarBatch(jax.device_put(batch.columns, device),
+                             batch.num_rows)
     table = table.combine_chunks()
     n = table.num_rows
     cap = round_capacity(n)

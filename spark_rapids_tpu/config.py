@@ -1022,16 +1022,22 @@ STAGE_FUSION_ENABLED = conf_bool(
     "the unfused operator chain.", commonly_used=True)
 
 MULTICHIP_ENABLED = conf_bool(
-    "spark.rapids.sql.multichip.enabled", False,
-    "Shard whole fused stages across the `part` axis of the device mesh "
-    "and run them as ONE SPMD dispatch per batch-wave (exec/sharded.py), "
-    "with the hash exchange executing as an in-program ICI all-to-all "
-    "instead of a host-side round-trip — the TPU analog of the "
-    "reference's UCX/RDMA shuffle manager. Stages the planner cannot "
-    "shard (carries, LIMIT early-exit, flat string planes) fall back "
-    "per-shard to the single-device path through the tagging tree. "
-    "Compile-cache keys gain a mesh fingerprint while this is on, so "
-    "sharded and single-device executables never collide.",
+    "spark.rapids.sql.multichip.enabled", None,
+    "Shard whole fused stages, and the scan-filter-partial-aggregate over "
+    "a cached table, across the `part` axis of the device mesh and run "
+    "them as ONE SPMD dispatch per batch-wave (exec/sharded.py), with the "
+    "hash exchange executing as an in-program ICI all-to-all instead of a "
+    "host-side round-trip — the TPU analog of the reference's UCX/RDMA "
+    "shuffle manager. Unset (None), the engine takes the mesh it is "
+    "given: on when the process has more than one accelerator device, off "
+    "with one device and on the CPU simulator (parallel/mesh.py "
+    "`multichip_on`); `true` forces it (one device gives the degenerate "
+    "one-device mesh), `false` turns it off. Under a mesh `df.cache()` "
+    "places an in-memory source's row ranges one per device. Stages the "
+    "planner cannot shard (carries, LIMIT early-exit, flat string planes) "
+    "fall back per-shard to the single-device path through the tagging "
+    "tree. Compile-cache keys gain a mesh fingerprint while this is on, "
+    "so sharded and single-device executables never collide.",
     commonly_used=True)
 
 MULTICHIP_DEVICES = conf_int(

@@ -129,7 +129,7 @@ class InMemoryScanExec(TpuExec):
             self._acquire(ctx)
             FLT.site("scan.decode")
             with self.span(copy_t):
-                b = from_arrow(chunk)
+                b = from_arrow(chunk, device=ctx.device)
             up_bytes.add(b.device_memory_size())
             yield b
             out_rows.add(take)
@@ -533,7 +533,16 @@ class TextScanExec(TpuExec):
 class CachedScanExec(TpuExec):
     """Materializes the child once into HBM-resident batches stored on the
     CachedRelation plan node (shared across collects of the same
-    DataFrame); later scans stream straight from device memory."""
+    DataFrame); later scans stream straight from device memory.
+
+    Under a mesh (parallel/mesh.placement_devices) partition p's merged
+    batch is built ON device p mod n and committed to it, so a table
+    larger than one chip is never whole on one; the shards of a column
+    are made uniform planes (one capacity, one vocabulary a string
+    column, validity on all or none) so that a sharded stage consumes
+    them in place (`resident_shards`). Every other consumer gets a
+    partition through `execute_partition`, moved to the default device
+    when it lives on another chip (counted in meshPutBytes)."""
 
     _lock = threading.Lock()
 
@@ -547,31 +556,151 @@ class CachedScanExec(TpuExec):
             return len(self.plan.materialized)
         return self.children[0].num_partitions
 
+    def _load_partition(self, p: int, dev) -> Optional[ColumnarBatch]:
+        """Partition p of the child as ONE batch: every query over the
+        cache then costs a fixed handful of fused dispatches instead of
+        one chain per source chunk. On `dev` when the cache is placed."""
+        child = self.children[0]
+        with TaskContext(partition_id=p) as tctx:
+            tctx.device = dev
+            batches = list(child.execute_partition(tctx, p))
+        if not batches:
+            return None
+        if dev is None:
+            return K.compact_batch(K.concat_batches(batches))
+        with jax.default_device(dev):
+            # a source that took no notice of tctx.device left its
+            # batches on the default device: bring them over one at a
+            # time (already in place, device_put copies nothing)
+            batches = [ColumnarBatch(
+                jax.device_put(b.columns, dev), b.num_rows,
+                None if b.row_mask is None
+                else jax.device_put(b.row_mask, dev)) for b in batches]
+            return K.compact_batch(K.concat_batches(batches))
+
     def _materialize(self):
+        from spark_rapids_tpu.parallel.mesh import placement_devices
         from spark_rapids_tpu.runtime.memory import SpillableColumnarBatch
         with CachedScanExec._lock:
             if self.plan.materialized is None:
-                child = self.children[0]
+                devs = placement_devices(self.conf)
+                nparts = self.children[0].num_partitions
+
+                def load(p):
+                    return self._load_partition(
+                        p, devs[p % len(devs)] if devs else None)
+
+                if len(devs) > 1 and nparts > 1:
+                    # one upload stream a chip: the row ranges decode and
+                    # upload side by side, each into its own HBM
+                    from spark_rapids_tpu.runtime.host_pool import (
+                        get_host_pool,
+                    )
+                    merged = list(get_host_pool(self.conf).map_ordered(
+                        load, range(nparts), max_concurrency=len(devs)))
+                else:
+                    merged = [load(p) for p in range(nparts)]
+                if devs:
+                    merged = _uniform_shards(merged, devs)
                 out = []
-                for p in range(child.num_partitions):
-                    with TaskContext(partition_id=p) as tctx:
-                        batches = list(child.execute_partition(tctx, p))
-                    if batches:
-                        # ONE device batch per partition: every query over
-                        # the cache then costs a fixed handful of fused
-                        # dispatches instead of one chain per source chunk.
-                        # Registered spillable: under HBM pressure the
-                        # cache pages out to host/disk instead of OOMing.
-                        merged = K.compact_batch(K.concat_batches(batches))
-                        _attach_column_stats(merged)
-                        batches = [SpillableColumnarBatch(merged)]
-                    out.append(batches)
+                for p, m in enumerate(merged):
+                    if m is None:
+                        out.append([])
+                        continue
+                    _attach_column_stats(m)
+                    # Registered spillable: under HBM pressure the cache
+                    # pages out to host/disk instead of OOMing.
+                    out.append([SpillableColumnarBatch(m)])
+                self.plan.placed_on = tuple(devs)
                 self.plan.materialized = out
         return self.plan.materialized
 
+    def resident_shards(self, pids) -> Optional[List[ColumnarBatch]]:
+        """The merged batch of each partition in `pids`, where it lives
+        (no copy), for a sharded stage that computes where the shards
+        are; None if the cache is not placed over a mesh or a partition
+        is not exactly one batch."""
+        mat = self._materialize()
+        if not self.plan.placed_on:
+            return None
+        out = []
+        for p in pids:
+            if len(mat[p]) != 1:
+                return None
+            out.append(mat[p][0].get_batch())
+        return out
+
     def execute_partition(self, ctx, pidx):
-        for sb in self._materialize()[pidx]:
-            yield sb.get_batch()
+        mat = self._materialize()
+        home = self.plan.placed_on
+        for sb in mat[pidx]:
+            b = sb.get_batch()
+            if len(home) > 1 and pidx % len(home):
+                # an operator that knows no mesh computes on the default
+                # device: the shard crosses the interconnect, each query
+                self.metrics.metric(M.MESH_PUT_BYTES).add(
+                    b.device_memory_size())
+                cols = jax.device_put(b.columns, home[0])
+                for src, dst in zip(b.columns, cols):
+                    dst.bounds = src.bounds
+                b = ColumnarBatch(cols, b.num_rows, b.row_mask)
+            yield b
+
+
+def _uniform_shards(shards: List[Optional[ColumnarBatch]], devs
+                    ) -> List[Optional[ColumnarBatch]]:
+    """Make the per-device shards of a placed cache uniform planes, each
+    staying on its device: one capacity; a string column dictionary-coded
+    against ONE vocabulary for the whole table (replicated on every
+    chip, int32 codes a shard), so that equal strings have equal codes
+    in every shard; a validity plane on every shard of a column or on
+    none. Shard p lives on devs[p % len(devs)]."""
+    live = [(p, b) for p, b in enumerate(shards) if b is not None]
+    if len(live) < 2:
+        return shards
+    cap = max(b.capacity for _p, b in live)
+    ncols = live[0][1].num_cols
+    cols_of = {p: list(b.columns) for p, b in live}
+
+    def on(p):
+        return jax.default_device(devs[p % len(devs)])
+
+    for p, b in live:
+        if b.capacity != cap:
+            with on(p):
+                cols_of[p] = [_resize_col(c, cap) for c in cols_of[p]]
+    for j in range(ncols):
+        col_j = [cols_of[p][j] for p, _b in live]
+        if all(c.is_dict for c in col_j):
+            uoff, ubytes, remaps = K.unify_vocabs(col_j)
+            unique = all(c.dict_unique for c in col_j)
+            for (p, _b), c, remap in zip(live, col_j, remaps):
+                dev = devs[p % len(devs)]
+                codes = c.data["codes"]
+                if len(remap) and not np.array_equal(
+                        remap, np.arange(len(remap))):
+                    with on(p):
+                        codes = jnp.asarray(remap)[
+                            jnp.clip(codes, 0, len(remap) - 1)]
+                cols_of[p][j] = ColumnVector(
+                    c.dtype, {"codes": codes,
+                              "dict_offsets": jax.device_put(uoff, dev),
+                              "dict_bytes": jax.device_put(ubytes, dev)},
+                    c.validity, dict_unique=unique)
+        if any(cols_of[p][j].validity is not None for p, _b in live) \
+                and not cols_of[live[0][0]][j].is_nested:
+            for p, b in live:
+                c = cols_of[p][j]
+                if c.validity is None:
+                    with on(p):
+                        cols_of[p][j] = ColumnVector(
+                            c.dtype, c.data,
+                            c.validity_or_default(b.num_rows),
+                            dict_unique=c.dict_unique)
+    out = list(shards)
+    for p, b in live:
+        out[p] = ColumnarBatch(cols_of[p], b.num_rows, b.row_mask)
+    return out
 
 
 def _attach_column_stats(batch: ColumnarBatch) -> None:
@@ -2669,6 +2798,13 @@ class HashAggregateExec(TpuExec):
         self.pre_chain_members: List[TpuExec] = []
         self.fused_stage_id = 0
         self._chain_failed = False
+        #: mesh size when the planner (exec/sharded.shard_stages) found
+        #: this partial aggregate over a cached table: the update phase
+        #: of all partitions then runs as SPMD waves over the resident
+        #: shards, and only the partial states leave the chips
+        self.shard_over = 0
+        self._shard_out = None  # per-partition states; False = unsharded
+        self._shard_lock = threading.Lock()
 
     # ---- schema of the partial (state) batches ----
     def state_fields(self):
@@ -2727,10 +2863,16 @@ class HashAggregateExec(TpuExec):
 
     def tree_string(self, indent: int = 0) -> str:
         if not self.pre_chain_members:
-            return super().tree_string(indent)
+            line = super().tree_string(indent)
+            if not self.shard_over:
+                return line
+            head, nl, rest = line.partition("\n")
+            return f"{head} [sharded n={self.shard_over}]{nl}{rest}"
         pad = "  " * indent
         sid = self.fused_stage_id
-        lines = [f"{pad}*({sid}) {self.name()} <- {self.plan.describe()}"]
+        lines = [f"{pad}*({sid}) {self.name()} <- {self.plan.describe()}"
+                 + (f" [sharded n={self.shard_over}]"
+                    if self.shard_over else "")]
         for m in reversed(self.pre_chain_members):
             lines.append(f"{pad}  *({sid}) {type(m).__name__} "
                          f"<- {m.plan.describe()} [fused]")
@@ -2738,8 +2880,80 @@ class HashAggregateExec(TpuExec):
             lines.append(c.tree_string(indent + 1))
         return "\n".join(lines)
 
+    # -- the update phase where the shards live (exec/sharded.py) ----------
+
+    def _sharded_states(self, ctx) -> Optional[List[ColumnarBatch]]:
+        """One partial-state batch a partition, computed by SPMD waves
+        over the child cache's resident shards (once, for all
+        partitions); None when the shards are not in place or the
+        program does not trace: the per-partition path then runs."""
+        with self._shard_lock:
+            if self._shard_out is None:
+                self._shard_out = self._run_sharded(ctx) or False
+        return self._shard_out or None
+
+    def _run_sharded(self, ctx) -> Optional[List[ColumnarBatch]]:
+        from spark_rapids_tpu.exec.sharded import MeshWave, input_refs
+        from spark_rapids_tpu.expr.core import SparkException
+        from spark_rapids_tpu.parallel.mesh import MeshDeviceError
+        child, m, kern = self.children[0], self.shard_over, self.kern
+        nparts = child.num_partitions
+        if nparts % m:
+            return None
+        ansi = self.conf.get(C.ANSI_ENABLED)
+        wave = self.shard_wave = MeshWave(
+            m, self.pre_chain or [], [f.dtype for f in child.schema.fields],
+            input_refs(self.pre_chain_members,
+                       kern._state_input_exprs() + [self.pre_filter]),
+            tail=lambda: kern._build_update(ansi),
+            tail_key=self._sig("update", ansi))
+        state_dtypes = [f.dtype for f in self.state_fields()]
+        disp_t = self.metrics.metric(M.SHARD_DISPATCH_TIME)
+        back_t = self.metrics.metric(M.SHARD_READBACK_TIME)
+        member_rows = [mb.metrics.metric(M.NUM_OUTPUT_ROWS)
+                       for mb in self.pre_chain_members]
+        outs: List[ColumnarBatch] = []
+        for g0 in range(0, nparts, m):
+            pids = list(range(g0, g0 + m))
+            operands = wave.resident_operands(child.resident_shards(pids))
+            if operands is None:
+                return None
+            self._acquire(ctx)
+            try:
+                with self.span(disp_t):
+                    out = wave.dispatch(operands, pids)
+                with self.span(back_t):
+                    states, rows = wave.read_back(out, range(m),
+                                                  state_dtypes)
+            except (SparkException, MeshDeviceError,
+                    LC.QueryCancelledError):
+                raise  # typed errors are not trace failures
+            except Exception:  # noqa: BLE001 - the per-stage fallback
+                import logging
+                logging.getLogger("spark_rapids_tpu").warning(
+                    "sharded update trace failed for %s; falling back to "
+                    "the per-partition update", self.name(), exc_info=True)
+                from spark_rapids_tpu.runtime import obs as _obs
+                _obs.note_exec_fallback("sharded_agg")
+                return None
+            self.metrics.metric(M.STAGE_DISPATCHES).add(1)
+            self.metrics.metric(M.SHARD_WAVES).add(1)
+            self.metrics.metric(M.NUM_INPUT_BATCHES).add(m)
+            for mr, r in zip(member_rows, rows):
+                mr.add(int(r.sum()))
+            outs.extend(states[i] for i in range(m))
+        return outs
+
     def execute_partition(self, ctx, pidx):
         agg_t = self.metrics.metric(M.AGG_TIME)
+        if self.shard_over:
+            states = self._sharded_states(ctx)
+            if states is not None:
+                self.metrics.metric(M.NUM_OUTPUT_ROWS).add(
+                    states[pidx].num_rows)
+                self.metrics.metric(M.NUM_OUTPUT_BATCHES).add(1)
+                yield states[pidx]
+                return
         child_batches = self.children[0].execute_partition(ctx, pidx)
         nkeys = len(self.plan.group_exprs)
 
@@ -3484,8 +3698,9 @@ class ShuffleExchangeExec(ExchangeExec):
     def _ici_first(self):
         # the in-program all_to_all is the shuffle whenever the session
         # runs sharded (multichip) or asks for it outright (SHUFFLE_MODE)
+        from spark_rapids_tpu.parallel.mesh import multichip_on
         return (self.conf.get(C.SHUFFLE_MODE).upper() == "ICI"
-                or bool(self.conf.get(C.MULTICHIP_ENABLED)))
+                or multichip_on(self.conf))
 
     @property
     def _streaming_ok(self):
@@ -3848,6 +4063,26 @@ class ShuffleExchangeExec(ExchangeExec):
             target, counts_host = jax.device_get((pid_all, counts_dev))
             counts_host = np.asarray(counts_host)
         else:
+            keys = self.keys
+
+            def _build_src_hash():
+                def f(b):
+                    live = b.live_mask()
+                    ectx = EvalCtx(b.columns, traced_rows(b.num_rows),
+                                   b.capacity, False, live=live)
+                    key_cols = [e.eval_tpu(ectx) for e in keys]
+                    h = K.partition_hash_batch(key_cols, b.num_rows,
+                                               live=live)
+                    pid = _pmod(h, n)
+                    return pid, RP.partition_counts(pid, live, n)
+                return f
+
+            # keyed like the fast path above: run eagerly, the hash of a
+            # string key's vocabulary is a while loop jax compiles anew
+            # on every call (8 compiles a Q1 over four shards)
+            sfn = fuse.fused(
+                ("ici_hash_src", n,
+                 tuple(e.fingerprint() for e in keys)), _build_src_hash)
             tgt_parts = []
             count_parts = []
             for b in batches:
@@ -3855,14 +4090,8 @@ class ShuffleExchangeExec(ExchangeExec):
                     tgt_parts.append(np.zeros(cap, np.int32))
                     count_parts.append(jnp.zeros(n, jnp.int32))
                     continue
-                ectx = EvalCtx(b.columns, traced_rows(b.num_rows),
-                               b.capacity, False, live=b.live_mask())
-                key_cols = [e.eval_tpu(ectx) for e in self.keys]
-                h = K.partition_hash_batch(key_cols, b.num_rows,
-                                           live=b.live_mask())
-                pid = _pmod(h, n)
-                count_parts.append(
-                    RP.partition_counts(pid, b.live_mask(), n))
+                pid, counts = sfn(b)
+                count_parts.append(counts)
                 tgt_parts.append(pad_plane(pid, 0, np.int32))
             target = np.concatenate(tgt_parts)
             counts_host = np.asarray(jax.device_get(jnp.stack(count_parts)))

@@ -116,6 +116,29 @@ def check_mesh_devices(mesh: Mesh) -> None:
             "re-initialized — rebuild the mesh before dispatching")
 
 
+def multichip_on(conf) -> bool:
+    """Whether the session runs over a mesh. An explicit
+    ``spark.rapids.sql.multichip.enabled`` decides; unset, the engine
+    takes the mesh it is given: on when the process has more than one
+    accelerator device, off with one and on the CPU simulator (whose
+    virtual devices are a test harness, not a deployment)."""
+    from spark_rapids_tpu import config as C
+    v = conf.get(C.MULTICHIP_ENABLED)
+    if v is not None:
+        return bool(v)
+    devs = jax.devices()
+    return len(devs) > 1 and devs[0].platform != "cpu"
+
+
+def placement_devices(conf) -> Sequence:
+    """The devices a cached table's row ranges are placed over, in mesh
+    order (partition p lives on entry p mod n); empty when the session
+    runs no mesh."""
+    if not multichip_on(conf):
+        return ()
+    return tuple(mesh_devices(multichip_devices(conf)))
+
+
 def multichip_devices(conf) -> int:
     """How many devices the `part` axis gets under the session conf:
     ``spark.rapids.sql.multichip.devices`` (0 = all available), clamped

@@ -119,9 +119,12 @@ def expr_name(e: Expression, idx: int) -> str:
 class InMemorySource(PlanNode):
     """A pyarrow Table split into partitions (local-mode data source)."""
 
-    def __init__(self, table, num_partitions: int = 1):
+    def __init__(self, table, num_partitions: Optional[int] = None):
         self.table = table
-        self.num_partitions = max(1, num_partitions)
+        #: None = the caller gave no count: one partition, and a cache
+        #: under a mesh may split the row range over its devices
+        self.explicit_partitions = num_partitions is not None
+        self.num_partitions = max(1, num_partitions or 1)
         self.children = []
 
     @property
@@ -240,6 +243,9 @@ class CachedRelation(PlanNode):
     def __init__(self, child: PlanNode):
         self.children = [child]
         self.materialized = None  # List[List[ColumnarBatch]] set by the exec
+        #: the devices the partitions were placed over (partition p on
+        #: entry p mod n); () for a cache on the default device
+        self.placed_on = ()
 
     @property
     def schema(self) -> T.Schema:

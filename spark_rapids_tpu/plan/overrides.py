@@ -1312,9 +1312,8 @@ def convert_plan(plan: P.PlanNode, conf):
     # multichip sharding: eligible fused stages re-dispatch as ONE SPMD
     # program per batch-wave over the mesh (spark.rapids.sql.multichip.
     # enabled; ineligible stages record their fallback reason)
-    if conf.get(C.MULTICHIP_ENABLED):
-        from spark_rapids_tpu.exec.sharded import shard_stages
-        exec_root = shard_stages(exec_root, conf)
+    from spark_rapids_tpu.exec.sharded import shard_stages
+    exec_root = shard_stages(exec_root, conf)
     # pipelined execution: bounded producer/consumer boundaries at
     # scan->compute edges so host decode/upload of batch i+1 overlaps
     # device compute of batch i (spark.rapids.sql.pipeline.enabled)

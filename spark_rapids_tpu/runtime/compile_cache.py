@@ -142,15 +142,15 @@ def _fp_of(c) -> Tuple:
     if fp is None:
         fp = (bool(c.get(C.ANSI_ENABLED)),
               bool(c.get(C.IMPROVED_FLOAT_OPS)))
-        if c.get(C.MULTICHIP_ENABLED):
+        from spark_rapids_tpu.parallel import mesh as _mesh
+        if _mesh.multichip_on(c):
             # sharded executables trace against a specific mesh shape:
             # 1-dev and 8-dev sessions must never share an entry. The
             # component is appended ONLY while multichip is on, so
             # default-path keys (and every artifact derived from them)
             # stay byte-identical to pre-multichip builds. RapidsConf.set
             # pops the memo, so flipping the conf re-fingerprints.
-            from spark_rapids_tpu.parallel.mesh import mesh_fingerprint
-            fp = fp + ("mesh",) + mesh_fingerprint(c)
+            fp = fp + ("mesh",) + _mesh.mesh_fingerprint(c)
         try:
             c._compile_fp = fp
         except Exception:  # noqa: BLE001 - a frozen conf object just

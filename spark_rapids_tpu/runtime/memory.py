@@ -54,6 +54,16 @@ def _record_spill(kind: str, nbytes: int, dur_ns: int,
         "bytes": nbytes, "dur_ns": dur_ns, "handle": handle_id[:8]})
 
 
+def _committed_device(batch: ColumnarBatch):
+    """The one device `batch`'s planes are committed to, else None."""
+    for leaf in jax.tree_util.tree_leaves(batch.columns):
+        if isinstance(leaf, jax.Array):
+            devs = leaf.devices()
+            return next(iter(devs)) if leaf.committed and len(devs) == 1 \
+                else None
+    return None
+
+
 class SpillableHandle:
     """One registered batch. State machine: device -> host -> disk,
     rematerialized back to device on demand (`get`). Priority: larger
@@ -72,6 +82,9 @@ class SpillableHandle:
         self._lock = _san.lock("memory.handle")
         self._tier = DEVICE
         self._device: Optional[ColumnarBatch] = batch
+        #: the chip a shard of a mesh-placed cache is committed to: it
+        #: comes back there, not to the default device
+        self._home = _committed_device(batch)
         self._host = None  # leaves (host numpy)
         self._disk_paths: Optional[List[str]] = None
         self._treedef = None
@@ -157,7 +170,8 @@ class SpillableHandle:
             self.fw.reserve(self.size, exclude=self, best_effort=True)
             with self._lock:
                 if self._tier == HOST:
-                    leaves = [jax.device_put(x) if isinstance(x, np.ndarray)
+                    leaves = [jax.device_put(x, self._home)
+                              if isinstance(x, np.ndarray)
                               else x for x in self._host]
                     batch = jax.tree_util.tree_unflatten(self._treedef, leaves)
                     self._device = ColumnarBatch(
@@ -475,9 +489,10 @@ def get_spill_framework(conf=None) -> SpillFramework:
 
 
 def _device_budget_from(conf) -> int:
-    """HBM budget = min(budgetBytes, allocFraction x detected chip HBM).
-    The fraction keeps headroom for XLA scratch on chips whose HBM the
-    runtime can report; budgetBytes remains the explicit ceiling."""
+    """HBM budget = min(budgetBytes, allocFraction x detected chip HBM),
+    times the chips of the session's mesh. The fraction keeps headroom
+    for XLA scratch on chips whose HBM the runtime can report;
+    budgetBytes remains the explicit ceiling a chip."""
     budget = conf.get(C.DEVICE_MEMORY_BUDGET)
     frac = conf.get(C.DEVICE_MEMORY_FRACTION)
     import jax
@@ -486,6 +501,9 @@ def _device_budget_from(conf) -> int:
     total = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
     if total:
         budget = min(budget, int(total * frac))
+        # the ledger is process-wide: a mesh of n chips holds n budgets
+        from spark_rapids_tpu.parallel.mesh import placement_devices
+        budget *= max(1, len(placement_devices(conf)))
     elif dev.platform == "tpu":
         # the CPU simulator reports no HBM; a chip that cannot say how
         # much it has must not silently run under the constant

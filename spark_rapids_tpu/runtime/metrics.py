@@ -75,6 +75,18 @@ STAGE_DISPATCHES = "stageDispatches"
 #: the mesh, so shardWaves * n_shards bounds the partition batches the
 #: multichip path absorbed into collective dispatches
 SHARD_WAVES = "shardWaves"
+#: ns a sharded stage spent issuing its SPMD program: operands assembled
+#: (in place from resident shards, or packed on the host and put over the
+#: mesh), the keyed dispatch and its retry. No host sync inside
+SHARD_DISPATCH_TIME = "shardDispatchTime"
+#: ns a sharded stage spent bringing its output (partial states, or the
+#: chain's planes) back to the host: the sync on the SPMD program
+SHARD_READBACK_TIME = "shardReadbackTime"
+#: bytes a query moved onto or between chips to feed a mesh: the planes a
+#: sharded stage packed on the host and put over the mesh, and the
+#: shards of a placed cache handed to an operator that computes on the
+#: default device. 0 when the shards are consumed where they live
+MESH_PUT_BYTES = "meshPutBytes"
 #: ns a shuffle exchange spent inside the in-program ICI all_to_all
 #: dispatch (the shard_map'd collective itself, issued with NO host
 #: sync in the span). NESTED inside partitionTime — rollups and

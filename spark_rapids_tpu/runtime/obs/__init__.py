@@ -747,9 +747,8 @@ def on_query_end(token, *, session, plan, status: str,
             mesh_doc = None
             try:
                 conf = getattr(session, "conf", None)
-                from spark_rapids_tpu import config as C
-                if conf is not None and conf.get(C.MULTICHIP_ENABLED):
-                    from spark_rapids_tpu.parallel import mesh as _mesh
+                from spark_rapids_tpu.parallel import mesh as _mesh
+                if conf is not None and _mesh.multichip_on(conf):
                     mesh_doc = {
                         "n_devices": _mesh.multichip_devices(conf),
                         "axes": [_mesh.PART_AXIS],

@@ -34,7 +34,16 @@ the epilogue the account becomes one record in the obs ring
                               accumulators attribution.finish() returns
                               (semaphore_wait, compile, retry_backoff,
                               spill), plus deviceWaitTime (below)
-    counters                  keyed_dispatches, upload_bytes (uploadBytes)
+    counters                  keyed_dispatches, upload_bytes (uploadBytes),
+                              shard_waves (shardWaves: SPMD waves the
+                              sharded stages dispatched), mesh_put_bytes
+                              (meshPutBytes: table planes moved onto or
+                              between chips to feed a mesh; 0 when the
+                              shards are consumed where they live)
+    mesh                      only when a sharded stage ran: devices (the
+                              mesh size) and shard_rows (per stage, the
+                              live rows each shard's last body put out,
+                              summed over the query's waves)
     wall_ms, error_class, finished_unix[, degraded_reason, slo_breach]
                               what ObsState.last_query always carried
 
@@ -57,7 +66,8 @@ import time
 from typing import Dict, Optional
 
 from spark_rapids_tpu.runtime.metrics import (
-    ESSENTIAL, UPLOAD_BYTES, GpuMetric, walk_exec_tree,
+    ESSENTIAL, MESH_PUT_BYTES, SHARD_WAVES, UPLOAD_BYTES, GpuMetric,
+    walk_exec_tree,
 )
 
 #: phase -> the span that times it
@@ -111,7 +121,7 @@ class QueryPhases:
     """One top-level action's account (module docstring)."""
 
     __slots__ = ("t0_ns", "parse_ns", "clocks", "dispatches0", "wait0",
-                 "exec_root", "_peeked")
+                 "exec_root", "_peeked", "_shard_waves")
 
     def __init__(self, plan):
         self.t0_ns = time.perf_counter_ns()
@@ -121,6 +131,7 @@ class QueryPhases:
         self.wait0 = device_wait_ns
         self.exec_root = None
         self._peeked: Optional[Dict[str, dict]] = None
+        self._shard_waves: list = []  # exec/sharded.MeshWave, by the walk
 
     def span(self, phase: str):
         return span(phase, self.clocks[phase])
@@ -138,10 +149,13 @@ class QueryPhases:
         last_metrics() shape but through GpuMetric.peek: the ONE walk of
         a query's epilogue, shared by attribution and the record."""
         if self._peeked is None:
-            self._peeked = {} if self.exec_root is None else {
-                key: node.metrics.peek_snapshot()
-                for key, node, _d, _role, _sid in walk_exec_tree(
-                    self.exec_root)}
+            self._peeked = {}
+            for key, node, _d, _role, _sid in walk_exec_tree(
+                    self.exec_root) if self.exec_root is not None else ():
+                self._peeked[key] = node.metrics.peek_snapshot()
+                wave = getattr(node, "shard_wave", None)
+                if wave is not None:
+                    self._shard_waves.append(wave)
         return self._peeked
 
     def record(self, query_id, status: str, duration_ns: int,
@@ -154,13 +168,17 @@ class QueryPhases:
         phases["unspanned"] = wall_ns - sum(
             phases[p] for p in ("admit", "plan", "execute", "epilogue"))
         timers: Dict[str, int] = {}
-        upload = 0
+        upload = waves = mesh_put = 0
         for snap in self.peek_metrics().values():
             for name, v in snap.items():
                 if name.endswith("Time"):
                     timers[name] = timers.get(name, 0) + v
                 elif name == UPLOAD_BYTES:
                     upload += v
+                elif name == SHARD_WAVES:
+                    waves += v
+                elif name == MESH_PUT_BYTES:
+                    mesh_put += v
         for bucket, ns in (extra or {}).items():
             timers[bucket] = timers.get(bucket, 0) + int(ns)
         timers["deviceWaitTime"] = device_wait_ns - self.wait0
@@ -171,13 +189,18 @@ class QueryPhases:
             "phases_ns": phases, "timers_ns": timers,
             "counters": {
                 "keyed_dispatches": keyed_dispatches - self.dispatches0,
-                "upload_bytes": upload},
+                "upload_bytes": upload, "shard_waves": waves,
+                "mesh_put_bytes": mesh_put},
             "wall_ms": round(duration_ns / 1e6, 3),
             "error_class": type(error).__name__ if error else None,
             "finished_unix": time.time(),
         }
         if degraded_reason is not None:
             rec["degraded_reason"] = degraded_reason
+        ran = [w for w in self._shard_waves if w.shard_rows.any()]
+        if ran:
+            rec["mesh"] = {"devices": ran[0].m, "shard_rows": [
+                [int(r) for r in w.shard_rows] for w in ran]}
         return rec
 
 

@@ -32,6 +32,10 @@ class TaskContext:
         from spark_rapids_tpu.runtime.obs import live as _live
         self.query_id = _live.current_query_id()
         self.holds_device_data = False
+        #: where this task's source uploads its batches; None is the
+        #: default device. A cache placed over a mesh sets it to the
+        #: partition's own device (CachedScanExec._materialize)
+        self.device = None
         self.start_ns = time.perf_counter_ns()
         self._metrics: Dict[str, GpuMetric] = {}
         self._completion: List[Callable[[], None]] = []

@@ -251,10 +251,34 @@ class DataFrame:
 
     def cache(self) -> "DataFrame":
         """Pin this DataFrame's result in device HBM; repeated queries over
-        it skip the scan + upload entirely."""
-        return DataFrame(P.CachedRelation(self.plan), self.session)
+        it skip the scan + upload entirely. Under a mesh of n devices an
+        in-memory source given no partition count is cached as n row
+        ranges, one a device (CachedScanExec places partition p on device
+        p mod n); an explicit count is respected."""
+        plan = self.plan
+        if isinstance(plan, P.InMemorySource) \
+                and not plan.explicit_partitions:
+            from spark_rapids_tpu.parallel.mesh import placement_devices
+            n = len(placement_devices(self.session.conf))
+            if n > 1:
+                plan = P.InMemorySource(plan.table, n)
+                plan.explicit_partitions = False
+        return DataFrame(P.CachedRelation(plan), self.session)
 
     persist = cache
+
+    def unpersist(self) -> "DataFrame":
+        """Drop a cache()'d result from HBM, every partition's shard on
+        its own chip; the next action materializes it again."""
+        if isinstance(self.plan, P.CachedRelation):
+            from spark_rapids_tpu.exec.tpu_nodes import CachedScanExec
+            with CachedScanExec._lock:
+                parts, self.plan.materialized = self.plan.materialized, None
+                self.plan.placed_on = ()
+            for part in parts or ():
+                for sb in part:
+                    sb.close()
+        return self
 
     def distinct(self) -> "DataFrame":
         keys = [E.col(n) for n in self.plan.schema.names]

@@ -161,7 +161,8 @@ class TpuSession:
             pass  # isn't warmup-replayable
         return df
 
-    def create_dataframe(self, data, num_partitions: int = 1) -> DataFrame:
+    def create_dataframe(self, data,
+                         num_partitions: Optional[int] = None) -> DataFrame:
         self._activate()
         if isinstance(data, dict):
             table = pa.table(data)

@@ -670,7 +670,9 @@ def test_recent_queries_one_record_per_top_level_action():
         assert p["unspanned"] >= 0
         assert r["wall_ns"] >= r["wall_ms"] * 1e6 * 0.999  # + the epilogue
         assert r["timers_ns"]["copyToDeviceTime"] > 0
-        assert set(r["counters"]) == {"keyed_dispatches", "upload_bytes"}
+        assert set(r["counters"]) == {"keyed_dispatches", "upload_bytes",
+                                      "shard_waves", "mesh_put_bytes"}
+        assert "mesh" not in r  # no sharded stage ran
         assert r["counters"]["upload_bytes"] > 0  # the in-memory scan's
     # the parse rides on the plan: a SQL action has it, a DataFrame's not
     assert recs[0]["phases_ns"]["parse"] > 0
