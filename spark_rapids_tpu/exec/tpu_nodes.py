@@ -139,6 +139,14 @@ class InMemoryScanExec(TpuExec):
                 break
 
 
+def _note_scan_columns(ex: TpuExec) -> None:
+    """numScanColumns / numScanColumnsPruned of a Parquet scan exec."""
+    read = len(ex.plan.schema.fields)
+    whole = len((ex.plan.narrowed_from or ex.plan).schema.fields)
+    ex.metrics.metric(M.NUM_SCAN_COLUMNS).set(read)
+    ex.metrics.metric(M.NUM_SCAN_COLUMNS_PRUNED).set(whole - read)
+
+
 class ParquetScanExec(TpuExec):
     """Parquet scan: host-side read (pyarrow footer+decode) then one device
     upload per batch. Pushed-down filters prune hive-partition files at
@@ -167,6 +175,7 @@ class ParquetScanExec(TpuExec):
         else:
             kept = list(range(len(paths)))
         self._kept_files = kept
+        _note_scan_columns(self)
 
     @property
     def num_partitions(self):
@@ -311,6 +320,7 @@ class EncodedParquetSourceExec(TpuExec):
         else:
             kept = list(range(len(paths)))
         self._kept_files = kept
+        _note_scan_columns(self)
         #: column -> fallback reason (plan-time probe + execute-time
         #: page surprises): the explain/history surface
         self.fallback_columns: dict = {}

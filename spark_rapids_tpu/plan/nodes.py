@@ -50,14 +50,18 @@ class PlanNode:
         if isinstance(self, InMemorySource):
             return self.table.num_rows
         if isinstance(self, ParquetScan):
-            if getattr(self, "_est_rows", None) is None:
+            # the files' row count, whatever the columns read: a narrowed
+            # copy asks the scan it was cut from, so a view's footers
+            # are walked once, not once a query
+            src = self.narrowed_from or self
+            if getattr(src, "_est_rows", None) is None:
                 try:
                     import pyarrow.parquet as pq
-                    self._est_rows = sum(pq.ParquetFile(p).metadata.num_rows
-                                         for p in self.paths)
+                    src._est_rows = sum(pq.ParquetFile(p).metadata.num_rows
+                                        for p in src.paths)
                 except Exception:  # noqa: BLE001 - stats are advisory
-                    self._est_rows = -1
-            return None if self._est_rows < 0 else self._est_rows
+                    src._est_rows = -1
+            return None if src._est_rows < 0 else src._est_rows
         if isinstance(self, Range):
             return max(0, -(-(self.end - self.start) // self.step))
         if isinstance(self, Filter):
@@ -278,7 +282,25 @@ class ParquetScan(PlanNode):
         if columns and partition_values:
             pkeys = {k for v in partition_values for k in v}
             self.file_columns = [c for c in columns if c not in pkeys]
+        #: the scan this one was cut from by the planner's column pruning
+        #: (`narrowed`); None for a scan as its caller built it
+        self.narrowed_from: Optional["ParquetScan"] = None
         self.children = []
+
+    def narrowed(self, keep: Sequence[int]) -> "ParquetScan":
+        """A NEW scan of the same files reading only the columns at
+        schema positions `keep` (ascending, so the order stays this
+        scan's). This node is left as it is: a view's scan is shared by
+        every later query, which may name other columns. The schema is
+        carried over (no footer is read again) and the constructor
+        derives `file_columns` and the partition keys anew."""
+        fields = self.schema.fields
+        q = ParquetScan(self.paths,
+                        schema=T.Schema(tuple(fields[i] for i in keep)),
+                        columns=[fields[i].name for i in keep],
+                        partition_values=self.partition_values)
+        q.narrowed_from = self.narrowed_from or self
+        return q
 
     def partition_fields(self) -> List[T.StructField]:
         if not self.partition_values:
@@ -335,7 +357,11 @@ class ParquetScan(PlanNode):
         return self._schema
 
     def describe(self):
-        return f"ParquetScan[{len(self.paths)} files]"
+        if self.narrowed_from is None:
+            return f"ParquetScan[{len(self.paths)} files]"
+        return (f"ParquetScan[{len(self.paths)} files, "
+                f"{len(self.schema.fields)} of "
+                f"{len(self.narrowed_from.schema.fields)} columns]")
 
 
 class Range(PlanNode):

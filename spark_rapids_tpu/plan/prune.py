@@ -8,15 +8,27 @@ gathers every input column — measured ~150-350 ms per 8-30M-row gather
 on v5e. Dropping unreferenced columns before those operators is worth
 more than any kernel tuning on them.
 
-Two rewrites, applied bottom-up:
+Why it matters more still over files: a `read_parquet` view names every
+column of its table, and each column a scan keeps is parsed by the host
+and uploaded whether or not a later operator reads it (PERF.md, PR 29:
+Q6 names 4 of lineitem's 16 columns).
+
+Three rewrites. Two are applied bottom-up:
 - Project(Join(l, r)):   push the used-column subset below the join
 - Project(Window(c)):    push the used-column subset below the window
+  (and Aggregate(Project(c)) folds the project into the aggregate).
 Both rebuild the intermediate node with remapped BoundRefs and keep the
-outer Project's schema byte-identical.
+outer Project's schema byte-identical. The third runs top-down over
+their result:
+- ParquetScan under operators that read a strict subset of its columns:
+  a narrowed COPY of the scan (`ParquetScan.narrowed`) takes its place,
+  and the operators between it and the Project, Aggregate or Join that
+  absorbs the change are rebuilt with remapped BoundRefs. The scan node
+  itself is never touched: a view's scan is shared by later queries.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Set
+from typing import Dict, List, Optional, Set
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.expr import core as E
@@ -54,6 +66,24 @@ def _clone_project(old: P.Project, new_child: P.PlanNode,
     return q
 
 
+def _clone_join(j: P.Join, left: P.PlanNode, right: P.PlanNode,
+                ml: Dict[int, int], mr: Dict[int, int],
+                mc: Dict[int, int]) -> P.Join:
+    """`j` over new children: `ml`/`mr` remap the key expressions of a
+    side, `mc` the condition over the concatenated schema. A new node,
+    not a copy: the build-side caches an exec leaves on a Join belong to
+    the children it had."""
+    nj = P.Join.__new__(P.Join)
+    nj.children = [left, right]
+    nj.left_keys = [_remap(e, ml) for e in j.left_keys]
+    nj.right_keys = [_remap(e, mr) for e in j.right_keys]
+    nj.how = j.how
+    nj.condition_raw = j.condition_raw
+    nj.condition = (_remap(j.condition, mc)
+                    if j.condition is not None else None)
+    return nj
+
+
 def _prune_join(p: P.Project, j: P.Join):
     if j.how in ("left_semi", "left_anti"):
         return p  # output = left schema only; nothing to split
@@ -77,17 +107,11 @@ def _prune_join(p: P.Project, j: P.Join):
     ul, ur = sorted(used_l), sorted(used_r)
     ml = {old: new for new, old in enumerate(ul)}
     mr = {old: new for new, old in enumerate(ur)}
-    nj = P.Join.__new__(P.Join)
-    nj.children = [_subset_project(left, ul) if len(ul) < nl else left,
-                   _subset_project(right, ur) if len(ur) < nr else right]
-    nj.left_keys = [_remap(e, ml) for e in j.left_keys]
-    nj.right_keys = [_remap(e, mr) for e in j.right_keys]
-    nj.how = j.how
-    nj.condition_raw = j.condition_raw
     mc = {**{o: ml[o] for o in ul},
           **{o + nl: mr[o] + len(ul) for o in ur}}
-    nj.condition = (_remap(j.condition, mc)
-                    if j.condition is not None else None)
+    nj = _clone_join(
+        j, _subset_project(left, ul) if len(ul) < nl else left,
+        _subset_project(right, ur) if len(ur) < nr else right, ml, mr, mc)
     return _clone_project(p, nj, [_remap(e, mc) for e in p.exprs])
 
 
@@ -169,11 +193,165 @@ def _absorb_project_into_agg(a: P.Aggregate, pr: P.Project) -> P.Aggregate:
     return na
 
 
+def _refs_of(exprs) -> Set[int]:
+    out: Set[int] = set()
+    for e in exprs:
+        _refs(e, out)
+    return out
+
+
+def _narrow_scan(s: P.ParquetScan, req: Optional[Set[int]]):
+    if req is None:
+        return s, None
+    fields = s.schema.fields
+    keep = set(req)
+    n_file = len(fields) - len(s.partition_fields())
+    if n_file and not any(i < n_file for i in keep):
+        # no column of the files is read (count(*), or partition values
+        # alone): the cheapest one carries the row count
+        keep.add(min(range(n_file),
+                     key=lambda i: fields[i].dtype.default_size()))
+    if len(keep) >= len(fields):
+        return s, None
+    keep = sorted(keep)
+    return s.narrowed(keep), {old: new for new, old in enumerate(keep)}
+
+
+def _rebuilt(p: P.PlanNode, child: P.PlanNode) -> P.PlanNode:
+    q = type(p).__new__(type(p))
+    q.children = [child]
+    return q
+
+
+def _rebuild_project(p: P.Project, child, m) -> P.PlanNode:
+    exprs = [_remap(e, m) for e in p.exprs]
+    if isinstance(child, P.ParquetScan):
+        names = child.schema.names
+        if p.names == names and all(
+                isinstance(e, E.BoundRef) and e.index == i
+                for i, e in enumerate(exprs)):
+            return child  # a subset project that the narrowed scan now is
+    return _clone_project(p, child, exprs)
+
+
+def _rebuild_aggregate(p: P.Aggregate, child, m) -> P.Aggregate:
+    q = _rebuilt(p, child)
+    q.raw_group_exprs = p.raw_group_exprs
+    q.group_exprs = [_remap(e, m) for e in p.group_exprs]
+    q.group_names = p.group_names
+    q.aggs = [a.transform(lambda n: _remap(n, m)
+                          if isinstance(n, E.BoundRef) else n)
+              for a in p.aggs]
+    return q
+
+
+def _rebuild_filter(p: P.Filter, child, m) -> P.Filter:
+    q = _rebuilt(p, child)
+    q.condition = _remap(p.condition, m)
+    return q
+
+
+def _rebuild_sort(p: P.Sort, child, m) -> P.Sort:
+    q = _rebuilt(p, child)
+    q.orders = [P.SortOrder(_remap(o.expr, m), o.ascending, o.nulls_first)
+                for o in p.orders]
+    q.global_sort = p.global_sort
+    return q
+
+
+def _rebuild_limit(p: P.Limit, child, m) -> P.Limit:
+    q = _rebuilt(p, child)
+    q.n = p.n
+    return q
+
+
+#: the unary nodes whose use of their child is known from their
+#: expressions: (type, the child columns the node itself reads, whether
+#: the child's other columns pass through to the node's output, rebuild)
+_UNARY = (
+    (P.Project, lambda p: _refs_of(p.exprs), False, _rebuild_project),
+    (P.Aggregate, lambda p: _refs_of(p.group_exprs)
+     | _refs_of(a.fn for a in p.aggs), False, _rebuild_aggregate),
+    (P.Filter, lambda p: _refs_of([p.condition]), True, _rebuild_filter),
+    (P.Sort, lambda p: _refs_of(o.expr for o in p.orders), True,
+     _rebuild_sort),
+    (P.Limit, lambda p: set(), True, _rebuild_limit),
+)
+
+
+def _set_children(p: P.PlanNode, children: List[P.PlanNode]) -> None:
+    if any(a is not b for a, b in zip(children, p.children)):
+        p.children = children
+
+
+def _narrow_scans(p: P.PlanNode, req: Optional[Set[int]]):
+    """Top-down: `req` is the set of `p`'s output columns its parent
+    reads (None: all of them, which is also the answer for a parent
+    whose use of its child is not known here). Returns `(node, m)`.
+    `m` is None when the node puts out the columns it did; the node is
+    then `p`, with at most a subtree replaced in place, as the bottom-up
+    rewrites do (the subtree is semantically identical). Otherwise the
+    node is NEW, puts out a strict subset of `p`'s columns that holds
+    `req`, in `p`'s order, and `m` maps old positions to new ones."""
+    if isinstance(p, P.ParquetScan):
+        return _narrow_scan(p, req)
+    if isinstance(p, P.CachedRelation):
+        # the cache holds the whole table for every later query
+        return p, None
+    if isinstance(p, P.Join):
+        return _narrow_join(p, req)
+    rule = next((r for r in _UNARY if isinstance(p, r[0])), None)
+    if rule is None:
+        _set_children(p, [_narrow_scans(c, None)[0] for c in p.children])
+        return p, None
+    _, own, through, rebuild = rule
+    creq = own(p)
+    if through:
+        creq = None if req is None else creq | req
+    child, m = _narrow_scans(p.children[0], creq)
+    if m is None:
+        _set_children(p, [child])
+        return p, None
+    return rebuild(p, child, m), m if through else None
+
+
+def _narrow_join(j: P.Join, req: Optional[Set[int]]):
+    left, right = j.children
+    nl = len(left.schema.fields)
+    lreq = rreq = None
+    if req is not None:
+        used = set(req)
+        if j.condition is not None:
+            _refs(j.condition, used)
+        lreq = {i for i in used if i < nl} | _refs_of(j.left_keys)
+        rreq = {i - nl for i in used if i >= nl} | _refs_of(j.right_keys)
+    nleft, ml = _narrow_scans(left, lreq)
+    nright, mr = _narrow_scans(right, rreq)
+    if ml is None and mr is None:
+        _set_children(j, [nleft, nright])
+        return j, None
+    left_out = ml  # None: the left side puts out what it did
+    if ml is None:
+        ml = {i: i for i in range(nl)}
+    if mr is None:
+        mr = {i: i for i in range(len(right.schema.fields))}
+    nl2 = len(nleft.schema.fields)
+    mc = {**ml, **{o + nl: n + nl2 for o, n in mr.items()}}
+    nj = _clone_join(j, nleft, nright, ml, mr, mc)
+    # a semi or anti join puts out its left side's columns alone
+    return nj, left_out if j.how in ("left_semi", "left_anti") else mc
+
+
 def prune_plan(p: P.PlanNode) -> P.PlanNode:
-    """Bottom-up pruning. Replaces children in place (a rewritten subtree
-    is semantically identical, so sharing with sibling plans stays
-    sound); returns the possibly-rewritten node."""
-    p.children = [prune_plan(c) for c in p.children]
+    """The bottom-up rewrites, then the scans narrowed top-down."""
+    return _narrow_scans(_prune_bottom_up(p), None)[0]
+
+
+def _prune_bottom_up(p: P.PlanNode) -> P.PlanNode:
+    """Replaces children in place (a rewritten subtree is semantically
+    identical, so sharing with sibling plans stays sound); returns the
+    possibly-rewritten node."""
+    p.children = [_prune_bottom_up(c) for c in p.children]
     if isinstance(p, P.Project):
         c = p.children[0]
         if isinstance(c, P.Join):

@@ -38,6 +38,13 @@ UPLOAD_BYTES = "uploadBytes"
 #: columns a device-decode scan host-decoded instead (unsupported
 #: type/encoding/codec; per-column reasons in explain/history)
 NUM_DECODE_FALLBACK_COLUMNS = "numDecodeFallbackColumns"
+#: columns a Parquet scan reads: its plan node's schema, partition-value
+#: columns included. Set once, where the exec is built
+NUM_SCAN_COLUMNS = "numScanColumns"
+#: columns of a Parquet scan's view (or caller-given list) that the
+#: planner's column pruning (plan/prune.py) cut from it: what the host
+#: neither parses nor uploads
+NUM_SCAN_COLUMNS_PRUNED = "numScanColumnsPruned"
 OP_TIME = "opTime"
 SORT_TIME = "sortTime"
 AGG_TIME = "aggTime"

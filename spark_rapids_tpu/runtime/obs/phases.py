@@ -39,7 +39,12 @@ the epilogue the account becomes one record in the obs ring
                               sharded stages dispatched), mesh_put_bytes
                               (meshPutBytes: table planes moved onto or
                               between chips to feed a mesh; 0 when the
-                              shards are consumed where they live)
+                              shards are consumed where they live),
+                              scan_columns_read, scan_columns_pruned
+                              (numScanColumns, numScanColumnsPruned over
+                              the query's Parquet scans: columns the host
+                              parsed and uploaded, and columns the
+                              planner's pruning cut from the scans)
     mesh                      only when a sharded stage ran: devices (the
                               mesh size) and shard_rows (per stage, the
                               live rows each shard's last body put out,
@@ -66,9 +71,15 @@ import time
 from typing import Dict, Optional
 
 from spark_rapids_tpu.runtime.metrics import (
-    ESSENTIAL, MESH_PUT_BYTES, SHARD_WAVES, UPLOAD_BYTES, GpuMetric,
-    walk_exec_tree,
+    ESSENTIAL, MESH_PUT_BYTES, NUM_SCAN_COLUMNS, NUM_SCAN_COLUMNS_PRUNED,
+    SHARD_WAVES, UPLOAD_BYTES, GpuMetric, walk_exec_tree,
 )
+
+#: record counter -> the exec metric summed into it over the exec tree
+COUNTERS = {"upload_bytes": UPLOAD_BYTES, "shard_waves": SHARD_WAVES,
+            "mesh_put_bytes": MESH_PUT_BYTES,
+            "scan_columns_read": NUM_SCAN_COLUMNS,
+            "scan_columns_pruned": NUM_SCAN_COLUMNS_PRUNED}
 
 #: phase -> the span that times it
 SPANS = {"parse": "sql.parse", "admit": "query.admit",
@@ -168,17 +179,14 @@ class QueryPhases:
         phases["unspanned"] = wall_ns - sum(
             phases[p] for p in ("admit", "plan", "execute", "epilogue"))
         timers: Dict[str, int] = {}
-        upload = waves = mesh_put = 0
+        counters = {"keyed_dispatches": keyed_dispatches - self.dispatches0}
+        counters.update((c, 0) for c in COUNTERS)
         for snap in self.peek_metrics().values():
             for name, v in snap.items():
                 if name.endswith("Time"):
                     timers[name] = timers.get(name, 0) + v
-                elif name == UPLOAD_BYTES:
-                    upload += v
-                elif name == SHARD_WAVES:
-                    waves += v
-                elif name == MESH_PUT_BYTES:
-                    mesh_put += v
+            for c, name in COUNTERS.items():
+                counters[c] += snap.get(name, 0)
         for bucket, ns in (extra or {}).items():
             timers[bucket] = timers.get(bucket, 0) + int(ns)
         timers["deviceWaitTime"] = device_wait_ns - self.wait0
@@ -187,10 +195,7 @@ class QueryPhases:
             "query_id": query_id, "status": status,
             "t0_ns": self.t0_ns, "wall_ns": wall_ns,
             "phases_ns": phases, "timers_ns": timers,
-            "counters": {
-                "keyed_dispatches": keyed_dispatches - self.dispatches0,
-                "upload_bytes": upload, "shard_waves": waves,
-                "mesh_put_bytes": mesh_put},
+            "counters": counters,
             "wall_ms": round(duration_ns / 1e6, 3),
             "error_class": type(error).__name__ if error else None,
             "finished_unix": time.time(),

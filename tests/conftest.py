@@ -59,6 +59,10 @@ def _reset_runtime():
             st.slo.reset_for_tests()
         st.last_slow = None
         st.last_roofline = None
+        # the first session with a historyDir installs the store for the
+        # process: left in place, a later test's second run of one plan
+        # reads the first run's record and converts under measured hints
+        st.history = None
     # the kernel cost auditor: disarm + drop the per-query tally and
     # findings; the (entry, shape) record table deliberately persists —
     # it mirrors the process-wide warm-trace cache (tests wanting a

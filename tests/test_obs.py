@@ -671,7 +671,10 @@ def test_recent_queries_one_record_per_top_level_action():
         assert r["wall_ns"] >= r["wall_ms"] * 1e6 * 0.999  # + the epilogue
         assert r["timers_ns"]["copyToDeviceTime"] > 0
         assert set(r["counters"]) == {"keyed_dispatches", "upload_bytes",
-                                      "shard_waves", "mesh_put_bytes"}
+                                      "shard_waves", "mesh_put_bytes",
+                                      "scan_columns_read",
+                                      "scan_columns_pruned"}
+        assert r["counters"]["scan_columns_read"] == 0  # no Parquet scan
         assert "mesh" not in r  # no sharded stage ran
         assert r["counters"]["upload_bytes"] > 0  # the in-memory scan's
     # the parse rides on the plan: a SQL action has it, a DataFrame's not
