@@ -7,10 +7,10 @@ runs in it on the host) drives, through the entry points a user calls:
 
   device    fail unless jax's default device is a TPU; print the header
             every later number is under
-  load      bench.py's TPC-H-shaped tables from --seed (30M-row lineitem,
+  load      TPC-H-shaped tables made from --seed (30M-row lineitem,
             3M-row orders) cached into HBM through a default-conf session
   resident  q6, q1, q3join, q67win, q72shfl: once cold, then warm, each
-            answer checked against the host reference (bench.validate)
+            answer checked against the host reference (validate)
   scan      the same lineitem as a real Parquet file, q6 and q1 from
             read_parquet under the default conf (device decode on)
   served    q6, q1 and the q3 join as SQL text over POST /sql
@@ -46,11 +46,15 @@ import threading
 import time
 import traceback
 
+import numpy as np
+
 REPO = os.path.dirname(os.path.abspath(__file__))
 #: the Parquet file is generated here from --seed, and removed again
 DATA_DIR = os.path.join(REPO, ".chip_smoke_data")
 SECTIONS = ("load", "resident", "scan", "served", "kernels", "multichip")
 RESIDENT = ("q6", "q1", "q3join", "q67win", "q72shfl")
+#: lineitem rows at the full size (~SF5); --rows cuts it for a rehearsal
+FULL_ROWS = 30_000_000
 WARM_REPS = 3
 SERVED_REPS = 3
 
@@ -80,6 +84,279 @@ def log(msg: str) -> None:
     print(f"[chip_smoke] {msg}", file=sys.stderr, flush=True)
 
 
+# ---------------------------------------------------------------------------
+# the workload: TPC-H-shaped tables from --seed, five queries over them
+# through the DataFrame API, and the pyarrow/pandas reference of each
+#
+#   q6      filter + sum(price*discount)          scan/filter/reduce
+#   q1      group by 2 string keys, 5 aggregates  segmented aggregation
+#   q3join  lineitem x orders hash join + topN    build/probe join, sort
+#   q67win  rank over (partition, order) + agg    window family
+#   q72shfl 4-partition high-card group-by        hash shuffle exchange
+# ---------------------------------------------------------------------------
+
+SHUFFLE_PARTS = 4
+LO, HI = 8766, 9131  # [1994-01-01, 1995-01-01) in days since epoch
+#: q6's four columns: all numeric, so all device-decodable
+Q6_COLUMNS = ["l_shipdate", "l_discount", "l_quantity", "l_extendedprice"]
+
+
+def set_scale(rows: int) -> None:
+    """Size every table from the lineitem row count (--rows)."""
+    global ROWS, ORDERS, WIN_ROWS
+    ROWS = int(rows)
+    ORDERS = max(ROWS // 10, 1000)
+    #: the window query runs on a slice (engine and reference alike): a
+    #: 30M-row groupby-rank costs minutes on the pandas reference alone
+    WIN_ROWS = min(ROWS, 10_000_000)
+
+
+def make_tables(seed: int):
+    import pyarrow as pa
+
+    rng = np.random.default_rng(seed)
+    flags = np.array(["A", "N", "R"])[rng.integers(0, 3, ROWS)]
+    status = np.array(["F", "O"])[rng.integers(0, 2, ROWS)]
+    lineitem = pa.table({
+        "l_orderkey": rng.integers(0, ORDERS, ROWS).astype(np.int64),
+        "l_returnflag": flags,
+        "l_linestatus": status,
+        "l_quantity": rng.integers(1, 51, ROWS).astype(np.float64),
+        "l_extendedprice": np.round(rng.uniform(900.0, 105000.0, ROWS), 2),
+        "l_discount": np.round(rng.uniform(0.0, 0.10, ROWS), 2),
+        "l_shipdate": rng.integers(8400, 10600, ROWS).astype(np.int32),
+    })
+    orders = pa.table({
+        "o_orderkey": np.arange(ORDERS, dtype=np.int64),
+        "o_orderdate": rng.integers(8400, 10600, ORDERS).astype(np.int32),
+        "o_custkey": rng.integers(0, max(ORDERS // 10, 10), ORDERS).astype(np.int64),
+    })
+    return lineitem, orders
+
+
+def cpu_queries(t, orders):
+    import pyarrow.compute as pc
+
+    def q6():
+        m = pc.and_(
+            pc.and_(
+                pc.and_(pc.greater_equal(t["l_shipdate"], LO),
+                        pc.less(t["l_shipdate"], HI)),
+                pc.and_(pc.greater_equal(t["l_discount"], 0.05),
+                        pc.less_equal(t["l_discount"], 0.07))),
+            pc.less(t["l_quantity"], 24.0))
+        f = t.filter(m)
+        return pc.sum(pc.multiply(f["l_extendedprice"], f["l_discount"])).as_py()
+
+    def q1():
+        f = t.filter(pc.less_equal(t["l_shipdate"], 10471))
+        g = f.group_by(["l_returnflag", "l_linestatus"]).aggregate([
+            ("l_quantity", "sum"), ("l_extendedprice", "sum"),
+            ("l_quantity", "mean"), ("l_discount", "mean"),
+            ("l_quantity", "count"),
+        ])
+        return {(rf, ls): (sq, sp, mq, md, cnt) for rf, ls, sq, sp, mq, md, cnt
+                in zip(g["l_returnflag"].to_pylist(),
+                       g["l_linestatus"].to_pylist(),
+                       g["l_quantity_sum"].to_pylist(),
+                       g["l_extendedprice_sum"].to_pylist(),
+                       g["l_quantity_mean"].to_pylist(),
+                       g["l_discount_mean"].to_pylist(),
+                       g["l_quantity_count"].to_pylist())}
+
+    def q3join():
+        li = t.select(["l_orderkey", "l_shipdate", "l_extendedprice",
+                       "l_discount"])
+        li = li.filter(pc.greater(li["l_shipdate"], 9100))
+        od = orders.filter(pc.less(orders["o_orderdate"], 9500))
+        j = li.join(od, keys="l_orderkey", right_keys="o_orderkey",
+                    join_type="inner")
+        rev = pc.multiply(j["l_extendedprice"],
+                          pc.subtract(1.0, j["l_discount"]))
+        j = j.append_column("rev", rev)
+        g = j.group_by(["l_orderkey"]).aggregate([("rev", "sum")])
+        idx = pc.select_k_unstable(g, 10, [("rev_sum", "descending")])
+        top = g.take(idx)
+        return {k: round(v, 2) for k, v in
+                zip(top["l_orderkey"].to_pylist(), top["rev_sum"].to_pylist())}
+
+    def q67win():
+        import pandas as pd
+        tw = t.slice(0, WIN_ROWS)
+        df = pd.DataFrame({
+            "rf": tw["l_returnflag"].to_pandas(),
+            "ls": tw["l_linestatus"].to_pandas(),
+            "sd": tw["l_shipdate"].to_pandas(),
+        })
+        rk = df.groupby(["rf", "ls"])["sd"].rank(method="min").astype(np.int64)
+        df["rk"] = rk
+        out = df.groupby(["rf", "ls"])["rk"].max()
+        return {k: int(v) for k, v in out.items()}
+
+    def q72shfl():
+        import pyarrow as pa
+        key = pa.chunked_array([
+            np.mod(c.to_numpy(), 100_000) for c in t["l_orderkey"].chunks])
+        tt = t.select(["l_quantity"]).append_column("k", key)
+        g = tt.group_by(["k"]).aggregate([("l_quantity", "sum"),
+                                          ("l_quantity", "count")])
+        return (g.num_rows,
+                round(pc.sum(g["l_quantity_sum"]).as_py(), 2),
+                int(pc.sum(g["l_quantity_count"]).as_py()))
+
+    return {"q6": q6, "q1": q1, "q3join": q3join, "q67win": q67win,
+            "q72shfl": q72shfl}
+
+
+def cache_tables(sess, t, orders) -> dict:
+    """Upload the working set and pin it in HBM with df.cache(): the
+    frames tpu_queries runs over."""
+
+    def _mat(df, what):
+        log(f"uploading {what}...")
+        df.count()  # force HBM materialization
+        return df
+
+    cached = _mat(sess.create_dataframe(t).cache(), "lineitem")
+    return {
+        "lineitem": cached,
+        "orders": _mat(sess.create_dataframe(orders).cache(), "orders"),
+        "sharded": _mat(sess.create_dataframe(
+            t.select(["l_orderkey", "l_quantity"]),
+            num_partitions=SHUFFLE_PARTS).cache(),
+            f"sharded {ROWS} rows x {SHUFFLE_PARTS} parts (2 cols)"),
+        "window": (cached if WIN_ROWS >= ROWS
+                   else _mat(sess.create_dataframe(
+                       t.slice(0, WIN_ROWS)).cache(),
+                       f"window slice {WIN_ROWS}")),
+    }
+
+
+def tpu_queries(frames: dict) -> dict:
+    """The five queries over `frames` (cache_tables' dict, or any subset
+    of it: a query only needs its own frames when it is called — the
+    scan-from-disk passes hand in a read_parquet lineitem alone)."""
+    from spark_rapids_tpu.sql import functions as F
+    from spark_rapids_tpu.expr.core import col, lit
+    from spark_rapids_tpu.expr.window import Window
+
+    cached = frames.get("lineitem")
+    ocached = frames.get("orders")
+    sharded = frames.get("sharded")
+    wcached = frames.get("window")
+
+    def q6():
+        cond = ((col("l_shipdate") >= lit(LO)) & (col("l_shipdate") < lit(HI))
+                & (col("l_discount") >= lit(0.05)) & (col("l_discount") <= lit(0.07))
+                & (col("l_quantity") < lit(24.0)))
+        out = (cached.filter(cond)
+               .agg(F.sum(col("l_extendedprice") * col("l_discount"))))
+        return shape_answer("q6", out.to_pydict())
+
+    def q1():
+        out = (cached.filter(col("l_shipdate") <= lit(10471))
+               .group_by("l_returnflag", "l_linestatus")
+               .agg(F.sum(col("l_quantity")).alias("sq"),
+                    F.sum(col("l_extendedprice")).alias("sp"),
+                    F.avg(col("l_quantity")).alias("mq"),
+                    F.avg(col("l_discount")).alias("md"),
+                    F.count(col("l_quantity")).alias("cnt")))
+        return shape_answer("q1", out.to_pydict())
+
+    def q3join():
+        li = cached.filter(col("l_shipdate") > lit(9100))
+        od = ocached.filter(col("o_orderdate") < lit(9500))
+        j = li.join(od, on=[(col("l_orderkey"), col("o_orderkey"))],
+                    how="inner")
+        g = (j.select(col("l_orderkey"),
+                      (col("l_extendedprice")
+                       * (lit(1.0) - col("l_discount"))).alias("rev"))
+             .group_by(col("l_orderkey")).agg(F.sum("rev").alias("rev")))
+        top = g.order_by(col("rev").desc(), col("l_orderkey").asc()).limit(10)
+        return shape_answer("q3join", top.to_pydict())
+
+    def q67win():
+        w = Window.partition_by(col("l_returnflag"), col("l_linestatus")) \
+                  .order_by(col("l_shipdate"))
+        out = (wcached.select(col("l_returnflag"), col("l_linestatus"),
+                              F.rank().over(w).alias("rk"))
+               .group_by(col("l_returnflag"), col("l_linestatus"))
+               .agg(F.max("rk").alias("mx")))
+        return shape_answer("q67win", out.to_pydict())
+
+    def q72shfl():
+        g = (sharded.select((col("l_orderkey") % lit(100_000)).alias("k"),
+                            col("l_quantity"))
+             .group_by(col("k"))
+             .agg(F.sum("l_quantity").alias("s"),
+                  F.count("l_quantity").alias("c")))
+        # final reduction of the grouped result stays on device (the
+        # reference reduces its grouped table on the host the same way):
+        # the query exercises the exchange + aggregation, not the
+        # download of 100k grouped rows
+        out = g.agg(F.count(col("k")).alias("n"), F.sum(col("s")).alias("ts"),
+                    F.sum(col("c")).alias("tc"))
+        return shape_answer("q72shfl", out.to_pydict())
+
+    return {"q6": q6, "q1": q1, "q3join": q3join, "q67win": q67win,
+            "q72shfl": q72shfl}
+
+
+def shape_answer(name, d):
+    """An engine result's columns (to_pydict) as the value `validate`
+    compares with the host reference's — shared by the DataFrame queries
+    above and by the served section's SQL requests, which alias alike."""
+    if name == "q6":
+        return list(d.values())[0][0]
+    if name == "q1":
+        return {(rf, ls): (sq, sp, mq, md, cnt) for rf, ls, sq, sp, mq, md, cnt
+                in zip(d["l_returnflag"], d["l_linestatus"], d["sq"], d["sp"],
+                       d["mq"], d["md"], d["cnt"])}
+    if name == "q3join":
+        return {k: round(v, 2) for k, v in zip(d["l_orderkey"], d["rev"])}
+    if name == "q67win":
+        return {(rf, ls): int(mx) for rf, ls, mx in
+                zip(d["l_returnflag"], d["l_linestatus"], d["mx"])}
+    if name == "q72shfl":
+        return (int(d["n"][0]), round(float(d["ts"][0]), 2), int(d["tc"][0]))
+    raise KeyError(name)
+
+
+def _close(a, b, tol=1e-6):
+    return abs(a - b) <= tol * max(1.0, abs(a), abs(b))
+
+
+def validate(name, tpu_val, cpu_val) -> bool:
+    if name == "q6":
+        return _close(tpu_val, cpu_val)
+    if name == "q1":
+        return (set(tpu_val) == set(cpu_val) and all(
+            all(_close(a, b) for a, b in zip(tpu_val[k][:4], cpu_val[k][:4]))
+            and int(tpu_val[k][4]) == int(cpu_val[k][4]) for k in cpu_val))
+    if name == "q3join":
+        return (set(tpu_val) == set(cpu_val)
+                and all(_close(tpu_val[k], cpu_val[k], 1e-9) for k in cpu_val))
+    if name == "q67win":
+        return tpu_val == cpu_val
+    if name == "q72shfl":
+        return (tpu_val[0] == cpu_val[0] and _close(tpu_val[1], cpu_val[1])
+                and tpu_val[2] == cpu_val[2])
+    return False
+
+
+def write_lineitem_parquet(t, path: str) -> None:
+    """`t` as a REAL parquet file: snappy, data-page v1, 1M-row groups.
+    Dictionary only where cardinality warrants it: pyarrow switches a
+    chunk's remaining pages to PLAIN when the dict overflows, and
+    mixed-encoding chunks host-fall-back per column (supported matrix)
+    — high-entropy columns are written PLAIN outright."""
+    import pyarrow.parquet as pq
+    pq.write_table(t, path, row_group_size=1 << 20,
+                   use_dictionary=["l_shipdate", "l_quantity",
+                                   "l_returnflag", "l_linestatus"],
+                   compression="snappy", data_page_version="1.0")
+
+
 class SectionFailed(Exception):
     """A check of this section did not hold."""
 
@@ -90,7 +367,7 @@ class Smoke:
         self.report: dict = {"ok": False, "device": None}
         self.failures: list = []
         self.sess = None      # the default-conf session
-        self.tpu = None       # bench.tpu_queries over the cached frames
+        self.tpu = None       # tpu_queries over the cached frames
         self.ref = {}         # query name -> host reference answer
         self.answers = {}     # query name -> one-chip engine answer
 
@@ -134,7 +411,6 @@ class Smoke:
         flushed) — the comparison tolerances rest on it."""
         import jax
         import jax.numpy as jnp
-        import numpy as np
         import spark_rapids_tpu  # noqa: F401 - turns jax's x64 mode on
         x = np.array([1e300, 1e-310, 1 / 3, np.pi, np.e, 2 / 7])
         d = jnp.asarray(x)
@@ -173,7 +449,6 @@ class Smoke:
         """`fn` once cold then WARM_REPS warm, checked against `ref` and
         against _hidden each time; warm reps must not compile. Returns
         (record, answer)."""
-        import bench
         from spark_rapids_tpu.runtime import compile_cache as CC
         from spark_rapids_tpu.runtime import obs
         rec: dict = {"ok": False}
@@ -196,13 +471,13 @@ class Smoke:
             t0 = time.perf_counter()
             wval = fn()
             warm.append(time.perf_counter() - t0)
-            if not bench.validate(validate_as or name, wval, val):
+            if not validate(validate_as or name, wval, val):
                 problems.append(f"warm answer differs: {wval} vs {val}")
         n_warm = CC.stats()["xla_compiles"] - c1["xla_compiles"]
         if n_warm:
             problems.append(f"{n_warm} XLA compile(s) in warm repetitions")
         problems += self._hidden(sess, fb0)
-        if not bench.validate(validate_as or name, val, ref):
+        if not validate(validate_as or name, val, ref):
             problems.append(f"MISMATCH engine={val} reference={ref}")
         rec["warm_median_s"] = statistics.median(warm)
         rec["n"] = len(warm)
@@ -219,22 +494,21 @@ class Smoke:
 
     # -- load --------------------------------------------------------------
     def load(self) -> None:
-        import bench
         from spark_rapids_tpu.sql.session import TpuSession
-        bench.set_scale(self.args.rows)
+        set_scale(self.args.rows)
         self.report["rows"] = {
-            "lineitem": bench.ROWS, "orders": bench.ORDERS,
-            "window_slice": bench.WIN_ROWS, "shuffle": bench.SHFL_ROWS,
-            "shuffle_partitions": bench.SHUFFLE_PARTS,
-            "cut_from_30M": round(1 - bench.ROWS / 30_000_000, 4)}
+            "lineitem": ROWS, "orders": ORDERS,
+            "window_slice": WIN_ROWS, "shuffle": ROWS,
+            "shuffle_partitions": SHUFFLE_PARTS,
+            "cut_from_30M": round(1 - ROWS / FULL_ROWS, 4)}
         t0 = time.perf_counter()
-        self.tables = bench.make_tables(self.args.seed)
-        self.cpu = bench.cpu_queries(*self.tables)
+        self.tables = make_tables(self.args.seed)
+        self.cpu = cpu_queries(*self.tables)
         gen_s = time.perf_counter() - t0
         self.sess = TpuSession()  # default conf
         t0 = time.perf_counter()
-        self.frames = bench.cache_tables(self.sess, *self.tables)
-        self.tpu = bench.tpu_queries(self.frames)
+        self.frames = cache_tables(self.sess, *self.tables)
+        self.tpu = tpu_queries(self.frames)
         self.report["load"] = {"ok": True, "generate_s": gen_s,
                                "upload_and_cache_s":
                                    time.perf_counter() - t0}
@@ -254,7 +528,6 @@ class Smoke:
 
     # -- scan from disk ----------------------------------------------------
     def scan(self) -> None:
-        import bench
         from spark_rapids_tpu.sql.session import TpuSession
         out = self.report["scan"] = {}
         shutil.rmtree(DATA_DIR, ignore_errors=True)
@@ -262,13 +535,13 @@ class Smoke:
         path = os.path.join(DATA_DIR, "lineitem.parquet")
         try:
             t0 = time.perf_counter()
-            bench.write_lineitem_parquet(self.tables[0], path)
+            write_lineitem_parquet(self.tables[0], path)
             out["write_s"] = time.perf_counter() - t0
             out["file_bytes"] = os.path.getsize(path)
             sess = TpuSession()  # default conf: device decode on
-            for name, cols in (("q6", bench.Q6_COLUMNS), ("q1", None)):
+            for name, cols in (("q6", Q6_COLUMNS), ("q1", None)):
                 df = sess.read_parquet(path, columns=cols)
-                fn = bench.tpu_queries({"lineitem": df})[name]
+                fn = tpu_queries({"lineitem": df})[name]
                 rec, _ = self._run_query(sess, f"scan_{name}", fn,
                                          self._reference(name),
                                          validate_as=name)
@@ -295,7 +568,6 @@ class Smoke:
 
     # -- served path -------------------------------------------------------
     def served(self) -> None:
-        import bench
         from spark_rapids_tpu.runtime import obs, serving
         from spark_rapids_tpu.runtime.serving.server import deserialize_table
         from spark_rapids_tpu.sql.session import TpuSession
@@ -359,16 +631,15 @@ class Smoke:
                     mine.append(f"HTTP {status}: "
                                 f"{json.dumps(doc)[:300]}")
                     continue
-                got = bench.shape_answer(name, deserialize_table(
+                got = shape_answer(name, deserialize_table(
                     base64.b64decode(doc["result"])).to_pydict())
                 want = self.answers.get(name)
                 if want is None:  # resident section not run
                     want = self.answers[name] = self.tpu[name]()
                 # rows equal to the in-process answers, and the
                 # in-process answer matches the host reference
-                if not bench.validate(name, got, want) or \
-                        not bench.validate(name, got,
-                                           self._reference(name)):
+                if not validate(name, got, want) or \
+                        not validate(name, got, self._reference(name)):
                     mine.append(f"MISMATCH served={got} in-process={want}")
             if any(rec["xla_compiles"][1:]):
                 mine.append(f"compiles in repeated requests: "
@@ -390,7 +661,6 @@ class Smoke:
         its lax twin — independent of the engine's eligibility gates."""
         import jax
         import jax.numpy as jnp
-        import numpy as np
         from spark_rapids_tpu.ops import kernels as K
         from spark_rapids_tpu.ops import pallas_decode as PD
         from spark_rapids_tpu.ops import pallas_kernels as PK
@@ -458,7 +728,6 @@ class Smoke:
 
     # -- four chips --------------------------------------------------------
     def multichip(self):
-        import bench
         import jax
         from spark_rapids_tpu.expr.core import col, lit
         from spark_rapids_tpu.runtime import obs
@@ -495,7 +764,7 @@ class Smoke:
                            "spark.rapids.sql.multichip.devices": "4"})
         # the SAME cached 4-partition relation, bound to this session
         sharded = DataFrame(self.frames["sharded"].plan, sess)
-        q72 = bench.tpu_queries({"sharded": sharded})["q72shfl"]
+        q72 = tpu_queries({"sharded": sharded})["q72shfl"]
         rec, got = self._run_query(sess, "multichip_q72shfl", q72,
                                    self._reference("q72shfl"),
                                    validate_as="q72shfl")
@@ -588,7 +857,7 @@ class Smoke:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=42)
-    ap.add_argument("--rows", type=int, default=30_000_000,
+    ap.add_argument("--rows", type=int, default=FULL_ROWS,
                     help="lineitem rows (orders is a tenth); cut only "
                          "when a time limit forces it")
     ap.add_argument("--allow-cpu", action="store_true",

@@ -1,9 +1,9 @@
 """Pallas sorted-window segmented reduction — the groupby hot path.
 
 Reference parity: SURVEY §7.3.1's "hard" kernel list (the cudf hash-agg
-shard). Measured on v5e (tools/profile_pallas_segsum.py): end-to-end
-sort + kernel = 317 ms vs 607 ms for the 3-scatter XLA bucket path at
-16.7M rows -> 4M groups, bit-exact sums.
+shard). Read on a v5e in an early round (not re-measured on today's
+chip): end-to-end sort + kernel = 317 ms vs 607 ms for the 3-scatter
+XLA bucket path at 16.7M rows -> 4M groups, bit-exact sums.
 
 Design: after a single co-sort by the packed key, dense group ids are
 MONOTONE, so a 1024-row tile touches a contiguous id span <= 1024 wide.

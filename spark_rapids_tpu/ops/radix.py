@@ -4,15 +4,14 @@ The round-3 performance backbone (reference parity: the cudf hash/radix
 groupby + sort kernel library, SURVEY.md §2.9.1/§7.3.1 — re-designed for
 what this TPU actually measures, not translated):
 
-Found on a v5e in round 3 (tools/profile_prims2.py re-runs the probes;
-none re-measured on today's installation): a single-plane argsort is
-fast and compiles in seconds, while the general multi-operand u64
-``lax.sort`` takes MINUTES to compile, and 64-bit scatter reductions
-(``segment_sum`` on f64/i64) and 64-bit ``searchsorted`` are an order of
-magnitude slower than their i32 forms (the device emulates 64-bit
-lanes).  The fast primitives are: single-key sorts, 32-bit scatters, and
-(exact, integer) cumsums — so the groupby backbone is built from exactly
-those:
+Found on a v5e in round 3 (none re-measured on today's installation):
+a single-plane argsort is fast and compiles in seconds, while the
+general multi-operand u64 ``lax.sort`` takes MINUTES to compile, and
+64-bit scatter reductions (``segment_sum`` on f64/i64) and 64-bit
+``searchsorted`` are an order of magnitude slower than their i32 forms
+(the device emulates 64-bit lanes).  The fast primitives are:
+single-key sorts, 32-bit scatters, and (exact, integer) cumsums — so
+the groupby backbone is built from exactly those:
 
 1. **Pack** all group keys into ONE int64 plane by runtime range
    compression: per key, ``code = value - min`` occupies

@@ -7,7 +7,7 @@ Reference parity: one Spark stage in the reference is scan → project/filter
 final segmented aggregation, with no host round-trip in the middle.
 
 These builders are the flagship "model" of the framework: what the graft
-entry dry-runs multi-chip and what bench.py times on hardware.
+entry dry-runs multi-chip.
 """
 from __future__ import annotations
 

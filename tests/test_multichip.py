@@ -5,8 +5,7 @@ failure paths (trace-failure fallback, retry-on-OOM, cancellation).
 
 The suite conftest forces 8 virtual CPU devices for every test process,
 so these drive the REAL shard_map / all_to_all path in-process. The
-heavier end-to-end gates live in tools/multichip_smoke.py (ci_check) and
-tools/bench_multichip.py (MULTICHIP_r06.json).
+heavier end-to-end gates live in tools/multichip_smoke.py (ci_check).
 """
 import numpy as np
 import pytest

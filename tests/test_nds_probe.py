@@ -24,7 +24,7 @@ def dfs():
 
 
 # The 98-query sweep is the suite's single heaviest parametrization (~7-8min
-# on the CPU sim). Tier-1 keeps the bench/probe anchors q1/q3/q6/q67/q72;
+# on the CPU sim). Tier-1 keeps the probe anchors q1/q3/q6/q67/q72;
 # the every-7th spread joined them until the round-18 headroom squeeze and
 # now rides tools/slow_rehomed.txt (ci_check runs it), with the full sweep
 # under @slow and audit_smoke's golden cost-signature replay in ci_check

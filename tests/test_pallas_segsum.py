@@ -1,6 +1,7 @@
 """Pallas sorted-window segmented-reduction tests (interpret mode on the
-CPU sim — the same kernel code that runs on hardware; measured 1.9x over
-the scatter path on v5e, tools/profile_pallas_segsum.py)."""
+CPU sim — the same kernel code that runs on hardware; an early round
+read 1.9x over the scatter path on a v5e, not re-measured on today's
+chip)."""
 import numpy as np
 import pyarrow as pa
 import pytest

@@ -201,13 +201,11 @@ def test_report_fusion_wins_absorbed_agg_stage(tmp_path):
     # A Filter→Project chain absorbed into a partial aggregate's update
     # kernel dispatches via the agg (no FusedStageExec span); the report
     # must still show the stage from its absorbed stageDispatch instants.
-    # Driven through bench_fusion's partial_agg_stage harness — the
+    # Driven through stage_harness's partial-aggregate stage — the
     # simple SQL-level shape folds entirely at plan time (CollapseProject
     # + pre_filter) and never forms a pre_chain.
-    import bench_fusion as BF
-    bt = BF._table(40_000)
-    batches = BF._device_batches(bt, 2048)
-    drive, _res = BF.make_partial_agg_stage(bt, True, 1, 2048, batches)
+    import stage_harness as SH
+    drive = SH.make_partial_agg_stage(40_000, 2048, fused=True)
     tr = trace.start_query(C.RapidsConf({
         "spark.rapids.sql.trace.enabled": "true",
         "spark.rapids.sql.trace.path": str(tmp_path)}))

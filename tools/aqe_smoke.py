@@ -2,8 +2,8 @@
 
 Three gates, CI-blocking (tools/ci_check.sh):
 
-1. CORRECTNESS — the q3join- and q72shfl-shaped probes (the two bench
-   losses the kernel audit attributed to dispatch_overhead) produce
+1. CORRECTNESS — the q3join- and q72shfl-shaped probes (the two shapes
+   the kernel audit attributed to dispatch_overhead) produce
    byte-identical results with adaptive execution on and off
    (canonically sorted: conversion legitimately reorders rows across
    partitions, it must never change them).

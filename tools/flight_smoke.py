@@ -6,8 +6,8 @@ The retroactive-observability CI gate (tools/ci_check.sh):
 1. **Overhead** (trace_overhead.py methodology — naive A/B wall-clock
    comparison is an order of magnitude noisier than the quantity under
    test on shared CI): count how often each instrumentation entry point
-   fires during one drive of the fused-bench chain, measure each entry
-   point's per-call cost WITH THE RECORDER ON minus its pre-flight
+   fires during one drive of stage_harness's unfused chain, measure each
+   entry point's per-call cost WITH THE RECORDER ON minus its pre-flight
    equivalent (the bare GpuMetric timer / nothing) over 10^5 tight-loop
    iterations, and gate sum(count_i x delta_i) < 2% of the drive's
    best-of wall time.
@@ -53,7 +53,7 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
-import bench_fusion as BF  # noqa: E402
+import stage_harness as SH  # noqa: E402
 
 _ENTRY_POINTS = ("exec_span", "metric_span", "span", "instant")
 
@@ -170,11 +170,9 @@ def main() -> int:
     # -- 1. overhead: recorder ON, tracing OFF ------------------------------
     flight_dir = tempfile.mkdtemp(prefix="flight_smoke_")
     flight.install(capacity=2048, out_dir=flight_dir, min_interval_s=0.0)
-    t = BF._table(args.rows)
-    batches = BF._device_batches(t, args.batch)
     # UNFUSED chain: per-batch exec_span traffic (the fused stage's hot
     # loop has no per-batch entry-point calls and would measure zero)
-    drive, _res = BF.make_chain_stage(t, False, 1, args.batch, batches)
+    drive = SH.make_chain_stage(args.rows, args.batch, fused=False)
     drive()  # warm every kernel cache before measuring
     drive_s = []
     for _ in range(args.reps):

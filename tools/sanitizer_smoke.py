@@ -3,7 +3,7 @@ and sharp when enabled.
 
 Gate 1 (overhead, the tracing bar): the total cost the DISABLED lock
 proxies add to one drive of the unfused Filter→Project chain
-(tools/bench_fusion.py's dispatch-bound shape — every batch acquires the
+(tools/stage_harness.py's dispatch-bound shape — every batch acquires the
 TPU semaphore, so the drive generates real sanitized-lock traffic) must
 be under --tolerance (2%) of the drive's wall time. Same method as
 tools/trace_overhead.py, for the same reason (run-to-run noise on shared
@@ -43,7 +43,7 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
-import bench_fusion as BF  # noqa: E402
+import stage_harness as SH  # noqa: E402
 
 
 def _count_lock_ops(san, drive):
@@ -122,9 +122,7 @@ def main() -> int:
 
     san.uninstall()  # the overhead half measures the DISABLED path
 
-    t = BF._table(args.rows)
-    batches = BF._device_batches(t, args.batch)
-    drive, _res = BF.make_chain_stage(t, False, 1, args.batch, batches)
+    drive = SH.make_chain_stage(args.rows, args.batch, fused=False)
     drive()  # warm kernel caches before measuring
 
     drive_s = []

@@ -1,7 +1,7 @@
 """Trace-overhead smoke: tracing must be FREE when disabled.
 
 Gate: the total cost the DISABLED instrumentation adds to one drive of
-the fused Filter→Project stage (tools/bench_fusion.py's dispatch-bound
+the fused Filter→Project stage (tools/stage_harness.py's dispatch-bound
 small shape) must be under --tolerance (2%) of the drive's wall time.
 
 Method — the naive way (time the drive with instrumentation vs with it
@@ -264,15 +264,13 @@ def main() -> int:
         print(json.dumps(query_report(args.rows)))
         return 0
 
-    import bench_fusion as BF
+    import stage_harness as SH
     from spark_rapids_tpu.runtime import trace
 
-    t = BF._table(args.rows)
-    batches = BF._device_batches(t, args.batch)
     # UNFUSED chain: FilterExec/ProjectExec drive exec_span per batch, so
     # the gate counts real instrumentation traffic (the fused stage's hot
     # loop has no per-batch entry-point calls and would measure zero)
-    drive, _res = BF.make_chain_stage(t, False, 1, args.batch, batches)
+    drive = SH.make_chain_stage(args.rows, args.batch, fused=False)
     drive()  # warm every kernel cache before measuring
 
     # drive wall time: best-of (the only robust end-to-end statistic)
