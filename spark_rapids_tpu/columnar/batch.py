@@ -160,6 +160,15 @@ def materialize_counts(batches: Sequence["ColumnarBatch"]) -> None:
         lz._val = int(v)
 
 
+def carry_host_stats(src_cols, dst_cols) -> None:
+    """Carry the host-side column stats (`bounds`, `str_width`: metadata,
+    not pytree leaves) from columns to their 1:1 row subsets or
+    permutations across a jit or device_put boundary."""
+    for src, dst in zip(src_cols, dst_cols):
+        dst.bounds = src.bounds
+        dst.str_width = src.str_width
+
+
 def _pad_to(arr: np.ndarray, capacity: int, fill=0) -> np.ndarray:
     if arr.shape[0] == capacity:
         return arr
@@ -198,6 +207,14 @@ class ColumnVector:
     #: pytree: consumed only host-side (radix packing skips its device
     #: range probe). Conservative bounds stay valid under any row subset.
     bounds: "Optional[Tuple[int, int]]" = None
+    #: string columns only: optional host-side bound, in bytes, on the
+    #: longest string (dict columns: the longest vocabulary entry),
+    #: stamped where the host builds the planes (column_from_arrow, a
+    #: unified concat). NOT part of the pytree, carried like `bounds`
+    #: (carry_host_stats): the keyed sort reads its static key width
+    #: from it instead of a device read-back (ops/kernels
+    #: .static_string_chunks). Stays valid under any row subset.
+    str_width: Optional[int] = None
 
     @property
     def capacity(self) -> int:
@@ -314,6 +331,11 @@ def _fixed_width_view(arr, np_dtype) -> np.ndarray:
     return out if out.dtype == np_dtype else out.astype(np_dtype)
 
 
+def max_entry_len(offsets_np: np.ndarray) -> int:
+    """Longest entry, in bytes, of a host offsets plane (`str_width`)."""
+    return int(np.diff(offsets_np).max(initial=0))
+
+
 def _pad_offsets(offsets_np: np.ndarray, n: int, capacity: int) -> np.ndarray:
     out = np.full(capacity + 1, offsets_np[n] if n < len(offsets_np)
                   else offsets_np[-1], dtype=np.int32)
@@ -328,6 +350,7 @@ def column_from_arrow(arr, dtype: T.DataType, capacity: int) -> ColumnVector:
 
     n = len(arr)
     valid_np = _np_valid_from_arrow(arr)
+    str_width = None
 
     if isinstance(dtype, T.ArrayType):
         arr = _normalize_null_slices(arr, pa.list_(T.to_arrow(dtype.element)))
@@ -397,7 +420,8 @@ def column_from_arrow(arr, dtype: T.DataType, capacity: int) -> ColumnVector:
                 validity = None
             else:
                 validity = jnp.asarray(_pad_to(valid_np.astype(np.bool_), capacity, fill=False))
-            return ColumnVector(dtype, data, validity)
+            return ColumnVector(dtype, data, validity,
+                                str_width=max_entry_len(voff))
         arr = arr.cast(pa.large_string()) if not pa.types.is_large_string(arr.type) else arr
         # fill nulls with "" so offsets stay monotone and bytes well-defined
         filled = pc.fill_null(arr, "")
@@ -417,6 +441,7 @@ def column_from_arrow(arr, dtype: T.DataType, capacity: int) -> ColumnVector:
             "offsets": jnp.asarray(off_padded),
             "bytes": jnp.asarray(_pad_to(bytes_np, byte_cap)),
         }
+        str_width = max_entry_len(offsets_np)
     elif isinstance(dtype, T.BooleanType):
         np_arr = np.asarray(pc.fill_null(arr, False), dtype=np.bool_)
         data = jnp.asarray(_pad_to(np_arr, capacity))
@@ -450,7 +475,7 @@ def column_from_arrow(arr, dtype: T.DataType, capacity: int) -> ColumnVector:
         validity = None
     else:
         validity = jnp.asarray(_pad_to(valid_np.astype(np.bool_), capacity, fill=False))
-    return ColumnVector(dtype, data, validity)
+    return ColumnVector(dtype, data, validity, str_width=str_width)
 
 
 def from_arrow(table, device=None) -> ColumnarBatch:
@@ -460,8 +485,9 @@ def from_arrow(table, device=None) -> ColumnarBatch:
     if device is not None:
         with jax.default_device(device):
             batch = from_arrow(table)
-        return ColumnarBatch(jax.device_put(batch.columns, device),
-                             batch.num_rows)
+        cols = jax.device_put(batch.columns, device)
+        carry_host_stats(batch.columns, cols)
+        return ColumnarBatch(cols, batch.num_rows)
     table = table.combine_chunks()
     n = table.num_rows
     cap = round_capacity(n)

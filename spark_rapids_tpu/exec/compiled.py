@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import (
-    ColumnVector, ColumnarBatch, host_int,
+    ColumnVector, ColumnarBatch, carry_host_stats, host_int,
 )
 from spark_rapids_tpu.expr.core import EvalCtx, Expression, SparkException
 from spark_rapids_tpu.runtime import compile_cache as _cc
@@ -114,13 +114,14 @@ def run_stage(exprs: Sequence[Expression], batch: ColumnarBatch,
 
 
 def carry_bounds(exprs, in_cols, out_cols) -> None:
-    """Carry column-stat bounds (host metadata, not pytree leaves) across
-    a jit boundary for passthrough column references."""
+    """Carry the host-side column stats (`bounds`, `str_width`: metadata,
+    not pytree leaves) across a jit boundary for passthrough column
+    references."""
     from spark_rapids_tpu.expr.core import Alias, BoundRef
     for e, o in zip(exprs, out_cols):
         inner = e.children[0] if isinstance(e, Alias) else e
         if isinstance(inner, BoundRef) and inner.index < len(in_cols):
-            o.bounds = in_cols[inner.index].bounds
+            carry_host_stats([in_cols[inner.index]], [o])
 
 
 def raise_errors(err: Dict[str, jax.Array]) -> None:

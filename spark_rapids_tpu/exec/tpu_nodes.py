@@ -28,8 +28,8 @@ from jax import lax
 from spark_rapids_tpu import config as C
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import (
-    ColumnVector, ColumnarBatch, LazyRowCount, from_arrow, to_arrow,
-    round_capacity, rows_int, traced_rows,
+    ColumnVector, ColumnarBatch, LazyRowCount, carry_host_stats, from_arrow,
+    to_arrow, round_capacity, rows_int, traced_rows,
 )
 from spark_rapids_tpu.exec import compiled
 from spark_rapids_tpu.exec import cpu_backend as CPU
@@ -651,8 +651,7 @@ class CachedScanExec(TpuExec):
                 self.metrics.metric(M.MESH_PUT_BYTES).add(
                     b.device_memory_size())
                 cols = jax.device_put(b.columns, home[0])
-                for src, dst in zip(b.columns, cols):
-                    dst.bounds = src.bounds
+                carry_host_stats(b.columns, cols)
                 b = ColumnarBatch(cols, b.num_rows, b.row_mask)
             yield b
 
@@ -986,8 +985,7 @@ class FilterExec(TpuExec):
             compiled.raise_errors(errs)
             # column-stat bounds are host metadata (not pytree leaves):
             # a filter's output columns are 1:1 row subsets of its input
-            for ic, oc in zip(batch.columns, out.columns):
-                oc.bounds = ic.bounds
+            carry_host_stats(batch.columns, out.columns)
             out_rows.add(out.num_rows)
             yield out
 
@@ -1242,14 +1240,56 @@ def _order_keys(kc: ColumnVector, o, num_rows, live=None, n_chunks=None):
     return [(k, nulls, o.ascending, o.resolved_nulls_first())]
 
 
-def _sort_perm_for(orders, batch):
-    key_cols = compiled.run_stage([o.expr for o in orders], batch)
-    keys = []
-    for o, kc in zip(orders, key_cols):
-        keys.extend(_order_keys(kc, o, batch.num_rows,
-                                live=batch.live_mask()))
-    return K.lexsort_indices(keys, traced_rows(batch.num_rows),
-                             live=batch.live_mask())
+#: a masked batch above this capacity is compacted (one count read-back)
+#: before the keyed sort: the program's output keeps its input's
+#: capacity, and what follows (the result's download first of all,
+#: session.fetch's own rule) moves full planes
+_SORT_COMPACT_ABOVE = 16384
+
+
+def _shrunk_for_sort(batch: ColumnarBatch) -> ColumnarBatch:
+    if batch.row_mask is not None and batch.capacity > _SORT_COMPACT_ABOVE:
+        return K.compact_batch(batch)
+    return batch
+
+
+def _sort_in_core(orders, batch: ColumnarBatch) -> ColumnarBatch:
+    """ORDER BY over one batch as ONE keyed program and no host sync: the
+    order expressions, normalisation (_order_keys), the stable lexsort
+    and the gather of every column trace into `fuse.fused(("sort", ...))`.
+    Dead rows of a masked batch sort to the end, so the output is
+    compacted and carries the input's (possibly lazy) row count. A string
+    key's width is static in the trace and part of the key: it is settled
+    before the program by K.string_chunk_count, which reads a device
+    value only where the host does not know the width."""
+    widths = []
+    for o in orders:
+        if not isinstance(o.expr.data_type(), T.StringType):
+            widths.append(None)
+            continue
+        # a computed string key is evaluated once for its width
+        kc = (batch.columns[o.expr.index] if isinstance(o.expr, BoundRef)
+              else compiled.run_stage([o.expr], batch)[0])
+        widths.append(K.string_chunk_count(kc))
+    fp = tuple((o.expr.fingerprint(), o.ascending, o.resolved_nulls_first())
+               for o in orders)
+
+    def build():
+        def fn(b):
+            live = b.live_mask()
+            n = traced_rows(b.num_rows)
+            ectx = EvalCtx(b.columns, n, b.capacity, False, live=live)
+            keys = []
+            for o, w in zip(orders, widths):
+                keys.extend(_order_keys(o.expr.eval_tpu(ectx), o, n,
+                                        live=live, n_chunks=w))
+            perm = K.lexsort_indices(keys, n, live=live)
+            return K.gather_batch(b, perm, b.num_rows).columns
+        return fn
+
+    cols = fuse.fused(("sort", fp, tuple(widths)), build)(batch)
+    carry_host_stats(batch.columns, cols)
+    return ColumnarBatch(cols, batch.num_rows)
 
 
 def _topn_image(kc: ColumnVector, order, live) -> Optional[jax.Array]:
@@ -1372,18 +1412,25 @@ class TopNExec(TpuExec):
                     return
             # fallback: exact full sort (string keys, tiny inputs, or a
             # pathologically wide tie set)
-            if batch.row_mask is not None:
-                batch = K.compact_batch(batch)
-            total = int(batch.num_rows)
-            perm = _sort_perm_for(self.orders, batch)
-            out = K.gather_batch(batch, perm, batch.num_rows)
-            yield K.slice_batch(out, 0, min(n, total))
+            out = _sort_in_core(self.orders, _shrunk_for_sort(batch))
+            yield K.slice_batch(out, 0, min(n, int(out.num_rows)))
 
 
 class SortExec(TpuExec):
-    """Whole-partition sort: evaluate sort-key expressions as a fused stage,
-    normalize, single lexsort, gather (reference GpuSortExec in-core path;
-    the out-of-core merge path arrives with the spill framework)."""
+    """Whole-partition sort (reference GpuSortExec). In core (input up
+    to sort.outOfCoreBytes) it is one keyed program a batch and no host
+    sync (_sort_in_core; `explain("stages")` marks it `[keyed: sort]`);
+    above that, the out-of-core path. The width of a string key comes
+    from the host (K.static_string_chunks: the width stamped at upload,
+    or a vocabulary too small to need more than one chunk); a read-back
+    remains, once a key and before the program, for a computed string
+    key or a column whose stamp an operator dropped, and the count
+    read-back of K.compact_batch for a masked batch whose capacity
+    exceeds _SORT_COMPACT_ABOVE."""
+
+    def tree_string(self, indent: int = 0) -> str:
+        head, nl, rest = super().tree_string(indent).partition("\n")
+        return f"{head} [keyed: sort]{nl}{rest}"
 
     def execute_partition(self, ctx, pidx):
         sort_t = self.metrics.metric(M.SORT_TIME)
@@ -1401,15 +1448,10 @@ class SortExec(TpuExec):
                     return
                 yield b
         batch = K.concat_batches(batches) if len(batches) > 1 else batches[0]
-        if batch.row_mask is not None:
-            batch = K.compact_batch(batch)
+        batch = _shrunk_for_sort(batch)
         with self.span(sort_t):
-            perm = self._sort_perm(batch)
-            out = K.gather_batch(batch, perm, batch.num_rows)
+            out = _sort_in_core(self.plan.orders, batch)
         yield out
-
-    def _sort_perm(self, batch):
-        return _sort_perm_for(self.plan.orders, batch)
 
     def _out_of_core(self, batches):
         """Out-of-core sort (reference GpuSortExec.scala:281 merge path,
@@ -3469,8 +3511,7 @@ class ExchangeExec(TpuExec):
                 RP.compact_slices(sorted_b, offsets, self.n_out)):
             if sub is None:
                 continue
-            for ic, oc in zip(batch.columns, sub.columns):
-                oc.bounds = ic.bounds
+            carry_host_stats(batch.columns, sub.columns)
             rows_m.add(int(sub.num_rows))
             out[p].append(sub)
             if self._emit_sink is not None:
@@ -3485,8 +3526,7 @@ class ExchangeExec(TpuExec):
         disp.add(self.n_out)
         fetch.add(self.n_out)
         for p, sub in enumerate(subs):
-            for ic, oc in zip(batch.columns, sub.columns):
-                oc.bounds = ic.bounds
+            carry_host_stats(batch.columns, sub.columns)
             rows_m.add(sub.num_rows)
             out[p].append(sub)
             if self._emit_sink is not None:
@@ -3599,8 +3639,7 @@ class ExchangeExec(TpuExec):
             while start < n:
                 ln = min(step, n - start)
                 sub = RP.slice_rows(b, start, ln)
-                for ic, oc in zip(b.columns, sub.columns):
-                    oc.bounds = ic.bounds
+                carry_host_stats(b.columns, sub.columns)
                 sub.coalesced = getattr(b, "coalesced", False)
                 nsplits += 1
                 yield sub
@@ -4690,8 +4729,7 @@ class _HashJoinBase(TpuExec):
         fn = fuse.fused(key, build_fn)
         out = fn(probe, build, table.slot_idx, table.bmin)
         # probe planes pass through: carry their column-stat bounds
-        for ic, oc in zip(probe.columns, out.columns):
-            oc.bounds = ic.bounds
+        carry_host_stats(probe.columns, out.columns)
         return out
 
     def _probe_one(self, probe, build, build_keys, matched_build):

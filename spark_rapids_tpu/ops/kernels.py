@@ -24,7 +24,7 @@ from jax import lax
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import (
     ColumnVector, ColumnarBatch, LazyRowCount, host_int, materialize_counts,
-    round_capacity, traced_rows,
+    max_entry_len, round_capacity, traced_rows,
 )
 from spark_rapids_tpu.runtime import compile_cache as _cc
 
@@ -265,7 +265,7 @@ def normalize_key(col: ColumnVector, num_rows: int,
     """Returns (key_u64, null_flags). Key order matches value order for all
     fixed-width types. Strings get a 64-bit double-hash of the bytes:
     equality-faithful up to astronomically-unlikely collisions, NOT
-    order-faithful (string ORDER BY uses the host sort path)."""
+    order-faithful (string ORDER BY uses string_chunk_keys)."""
     d = col.dtype
     if live is not None:
         valid = live if col.validity is None else (col.validity & live)
@@ -302,13 +302,47 @@ def normalize_key(col: ColumnVector, num_rows: int,
     return key, ~valid
 
 
+def _chunks_for(nbytes: int) -> int:
+    """8-byte chunks covering `nbytes`, rounded up to a power of two to
+    bound kernel variants."""
+    return round_capacity(max(1, -(-nbytes // 8)), minimum=1)
+
+
+def static_string_chunks(col: ColumnVector) -> Optional[int]:
+    """The chunk count of a string key from what the HOST already knows,
+    or None. First the width stamped on the column where the host built
+    its planes (ColumnVector.str_width: exact at upload, conservative
+    after); then the static shape of a dictionary's byte plane, which
+    bounds its longest entry, taken only where it cannot be loose: a
+    vocabulary of at most 8 bytes in all needs the one chunk every
+    string key has. A wider width than the longest string only adds
+    all-zero planes that tie: the order is the same."""
+    if col.str_width is not None:
+        return _chunks_for(col.str_width)
+    if col.is_dict and int(col.data["dict_bytes"].shape[0]) <= 8:
+        return 1
+    return None
+
+
+@_cc.jit
+def _max_entry_len(off: jax.Array) -> jax.Array:
+    return jnp.max(off[1:] - off[:-1])
+
+
 def string_chunk_count(col: ColumnVector) -> int:
     """Number of 8-byte chunks covering the longest string in the column
-    (HOST-side: one device scalar fetch — call at sort boundaries, never
-    inside jit). Rounded up to a power of two to bound kernel variants."""
+    (the longest vocabulary entry of a dict column), rounded up to a
+    power of two. The width of a string sort key is static in a trace, so
+    it is settled on the host before the program: from
+    static_string_chunks where the host knows it (no device access), and
+    only otherwise from ONE device scalar read-back (one program, one
+    `host_int`): a computed string key, or a column whose stamp was lost
+    across an operator that does not carry it. Never call inside jit."""
+    n = static_string_chunks(col)
+    if n is not None:
+        return n
     off = col.data["dict_offsets"] if col.is_dict else col.data["offsets"]
-    mx = host_int(jnp.max(off[1:] - off[:-1]))
-    return round_capacity(max(1, -(-mx // 8)), minimum=1)
+    return _chunks_for(host_int(_max_entry_len(off)))
 
 
 def string_chunk_keys(col: ColumnVector, num_rows: int, n_chunks: int,
@@ -471,7 +505,8 @@ def gather_column(col: ColumnVector, indices: jax.Array, src_rows: int,
         data = {"codes": col.data["codes"][safe],
                 "dict_offsets": col.data["dict_offsets"],
                 "dict_bytes": col.data["dict_bytes"]}
-        return ColumnVector(col.dtype, data, valid, dict_unique=col.dict_unique)
+        return ColumnVector(col.dtype, data, valid, dict_unique=col.dict_unique,
+                            str_width=col.str_width)
     if isinstance(col.dtype, T.StructType):
         kids = [gather_column(ch, indices, src_rows, src_live=src_live)
                 for ch in col.data["children"]]
@@ -522,7 +557,8 @@ def flat_string_as_dict(col: ColumnVector) -> ColumnVector:
     data = {"codes": jnp.arange(cap, dtype=jnp.int32),
             "dict_offsets": col.data["offsets"],
             "dict_bytes": col.data["bytes"]}
-    return ColumnVector(col.dtype, data, col.validity, dict_unique=False)
+    return ColumnVector(col.dtype, data, col.validity, dict_unique=False,
+                        str_width=col.str_width)
 
 
 class LazyGatheredCols:
@@ -681,7 +717,7 @@ def flatten_dict_column(col: ColumnVector, num_rows) -> ColumnVector:
     src = jnp.clip(starts[row] + (b - new_off[row]), 0, int(vraw.shape[0]) - 1)
     out_bytes = jnp.where(b < new_off[-1], vraw[src], 0).astype(jnp.uint8)
     return ColumnVector(col.dtype, {"offsets": new_off, "bytes": out_bytes},
-                        col.validity)
+                        col.validity, str_width=col.str_width)
 
 
 def _same_array(a, b) -> bool:
@@ -723,6 +759,13 @@ def _union_bounds(cols: List[ColumnVector]):
     if any(b is None for b in bs):
         return None
     return (min(b[0] for b in bs), max(b[1] for b in bs))
+
+
+def _union_width(cols: List[ColumnVector]) -> Optional[int]:
+    """Longest string over concat inputs; None if any input lacks the
+    stamp (host metadata: see ColumnVector.str_width)."""
+    ws = [c.str_width for c in cols]
+    return None if any(w is None for w in ws) else max(ws)
 
 
 def unify_vocabs(cols: List[ColumnVector]):
@@ -767,6 +810,7 @@ def align_dict_columns(cols: List[ColumnVector]) -> List[ColumnVector]:
     uoff, ubytes, remaps = unify_vocabs(cols)
     doff = jnp.asarray(uoff)
     dby = jnp.asarray(ubytes)
+    width = max_entry_len(uoff)
     out = []
     for c, remap in zip(cols, remaps):
         codes = jnp.asarray(remap)[jnp.clip(c.data["codes"], 0,
@@ -774,7 +818,7 @@ def align_dict_columns(cols: List[ColumnVector]) -> List[ColumnVector]:
         out.append(ColumnVector(c.dtype,
                                 {"codes": codes, "dict_offsets": doff,
                                  "dict_bytes": dby}, c.validity,
-                                dict_unique=True))
+                                dict_unique=True, str_width=width))
     return out
 
 
@@ -800,7 +844,8 @@ def _concat_columns(cols: List[ColumnVector], rows: List[int], cap: int) -> Colu
                                         "dict_offsets": cols[0].data["dict_offsets"],
                                         "dict_bytes": cols[0].data["dict_bytes"]},
                                 validity,
-                                dict_unique=all(c.dict_unique for c in cols))
+                                dict_unique=all(c.dict_unique for c in cols),
+                                str_width=_union_width(cols))
         # Distinct vocab objects: UNIFY host-side (vocabs are small; this
         # runs at eager concat boundaries only). Equal strings must map to
         # one code — duplicated vocab entries would make "unique bucket"
@@ -814,7 +859,7 @@ def _concat_columns(cols: List[ColumnVector], rows: List[int], cap: int) -> Colu
         return ColumnVector(dtype, {"codes": codes,
                                     "dict_offsets": jnp.asarray(uoff),
                                     "dict_bytes": jnp.asarray(ubytes)},
-                            validity)
+                            validity, str_width=max_entry_len(uoff))
 
     if isinstance(dtype, T.StructType):
         kids = []
@@ -872,7 +917,8 @@ def _concat_columns(cols: List[ColumnVector], rows: List[int], cap: int) -> Colu
         opad = cap + 1 - offsets.shape[0]
         if opad > 0:
             offsets = jnp.concatenate([offsets, jnp.broadcast_to(offsets[-1:], (opad,))])
-        return ColumnVector(dtype, {"offsets": offsets, "bytes": out_bytes}, validity)
+        return ColumnVector(dtype, {"offsets": offsets, "bytes": out_bytes}, validity,
+                            str_width=_union_width(cols))
 
     merged = jnp.concatenate([c.data[:r] for c, r in zip(cols, rows)])
     if cap - merged.shape[0] > 0:
