@@ -202,6 +202,13 @@ class ColumnVector:
     #: can merge 'a' and 'A') set False — bucket-by-code aggregation
     #: requires code uniqueness.
     dict_unique: bool = True
+    #: flat string columns only: True when the host that built the planes
+    #: saw every non-null string once (column_from_arrow: a column of
+    #: names or ids). A gather sees a flat column as its own dictionary
+    #: (ops/kernels.flat_string_as_dict), and its codes are then unique a
+    #: string exactly when this holds. Part of the pytree's static data;
+    #: a flat column an expression computes leaves it False.
+    flat_distinct: bool = False
     #: optional host-side (min, max) int bounds (cache-time column stats,
     #: the ParquetCachedBatchSerializer-stats analog). NOT part of the
     #: pytree: consumed only host-side (radix packing skips its device
@@ -351,6 +358,7 @@ def column_from_arrow(arr, dtype: T.DataType, capacity: int) -> ColumnVector:
     n = len(arr)
     valid_np = _np_valid_from_arrow(arr)
     str_width = None
+    flat_distinct = False
 
     if isinstance(dtype, T.ArrayType):
         arr = _normalize_null_slices(arr, pa.list_(T.to_arrow(dtype.element)))
@@ -442,6 +450,7 @@ def column_from_arrow(arr, dtype: T.DataType, capacity: int) -> ColumnVector:
             "bytes": jnp.asarray(_pad_to(bytes_np, byte_cap)),
         }
         str_width = max_entry_len(offsets_np)
+        flat_distinct = len(vocab) == n - arr.null_count
     elif isinstance(dtype, T.BooleanType):
         np_arr = np.asarray(pc.fill_null(arr, False), dtype=np.bool_)
         data = jnp.asarray(_pad_to(np_arr, capacity))
@@ -475,7 +484,8 @@ def column_from_arrow(arr, dtype: T.DataType, capacity: int) -> ColumnVector:
         validity = None
     else:
         validity = jnp.asarray(_pad_to(valid_np.astype(np.bool_), capacity, fill=False))
-    return ColumnVector(dtype, data, validity, str_width=str_width)
+    return ColumnVector(dtype, data, validity, str_width=str_width,
+                        flat_distinct=flat_distinct)
 
 
 def from_arrow(table, device=None) -> ColumnarBatch:
@@ -704,7 +714,8 @@ def _cv_flatten(c: ColumnVector):
         if "children" in c.data:  # struct: per-field child CVs
             return ((tuple(c.data["children"]), c.validity),
                     ("struct", c.dtype))
-        return (c.data["offsets"], c.data["bytes"], c.validity), ("str", c.dtype)
+        return ((c.data["offsets"], c.data["bytes"], c.validity),
+                ("str", c.dtype, c.flat_distinct))
     return (c.data, c.validity), ("fixed", c.dtype)
 
 
@@ -727,7 +738,8 @@ def _cv_unflatten(aux, children):
         return ColumnVector(dtype, {"children": list(kids)}, validity)
     if kind == "str":
         off, by, validity = children
-        return ColumnVector(dtype, {"offsets": off, "bytes": by}, validity)
+        return ColumnVector(dtype, {"offsets": off, "bytes": by}, validity,
+                            flat_distinct=aux[2])
     data, validity = children
     return ColumnVector(dtype, data, validity)
 

@@ -17,6 +17,7 @@ stages (exec/compiled.py).
 from __future__ import annotations
 
 import threading
+import time
 from typing import Iterator, List, Optional
 
 import numpy as np
@@ -46,7 +47,7 @@ from spark_rapids_tpu.runtime import faults as FLT
 from spark_rapids_tpu.runtime import lifecycle as LC
 from spark_rapids_tpu.runtime import metrics as M
 from spark_rapids_tpu.runtime import trace as TR
-from spark_rapids_tpu.runtime.obs.phases import device_wait
+from spark_rapids_tpu.runtime.obs.phases import device_mark, device_wait
 from spark_rapids_tpu.runtime.semaphore import get_semaphore
 from spark_rapids_tpu.runtime.task import TaskContext
 
@@ -1053,10 +1054,19 @@ class ExpandExec(TpuExec):
                                  len(self.plan.schema.types))
 
     def execute_partition(self, ctx, pidx):
+        exp_t = self.metrics.metric(M.EXPAND_TIME)
+        exp_rows = self.metrics.metric(M.EXPAND_ROWS)
+        projections = self._proj_exprs()
         for batch in self.children[0].execute_partition(ctx, pidx):
             self._acquire(ctx)
-            for exprs in self._proj_exprs():
-                yield compiled.run_projection(exprs, batch)
+            for exprs in projections:
+                # a lazy count is not forced for the counter's sake: it
+                # joins the metric's deferred list and counts once
+                # something else has read it
+                exp_rows.add(batch.num_rows)
+                with self.span(exp_t):
+                    out = compiled.run_projection(exprs, batch)
+                yield out
 
 
 class ShuffleFileScanExec(TpuExec):
@@ -1253,7 +1263,42 @@ def _shrunk_for_sort(batch: ColumnarBatch) -> ColumnarBatch:
     return batch
 
 
-def _sort_in_core(orders, batch: ColumnarBatch) -> ColumnarBatch:
+def _argsort_planes(planes: List[jax.Array], bits: List[int]) -> jax.Array:
+    """Stable argsort (int32 permutation) by several planes of
+    non-negative integers, the first the most significant, `bits[i]` the
+    bits plane i's values take: one pass a 32-bit digit from the least
+    significant up, every pass the SAME keyed program over one uint32
+    plane. XLA's TPU compiler takes most of a minute over a sort of
+    millions of rows, twice that when the key is 64 bits wide and longer
+    again with several key operands (PERF.md section 7), so the large
+    sorts that need more than one packed word (the rollup's keys, the
+    ranked window's partition and double order key) share this one
+    program a capacity instead of each tracing a sort of its own; a pass
+    more costs a sort of 8 M rows (22 ms) and two gathers."""
+    shifts = [(i, s) for i in reversed(range(len(planes)))
+              for s in range(0, bits[i], 32)]
+    def sort_digits(*ps):
+        return [(ps[i].astype(jnp.uint64) >> jnp.uint64(s)).astype(jnp.uint32)
+                for i, s in shifts]
+
+    def argsort(x):
+        return jnp.argsort(x, stable=True).astype(jnp.int32)
+
+    def take(x, i):
+        return x[i]
+
+    digits = fuse.fused(("sort_digits", tuple(shifts)),
+                        lambda: sort_digits)(*planes)
+    sort = fuse.fused(("argsort",), lambda: argsort)
+    gather = fuse.fused(("take",), lambda: take)
+    perm = sort(digits[0])
+    for digit in digits[1:]:
+        perm = gather(perm, sort(gather(digit, perm)))
+    return perm
+
+
+def _sort_in_core(orders, batch: ColumnarBatch,
+                  limit: Optional[int] = None) -> ColumnarBatch:
     """ORDER BY over one batch as ONE keyed program and no host sync: the
     order expressions, normalisation (_order_keys), the stable lexsort
     and the gather of every column trace into `fuse.fused(("sort", ...))`.
@@ -1261,7 +1306,9 @@ def _sort_in_core(orders, batch: ColumnarBatch) -> ColumnarBatch:
     compacted and carries the input's (possibly lazy) row count. A string
     key's width is static in the trace and part of the key: it is settled
     before the program by K.string_chunk_count, which reads a device
-    value only where the host does not know the width."""
+    value only where the host does not know the width. With `limit` (a
+    top-N's exact sort) the same program keeps the first `limit` rows, at
+    that capacity, and counts them on the device."""
     widths = []
     for o in orders:
         if not isinstance(o.expr.data_type(), T.StringType):
@@ -1284,12 +1331,19 @@ def _sort_in_core(orders, batch: ColumnarBatch) -> ColumnarBatch:
                 keys.extend(_order_keys(o.expr.eval_tpu(ectx), o, n,
                                         live=live, n_chunks=w))
             perm = K.lexsort_indices(keys, n, live=live)
-            return K.gather_batch(b, perm, b.num_rows).columns
+            if limit is None:
+                return K.gather_batch(b, perm, b.num_rows).columns, n
+            out_cap = min(round_capacity(max(limit, 1)), b.capacity)
+            kept = jnp.minimum(n, limit).astype(jnp.int32)
+            first = jnp.where(jnp.arange(out_cap, dtype=jnp.int32) < kept,
+                              perm[:out_cap], -1)
+            return K.gather_batch(b, first, b.num_rows).columns, kept
         return fn
 
-    cols = fuse.fused(("sort", fp, tuple(widths)), build)(batch)
+    cols, kept = fuse.fused(("sort", fp, tuple(widths), limit), build)(batch)
     carry_host_stats(batch.columns, cols)
-    return ColumnarBatch(cols, batch.num_rows)
+    return ColumnarBatch(cols, batch.num_rows if limit is None
+                         else LazyRowCount(kept))
 
 
 def _topn_image(kc: ColumnVector, order, live) -> Optional[jax.Array]:
@@ -1412,8 +1466,8 @@ class TopNExec(TpuExec):
                     return
             # fallback: exact full sort (string keys, tiny inputs, or a
             # pathologically wide tie set)
-            out = _sort_in_core(self.orders, _shrunk_for_sort(batch))
-            yield K.slice_batch(out, 0, min(n, int(out.num_rows)))
+            yield _sort_in_core(self.orders, _shrunk_for_sort(batch),
+                                limit=n)
 
 
 class SortExec(TpuExec):
@@ -1545,30 +1599,38 @@ def _attach_key_bounds(out_batch, spec, ranges_host) -> None:
                 out_batch.columns[i].bounds = (lo, hi)
 
 
+def _probe_key_ranges(key_cols, live, key_exprs=None):
+    """(ranges on the device, ranges on the host) of packable key columns,
+    or None where one does not pack (R.static_kinds). The integer keys'
+    (min, max) come from the expression or the column stats where the
+    host has them; else from one small device fetch."""
+    kinds = R.static_kinds(key_cols)
+    if kinds is None:
+        return None
+    if not R.needs_range_probe(kinds):
+        return (jnp.zeros(2 * len(key_cols), jnp.int64),
+                np.zeros(2 * len(key_cols), np.int64))
+    ranges_host = _static_expr_ranges(key_cols, kinds, key_exprs)
+    if ranges_host is not None:
+        return jnp.asarray(ranges_host), ranges_host
+    probe = fuse.fused(("radix_probe", tuple(kinds)),
+                       lambda: R.probe_ranges)
+    ranges = probe(key_cols, live)
+    with device_wait():
+        return ranges, np.asarray(jax.device_get(ranges))
+
+
 def _probe_pack_spec(key_cols, live, key_exprs=None):
     """Host decision: can these key columns pack into one int64 plane?
     Returns (spec, ranges_device, ranges_host) or (None, None, None).
     Costs one small device fetch when integer key ranges are involved and
     not statically derivable — from the expression or from column-stat
     bounds (shared by the aggregate, window, and sort radix paths)."""
-    kinds = R.static_kinds(key_cols)
-    if kinds is None:
+    probed = _probe_key_ranges(key_cols, live, key_exprs)
+    if probed is None:
         return None, None, None
-    if R.needs_range_probe(kinds):
-        ranges_host = _static_expr_ranges(key_cols, kinds, key_exprs)
-        if ranges_host is not None:
-            ranges = jnp.asarray(ranges_host)
-        else:
-            probe = fuse.fused(("radix_probe", tuple(kinds)),
-                               lambda: R.probe_ranges)
-            ranges = probe(key_cols, live)
-            with device_wait():
-                ranges_host = np.asarray(jax.device_get(ranges))
-    else:
-        ranges = jnp.zeros(2 * len(key_cols), jnp.int64)
-        ranges_host = np.zeros(2 * len(key_cols), np.int64)
-    spec = R.plan_packing(key_cols, ranges_host)
-    return spec, ranges, ranges_host
+    ranges, ranges_host = probed
+    return R.plan_packing(key_cols, ranges_host), ranges, ranges_host
 
 
 class _AggKernels:
@@ -2441,6 +2503,13 @@ class _AggKernels:
         return ColumnarBatch(out_cols, state.num_rows, state.row_mask)
 
 
+def _float_order(spec) -> bool:
+    """A window spec ordered by exactly one float key."""
+    return len(spec.order_specs) == 1 and isinstance(
+        spec.order_specs[0].expr.data_type(),
+        (T.Float32Type, T.Float64Type))
+
+
 class WindowExec(TpuExec):
     """Window evaluation: one sort by (partition, order) keys, then every
     window function as fused segmented scans (reference GpuWindowExec /
@@ -2477,6 +2546,17 @@ class WindowExec(TpuExec):
                     k in (R.KIND_INT, R.KIND_BOOL)
                     for k in pspec.kinds[nparts:]):
                 pspec = None  # dict codes are not value-ordered
+            if pspec is None and _float_order(spec):
+                # one float order key (a rank over a sum): its 64-bit
+                # order image is a plane of its own behind the packed
+                # partition keys, and the shared single-plane argsort
+                # sorts by the two (_argsort_planes)
+                pk, pranges, _ = _probe_pack_spec(
+                    kcols[:nparts], batch.live_mask(),
+                    list(spec.partition_exprs))
+                if pk is not None and pk.total_bits < R.MAX_PACK_BITS:
+                    yield self._wide_sorted(batch, pk, pranges, win_t)
+                    return
 
         def build_packed(pk):
             flags = [(True, True)] * nparts + \
@@ -2599,6 +2679,7 @@ class WindowExec(TpuExec):
             with self.span(win_t):
                 lay = fnA(batch, ranges)
                 out = fnB(batch, *lay)
+            carry_host_stats(batch.columns, out.columns)
             yield out
             return
         if pspec is not None:
@@ -2607,6 +2688,7 @@ class WindowExec(TpuExec):
             fn = fuse.fused(key, lambda: build_packed(pspec))
             with self.span(win_t):
                 out = fn(batch, ranges)
+            carry_host_stats(batch.columns, out.columns)
             yield out
             return
 
@@ -2661,7 +2743,96 @@ class WindowExec(TpuExec):
         fn = fuse.fused(key, build)
         with self.span(win_t):
             out = fn(batch)
+        carry_host_stats(batch.columns, out.columns)
         yield out
+
+    def _wide_sorted(self, batch, pk, pranges, win_t) -> ColumnarBatch:
+        """The window over a sort by two planes: the partition keys packed
+        (with the order key's null rank in the lowest bit), and the order
+        image of the one float order key. Two keyed programs around the
+        shared argsort; pass-through columns keep their original order."""
+        exprs = self.plan.window_exprs
+        spec = exprs[0].spec
+        nparts = len(spec.partition_exprs)
+        order = spec.order_specs[0]
+        key_exprs = list(spec.partition_exprs) + [order.expr]
+        asc, nulls_first = order.ascending, order.resolved_nulls_first()
+        pbits = pk.total_bits + 2   # the null rank's bit, the dead rows'
+
+        def build_keys():
+            def window_keys(batch, ranges):
+                nr = traced_rows(batch.num_rows)
+                cap = batch.capacity
+                ectx = EvalCtx(batch.columns, nr, cap, False)
+                kcols = [e.eval_tpu(ectx) for e in key_exprs]
+                live = jnp.arange(cap) < nr
+                part = R.pack_keys_sort(pk, kcols[:nparts], ranges, live,
+                                        [(True, True)] * nparts)
+                oc = kcols[nparts]
+                img = R._f64_order_i64(oc.data.astype(jnp.float64))
+                img = img if asc else ~img
+                valid = live if oc.validity is None else (oc.validity & live)
+                rank = valid if nulls_first else ~valid  # 0 sorts first
+                # the image's sign bit flipped: its order as an unsigned
+                # word; a row past the last sorts behind every live one
+                return (jnp.where(live, (part << jnp.int64(1))
+                                  | rank.astype(jnp.int64),
+                                  jnp.int64(1) << jnp.int64(pbits - 1)),
+                        jnp.where(valid, img.astype(jnp.uint64)
+                                  ^ (jnp.uint64(1) << jnp.uint64(63)),
+                                  jnp.uint64(0)))
+            return window_keys
+
+        def build_apply():
+            def window_apply(batch, perm, plane0, plane1):
+                from spark_rapids_tpu.ops import window as W
+                nr = traced_rows(batch.num_rows)
+                cap = batch.capacity
+                live = jnp.arange(cap) < nr
+                s0, s1 = plane0[perm], plane1[perm]
+                first = jnp.zeros(cap, jnp.bool_).at[0].set(True)
+                tail = jnp.zeros(1, jnp.bool_)
+                part = s0 >> jnp.int64(1)
+                segb = first | jnp.concatenate(
+                    [tail, part[1:] != part[:-1]])
+                peerb = segb | jnp.concatenate(
+                    [tail, (s0[1:] != s0[:-1]) | (s1[1:] != s1[:-1])])
+                seg_start, seg_end, peer_start, peer_end = \
+                    W.segment_layout(segb, peerb)
+                seg_end = jnp.minimum(
+                    seg_end, jnp.maximum(nr - 1, 0).astype(seg_end.dtype))
+                peer_end = jnp.minimum(peer_end, seg_end)
+                seg_id = jnp.cumsum(segb.astype(jnp.int32))
+                idx = jnp.arange(cap, dtype=jnp.int32)
+                sctx = EvalCtx([], nr, cap, False)
+                sctx.columns = K.LazyGatheredCols(batch.columns, perm,
+                                                  batch.num_rows)
+                out_cols = list(batch.columns)
+                for w in exprs:
+                    wc = _eval_window_fn(
+                        w, sctx, seg_start, seg_end, peer_start, peer_end,
+                        seg_id, segb, peerb, idx, live)
+                    out_cols.append(_scatter_window_output(
+                        wc, perm, cap, live, batch.num_rows))
+                return ColumnarBatch(out_cols, batch.num_rows)
+            return window_apply
+
+        kfp = (tuple(e.fingerprint() for e in key_exprs), asc, nulls_first,
+               pk.key)
+        keys = fuse.fused(("window_keys",) + kfp, build_keys)
+        apply = fuse.fused(("window_apply", tuple(w.fingerprint()
+                                                  for w in exprs), pk.key),
+                           build_apply)
+        t0 = time.perf_counter_ns()
+        with self.span(win_t):
+            with self.span(self.metrics.metric(M.WINDOW_SORT_TIME)):
+                planes = keys(batch, pranges)
+                perm = _argsort_planes(list(planes), [pbits, 64])
+            device_mark(self.metrics.metric(M.WINDOW_SORT_DEVICE_TIME),
+                        perm, t0)
+            out = apply(batch, perm, *planes)
+        carry_host_stats(batch.columns, out.columns)
+        return out
 
 
 # Module-level (state-free) window kernels: the fused builder closure is
@@ -4492,6 +4663,13 @@ class RangeExchangeExec(ExchangeExec):
 # Joins
 # ---------------------------------------------------------------------------
 
+#: a mask-through inner join cuts its probe to the build keys' span first
+#: when the probe batch has more rows of capacity than this and that span
+#: is at most one part in _JOIN_COMPACT_RATIO of the probe key's (_selective)
+_JOIN_COMPACT_ABOVE = 1 << 20
+_JOIN_COMPACT_RATIO = 4
+
+
 class _HashJoinBase(TpuExec):
     """Shared probe loop for the hash-join family (reference GpuHashJoin /
     JoinGatherer assembly). Skew handling: when the build side exceeds the
@@ -4614,8 +4792,15 @@ class _HashJoinBase(TpuExec):
             if table is not None and table.max_dup <= 1:
                 for probe in probe_iter:
                     self._acquire(ctx)
+                    t0 = time.perf_counter_ns()
                     with self.span(join_t):
+                        if how == "inner" and self._selective(probe, table):
+                            probe = self._in_key_range(probe, table)
                         out = self._probe_masked(probe, build, table)
+                    if how != "left":   # a left join hands its mask through
+                        device_mark(
+                            self.metrics.metric(M.JOIN_DEVICE_TIME),
+                            out.row_mask, t0)
                     yield out
                 return
         # sub-partitioning applies to inner/left/semi/anti; right/full track
@@ -4648,6 +4833,49 @@ class _HashJoinBase(TpuExec):
                 pi = jnp.full(un_idx.shape, -1, jnp.int32)
                 yield self._emit(dummy, build, pi, un_idx, n_un)
 
+    def _selective(self, probe, table) -> bool:
+        """Whether an inner join's build keys span so little of a large
+        probe batch's key range that the probe is cut to that span and
+        compacted first (_in_key_range), and the look-up and the gathers
+        of the build's columns run over the survivors and not at the
+        probe's capacity. Judged from what the host has, with no read-back
+        of its own: the dense table's span against the probe key's column
+        stats. A star join's filtered dimension (one year of a calendar)
+        is the case; an unfiltered dimension spans its key and the probe
+        passes through masked as before."""
+        key = self.plan.left_keys[0]
+        if probe.capacity <= _JOIN_COMPACT_ABOVE \
+                or not isinstance(key, BoundRef):
+            return False
+        bounds = probe.columns[key.index].bounds
+        if bounds is None:
+            return False
+        return table.span * _JOIN_COMPACT_RATIO <= bounds[1] - bounds[0] + 1
+
+    def _in_key_range(self, probe, table) -> ColumnarBatch:
+        """The probe rows whose key lies in the build keys' span,
+        compacted (the count's read-back sizes the output): every row an
+        inner join can match, and few others when the span is dense."""
+        key = self.plan.left_keys[0]
+
+        def build():
+            def join_key_range(probe, bmin, span):
+                live = probe.live_mask()
+                ectx = EvalCtx(probe.columns, traced_rows(probe.num_rows),
+                               probe.capacity, False, live=live)
+                k = key.eval_tpu(ectx)
+                v = k.data.astype(jnp.int64)
+                ok = (v >= bmin) & (v < bmin + span)
+                if k.validity is not None:
+                    ok = ok & k.validity
+                return K.mask_filter_batch(probe, ok)
+            return join_key_range
+
+        cut = fuse.fused(("join_key_range", key.fingerprint()), build)(
+            probe, table.bmin, jnp.int64(table.span))
+        carry_host_stats(probe.columns, cut.columns)
+        return K.compact_batch(cut)
+
     def _probe_masked(self, probe, build, table) -> ColumnarBatch:
         """Unique-build-key join without pair materialization: output is a
         masked batch sharing the probe's planes. Handles inner/left/semi/
@@ -4668,6 +4896,11 @@ class _HashJoinBase(TpuExec):
                 if lt == c.dtype and not c.is_string and not c.is_nested:
                     key_map[rk.index] = ki
 
+        # the build columns the join gathers, packed where the host knows
+        # them small (K.gather_plan): one gather a word, not two a column
+        plan = K.gather_plan([c for ci, c in enumerate(build.columns)
+                              if ci not in key_map])
+
         def build_fn():
             def fn(probe, build, slot_idx, bmin):
                 plive = probe.live_mask()
@@ -4683,6 +4916,10 @@ class _HashJoinBase(TpuExec):
                 matched = bidx >= 0
                 blive = build.live_mask() if build.row_mask is not None \
                     else None
+                gathered = iter(K.gather_columns(
+                    [c for ci, c in enumerate(build.columns)
+                     if ci not in key_map], bidx, build.num_rows,
+                    src_live=blive, plan=plan))
                 bcols = []
                 for ci, c in enumerate(build.columns):
                     ki = key_map.get(ci)
@@ -4692,8 +4929,7 @@ class _HashJoinBase(TpuExec):
                             if pk.validity is not None else matched
                         bcols.append(ColumnVector(c.dtype, pk.data, v))
                     else:
-                        bcols.append(K.gather_column(c, bidx, build.num_rows,
-                                                     src_live=blive))
+                        bcols.append(next(gathered))
                 if condition is not None:
                     cctx = EvalCtx(list(probe.columns) + bcols,
                                    traced_rows(probe.num_rows),
@@ -4725,11 +4961,15 @@ class _HashJoinBase(TpuExec):
                tuple(e.fingerprint() for e in left_keys),
                tuple(e.fingerprint() for e in right_keys),
                condition.fingerprint() if condition is not None else None,
-               tuple(sorted(key_map.items())))
+               tuple(sorted(key_map.items())), plan)
         fn = fuse.fused(key, build_fn)
         out = fn(probe, build, table.slot_idx, table.bmin)
-        # probe planes pass through: carry their column-stat bounds
+        # probe planes pass through, build columns are gathered: both
+        # keep their column stats (bounds, string widths)
         carry_host_stats(probe.columns, out.columns)
+        if how in ("inner", "left"):
+            carry_host_stats(build.columns,
+                             out.columns[len(probe.columns):])
         return out
 
     def _probe_one(self, probe, build, build_keys, matched_build):

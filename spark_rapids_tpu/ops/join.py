@@ -120,9 +120,13 @@ def prepare_dense_build(build_keys: List[ColumnVector], build_rows: int,
     span = bmax - bmin + 1
     if nbuild <= 0 or not (0 < span <= DENSE_KEY_RANGE_LIMIT):
         return None
-    starts, sorted_orig = _dense_table(bv, b_in, bcap, jnp.int64(bmin), span)
-    cnt = starts[1:] - starts[:-1]
-    max_dup = host_int(jnp.max(cnt)) if span > 0 else 0
+    starts, cnt_max = _dense_counts(bv, b_in, bcap, jnp.int64(bmin), span)
+    max_dup = host_int(cnt_max)
+    # the rows in key order: a placement when the keys are unique (the
+    # star-schema shape: no sort, which the TPU compiler takes half a
+    # minute over at a dimension's size), else the stable sort by key
+    order = _dense_unique_order if max_dup <= 1 else _dense_order
+    sorted_orig = order(bv, b_in, bcap, jnp.int64(bmin), starts)
     return DenseBuildTable(starts, sorted_orig, jnp.int64(bmin), span,
                            max_dup, bcap, build_rows)
 
@@ -225,20 +229,36 @@ def join_pairs(build_keys: List[ColumnVector], build_rows: int,
 
 
 @_cc.jit(static_argnames=("bcap", "span"))
-def _dense_table(bv, b_in, bcap, bmin, span):
-    """(starts[span+1], sorted_orig[bcap]): direct-address layout of build
-    rows grouped by key value (counting sort by key)."""
+def _dense_counts(bv, b_in, bcap, bmin, span):
+    """(starts[span+1], the most rows one key has): where each key
+    value's build rows begin in key order."""
     slot = jnp.where(b_in, (bv - bmin).astype(jnp.int32), span)
     cnt = jax.ops.segment_sum(jnp.ones(bcap, jnp.int32), slot,
                               num_segments=span + 1)[:span]
     starts = jnp.concatenate([jnp.zeros(1, jnp.int32),
                               jnp.cumsum(cnt).astype(jnp.int32)])
-    # stable counting sort: rows ordered by (key, original index)
+    return starts, jnp.max(cnt)
+
+
+@_cc.jit(static_argnames=("bcap",))
+def _dense_order(bv, b_in, bcap, bmin, starts):
+    """sorted_orig[bcap]: the build rows ordered by (key, original index),
+    a stable counting sort by key; -1 behind the last."""
     order = jnp.argsort(jnp.where(b_in, (bv - bmin),
                                   jnp.int64(1) << 62).astype(jnp.int64))
-    sorted_orig = jnp.where(jnp.arange(bcap) < jnp.sum(b_in.astype(jnp.int32)),
-                            order, -1)
-    return starts, sorted_orig
+    return jnp.where(jnp.arange(bcap) < jnp.sum(b_in.astype(jnp.int32)),
+                     order, -1)
+
+
+@_cc.jit(static_argnames=("bcap",))
+def _dense_unique_order(bv, b_in, bcap, bmin, starts):
+    """_dense_order where no key repeats: a row's place in key order is
+    where its key's rows begin."""
+    span = starts.shape[0] - 1
+    slot = jnp.where(b_in, (bv - bmin).astype(jnp.int32), span)
+    place = jnp.where(b_in, starts[jnp.clip(slot, 0, span - 1)], bcap)
+    return jnp.full(bcap + 1, -1, jnp.int32).at[place].set(
+        jnp.arange(bcap, dtype=jnp.int32), mode="drop")[:bcap]
 
 
 def _dense_int_pairs(table: DenseBuildTable, pv, p_in, pcap):

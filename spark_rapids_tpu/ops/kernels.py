@@ -13,6 +13,8 @@ scalar, then a jitted gather pass compiled per output-capacity bucket
 """
 from __future__ import annotations
 
+import dataclasses
+
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -23,9 +25,10 @@ from jax import lax
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import (
-    ColumnVector, ColumnarBatch, LazyRowCount, host_int, materialize_counts,
-    max_entry_len, round_capacity, traced_rows,
+    ColumnVector, ColumnarBatch, LazyRowCount, carry_host_stats, host_int,
+    materialize_counts, max_entry_len, round_capacity, traced_rows,
 )
+from spark_rapids_tpu.ops.pallas_decode import _cumsum
 from spark_rapids_tpu.runtime import compile_cache as _cc
 
 # ---------------------------------------------------------------------------
@@ -458,6 +461,16 @@ def _order_float_bits(bits: jax.Array, width: int) -> jax.Array:
 # Sort / argsort (reference cudf OrderByArg sort)
 # ---------------------------------------------------------------------------
 
+#: a lexsort of more operand planes than this runs as one single-key
+#: stable sort a plane, last plane first, inside one loop (one sort for the
+#: TPU compiler, which takes half a minute over a 39-operand sort of 2,048
+#: rows and under a second over the loop), as long as the stacked planes
+#: stay under _LEXSORT_LOOP_BYTES: a wide ORDER BY over the few rows a
+#: top-N or a ranked window leaves
+_LEXSORT_LOOP_PLANES = 8
+_LEXSORT_LOOP_BYTES = 1 << 28
+
+
 def lexsort_indices(keys: List[Tuple[jax.Array, jax.Array, bool, bool]],
                     num_rows: int, live=None) -> jax.Array:
     """Stable lexicographic argsort. keys = [(key_u64, null_flags, ascending,
@@ -474,7 +487,15 @@ def lexsort_indices(keys: List[Tuple[jax.Array, jax.Array, bool, bool]],
         operands.append(jnp.where(nulls, null_rank, val_rank))
         operands.append(key if asc else ~key)
     iota = jnp.arange(cap, dtype=jnp.int32)
-    out = lax.sort(tuple(operands) + (iota,), num_keys=len(operands), is_stable=True)
+    n = len(operands)
+    if n > _LEXSORT_LOOP_PLANES and n * cap * 8 <= _LEXSORT_LOOP_BYTES:
+        planes = jnp.stack([o.astype(jnp.uint64) for o in operands])
+
+        def by_plane(i, perm):
+            return perm[jnp.argsort(planes[n - 1 - i][perm],
+                                    stable=True).astype(jnp.int32)]
+        return lax.fori_loop(0, n, by_plane, iota)
+    out = lax.sort(tuple(operands) + (iota,), num_keys=n, is_stable=True)
     return out[-1]
 
 
@@ -498,8 +519,14 @@ def gather_column(col: ColumnVector, indices: jax.Array, src_rows: int,
         # Flat strings gather as an identity-coded dictionary (zero-copy
         # reinterpretation: vocab = the source planes themselves). A
         # byte-plane gather cannot duplicate rows without growing past the
-        # static byte capacity — code gather sidesteps that entirely.
+        # static byte capacity — code gather sidesteps that entirely. The
+        # code of row i is i: the gathered codes are the indices.
         col = flat_string_as_dict(col)
+        data = {"codes": safe.astype(jnp.int32),
+                "dict_offsets": col.data["dict_offsets"],
+                "dict_bytes": col.data["dict_bytes"]}
+        return ColumnVector(col.dtype, data, valid, dict_unique=col.dict_unique,
+                            str_width=col.str_width)
     if col.is_dict:
         # dict strings gather as integer codes; the vocab is shared.
         data = {"codes": col.data["codes"][safe],
@@ -548,7 +575,10 @@ def _gather_list_like(col: ColumnVector, safe: jax.Array, valid: jax.Array
 def flat_string_as_dict(col: ColumnVector) -> ColumnVector:
     """Reinterpret a flat offsets+bytes string column as a dictionary
     column with identity codes. Zero-copy: the vocab IS the source planes.
-    dict_unique=False (source rows may repeat values). The vocab keeps the
+    Source rows may repeat values, so dict_unique is False unless the host
+    that uploaded the column saw its strings distinct (flat_distinct: the
+    entries a null row points at repeat "", and no valid row's code is
+    one of them). The vocab keeps the
     full source byte plane alive regardless of how few codes survive
     downstream — acceptable: gather outputs share source lifetime anyway."""
     if col.is_dict or not col.is_string:
@@ -557,8 +587,37 @@ def flat_string_as_dict(col: ColumnVector) -> ColumnVector:
     data = {"codes": jnp.arange(cap, dtype=jnp.int32),
             "dict_offsets": col.data["offsets"],
             "dict_bytes": col.data["bytes"]}
-    return ColumnVector(col.dtype, data, col.validity, dict_unique=False,
+    return ColumnVector(col.dtype, data, col.validity,
+                        dict_unique=col.flat_distinct,
                         str_width=col.str_width)
+
+
+def canonical_dict_codes(col: ColumnVector) -> ColumnVector:
+    """A dict-string column whose codes are equal exactly where its
+    strings are: every code becomes the smallest code of an entry with the
+    same bytes. A vocabulary that came from an upload is unique already
+    (`dict_unique`) and the column comes back as it is; one that is a flat
+    column seen as its own dictionary (flat_string_as_dict, what a gather
+    leaves) repeats an entry wherever the strings repeat. Entries are
+    compared by normalize_key's 64-bit double hash, as every grouping of
+    strings in the engine is. The vocabulary is untouched."""
+    if not col.is_dict or col.dict_unique:
+        return col
+    off, raw = col.data["dict_offsets"], col.data["dict_bytes"]
+    h = (murmur3_bytes(off, raw, jnp.uint32(0x12345671)).astype(jnp.uint64)
+         << jnp.uint64(32)) | murmur3_bytes(
+        off, raw, jnp.uint32(0x89ABCDE3)).astype(jnp.uint64)
+    n = h.shape[0]
+    order = jnp.argsort(h, stable=True).astype(jnp.int32)
+    hs = h[order]
+    idx = jnp.arange(n, dtype=jnp.int32)
+    first = jnp.concatenate([jnp.ones(1, jnp.bool_), hs[1:] != hs[:-1]])
+    smallest = order[lax.cummax(jnp.where(first, idx, 0))]
+    canon = jnp.zeros(n, jnp.int32).at[order].set(smallest)
+    codes = canon[jnp.clip(col.data["codes"], 0, n - 1)]
+    return ColumnVector(col.dtype, {"codes": codes, "dict_offsets": off,
+                                    "dict_bytes": raw}, col.validity,
+                        dict_unique=False, str_width=col.str_width)
 
 
 class LazyGatheredCols:
@@ -589,11 +648,122 @@ class LazyGatheredCols:
         return (self[i] for i in range(len(self._cols)))
 
 
-def gather_batch(batch: ColumnarBatch, indices: jax.Array, out_rows: int) -> ColumnarBatch:
+#: an integer column rides in a packed word when its values' span takes
+#: at most this many bits
+_PACKED_FIELD_BITS = 40
+
+
+def gather_plan(cols: Sequence[ColumnVector]) -> tuple:
+    """What a gather of these columns can pack, from what the HOST knows
+    of them (a plan is static in a trace and part of its key): per column
+    (lo, bits) where its values are small non-negative codes once `lo` is
+    taken off (dictionary codes, booleans, integers and dates with column
+    stats), else None. A random gather costs the chip 9 to 16 ms a
+    million rows a plane, data and validity alike and more from a large
+    source, whatever the plane's width up to 8 bytes: gather_columns packs
+    the small columns and every column's validity bit into a few words
+    and gathers those."""
+    plan = []
+    for c in cols:
+        if c.is_dict:
+            plan.append((0, max(int(c.dict_size) - 1, 1).bit_length()))
+        elif c.is_string or c.is_nested:
+            plan.append(None)   # a flat string's codes are the indices
+        elif isinstance(c.dtype, T.BooleanType):
+            plan.append((0, 1))
+        elif c.bounds is not None and np.dtype(c.dtype.np_dtype).kind == "i" \
+                and (int(c.bounds[1]) - int(c.bounds[0])).bit_length() \
+                <= _PACKED_FIELD_BITS:
+            plan.append((int(c.bounds[0]), max(
+                (int(c.bounds[1]) - int(c.bounds[0])).bit_length(), 1)))
+        else:
+            plan.append(None)
+    return tuple(plan)
+
+
+def gather_columns(cols: Sequence[ColumnVector], indices: jax.Array,
+                   src_rows, src_live=None, plan: Optional[tuple] = None
+                   ) -> List[ColumnVector]:
+    """gather_column of every column, the same rows of each. With a
+    `plan` (gather_plan) the columns it names and the validity of all of
+    them travel in packed words: one gather a word, and one more a column
+    the plan could not pack."""
+    if plan is None or not cols:
+        return [gather_column(c, indices, src_rows, src_live=src_live)
+                for c in cols]
+    cap = cols[0].capacity
+    oob = indices < 0
+    safe = jnp.clip(indices, 0, cap - 1)
+    # fields: a column's code (if planned) then its validity bit, laid into
+    # words of at most 64 bits, first fit
+    words: List[List] = []   # [(column, shift, bits, is_validity)]
+    used: List[int] = []
+    for i, (c, p) in enumerate(zip(cols, plan)):
+        if c.is_nested:
+            continue
+        need = (p[1] if p is not None else 0) + 1
+        w = next((k for k, u in enumerate(used) if u + need <= 64), None)
+        if w is None:
+            words.append([])
+            used.append(0)
+            w = len(words) - 1
+        if p is not None:
+            words[w].append((i, used[w], p[1], False))
+        words[w].append((i, used[w] + need - 1, 1, True))
+        used[w] += need
+    got = {}
+    for fields, total in zip(words, used):
+        wide = total > 32
+        dt = jnp.uint64 if wide else jnp.uint32
+        word = jnp.zeros(cap, dt)
+        for i, shift, bits, is_valid in fields:
+            c = cols[i]
+            if is_valid:
+                if src_live is not None:
+                    v = src_live if c.validity is None \
+                        else (c.validity & src_live)
+                else:
+                    v = c.validity_or_default(src_rows)
+                code = v.astype(dt)
+            else:
+                raw = c.data["codes"] if c.is_dict else c.data
+                code = (raw.astype(jnp.int64) - plan[i][0]).astype(dt) \
+                    & dt((1 << bits) - 1)
+            word = word | (code << dt(shift))
+        word = word[safe]
+        for i, shift, bits, is_valid in fields:
+            code = (word >> dt(shift)) & dt((1 << bits) - 1)
+            got[(i, is_valid)] = code
+    out = []
+    for i, (c, p) in enumerate(zip(cols, plan)):
+        if c.is_nested:
+            out.append(gather_column(c, indices, src_rows, src_live=src_live))
+            continue
+        valid = got[(i, True)].astype(jnp.bool_) & ~oob
+        if p is None:
+            plain = gather_column(c, indices, src_rows, src_live=src_live)
+            out.append(dataclasses.replace(plain, validity=valid))
+            continue
+        code = got[(i, False)]
+        if c.is_dict:
+            data = {"codes": code.astype(jnp.int32),
+                    "dict_offsets": c.data["dict_offsets"],
+                    "dict_bytes": c.data["dict_bytes"]}
+            out.append(ColumnVector(c.dtype, data, valid,
+                                    dict_unique=c.dict_unique,
+                                    str_width=c.str_width))
+        else:
+            data = (code.astype(jnp.int64) + p[0]).astype(c.data.dtype)
+            out.append(ColumnVector(c.dtype, data, valid, bounds=c.bounds))
+    return out
+
+
+def gather_batch(batch: ColumnarBatch, indices: jax.Array, out_rows: int,
+                 plan: Optional[tuple] = None) -> ColumnarBatch:
     live = batch.live_mask() if batch.row_mask is not None else None
-    cols = [gather_column(c, indices, batch.num_rows, src_live=live)
-            for c in batch.columns]
-    return ColumnarBatch(cols, out_rows)
+    return ColumnarBatch(gather_columns(batch.columns, indices,
+                                        batch.num_rows, src_live=live,
+                                        plan=plan), out_rows)
 
 
 # ---------------------------------------------------------------------------
@@ -608,9 +778,12 @@ def _count_true(mask: jax.Array, num_rows) -> jax.Array:
 
 @_cc.jit(static_argnums=(2,))
 def _compact_indices(mask: jax.Array, num_rows, out_cap: int) -> jax.Array:
+    """The positions of the set entries among the first `num_rows` of
+    `mask`, in order, -1 behind them. The prefix sum is the two-level one:
+    the TPU compiler takes a minute over a flat one of millions of rows."""
     cap = mask.shape[0]
     mask = mask & (jnp.arange(cap) < num_rows)
-    pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
+    pos = _cumsum(mask.astype(jnp.int32)) - 1
     scatter_to = jnp.where(mask, pos, out_cap)  # non-selected drop
     out = jnp.full(out_cap + 1, -1, jnp.int32)
     out = out.at[scatter_to].set(jnp.arange(cap, dtype=jnp.int32), mode="drop")
@@ -641,17 +814,26 @@ def mask_filter_batch(batch: ColumnarBatch, pred_mask: jax.Array) -> ColumnarBat
     return ColumnarBatch(batch.columns, LazyRowCount(count), live)
 
 
+@_cc.jit(static_argnums=(1, 2))
+def _compact_gather(batch: ColumnarBatch, out_cap: int, plan: tuple):
+    idx = _compact_indices(batch.row_mask, batch.capacity, out_cap)
+    return gather_batch(batch, idx, batch.num_rows, plan=plan).columns
+
+
 def compact_batch(batch: ColumnarBatch) -> ColumnarBatch:
     """Gather live rows to the front and drop the selection mask (for
     consumers that need contiguous rows: sort output, host hand-off,
-    not-yet-mask-aware operators). Costs one count sync + one gather."""
+    not-yet-mask-aware operators). Costs one count sync, which sizes the
+    output, and ONE program an output capacity: the live rows' positions
+    and the gather of every column trace together, the small columns and
+    the validity bits in packed words (gather_plan)."""
     if batch.row_mask is None:
         return shrink_batch(batch)
     n = int(batch.num_rows)
-    out_cap = round_capacity(n)
-    idx = _compact_indices(batch.row_mask, batch.capacity, out_cap)
-    out = gather_batch(batch, idx, n)
-    return ColumnarBatch(out.columns, n)
+    cols = _compact_gather(batch, round_capacity(n),
+                           gather_plan(batch.columns))
+    carry_host_stats(batch.columns, cols)
+    return ColumnarBatch(cols, n)
 
 
 # ---------------------------------------------------------------------------

@@ -135,13 +135,9 @@ def _round_bits(b: int) -> int:
     return max(2, -(-b // 2) * 2)
 
 
-def plan_packing(key_cols: Sequence[ColumnVector],
-                 ranges_host: Optional[np.ndarray]) -> Optional[PackSpec]:
-    """Host-side: decide the static bit layout. ranges_host is the fetched
-    probe_ranges vector (None when no KIND_INT keys)."""
-    kinds = static_kinds(key_cols)
-    if kinds is None:
-        return None
+def _key_bits(key_cols: Sequence[ColumnVector], kinds: Sequence[str],
+              ranges_host: Optional[np.ndarray]) -> List[int]:
+    """Bits each key's code takes in a packed plane (slot 0 is NULL)."""
     bits = []
     for i, (c, kind) in enumerate(zip(key_cols, kinds)):
         if kind == KIND_DICT:
@@ -156,10 +152,53 @@ def plan_packing(key_cols: Sequence[ColumnVector],
                 span = 0
         # codes occupy [0, span+1]; slot 0 is NULL
         bits.append(_round_bits(int(span + 2).bit_length()))
-    spec = PackSpec(tuple(kinds), tuple(bits))
+    return bits
+
+
+def plan_packing(key_cols: Sequence[ColumnVector],
+                 ranges_host: Optional[np.ndarray]) -> Optional[PackSpec]:
+    """Host-side: decide the static bit layout. ranges_host is the fetched
+    probe_ranges vector (None when no KIND_INT keys)."""
+    kinds = static_kinds(key_cols)
+    if kinds is None:
+        return None
+    spec = PackSpec(tuple(kinds), tuple(_key_bits(key_cols, kinds,
+                                                  ranges_host)))
     if spec.total_bits > MAX_PACK_BITS:
         return None
     return spec
+
+
+#: planes plan_packing_planes lays keys over at most (the rollup's sort
+#: pays a pass of the shared argsort a 32-bit digit of each)
+MAX_PACK_PLANES = 2
+
+
+def plan_packing_planes(key_cols: Sequence[ColumnVector],
+                        ranges_host: Optional[np.ndarray]
+                        ) -> Optional[List[Tuple[PackSpec, int, int]]]:
+    """plan_packing for keys too wide for one plane: [(spec, first key,
+    one past the last key)] a plane, the first key in the first plane, a
+    key never split over two; None when the keys do not pack or need more
+    than MAX_PACK_PLANES. Sorting by the planes in order, the first the
+    most significant, is the lexicographic order of the keys' codes."""
+    kinds = static_kinds(key_cols)
+    if kinds is None:
+        return None
+    bits = _key_bits(key_cols, kinds, ranges_host)
+    planes, first, used = [], 0, 0
+    for i, b in enumerate(bits):
+        if b > MAX_PACK_BITS:
+            return None
+        if used + b > MAX_PACK_BITS:
+            planes.append((first, i))
+            first, used = i, 0
+        used += b
+    planes.append((first, len(bits)))
+    if len(planes) > MAX_PACK_PLANES:
+        return None
+    return [(PackSpec(tuple(kinds[a:b]), tuple(bits[a:b])), a, b)
+            for a, b in planes]
 
 
 def pack_keys(spec: PackSpec, key_cols: Sequence[ColumnVector],
@@ -167,11 +206,14 @@ def pack_keys(spec: PackSpec, key_cols: Sequence[ColumnVector],
     """Traced: ONE int64 plane with the range-compressed key codes.
     mins = the probe_ranges vector (device; only KIND_INT entries used).
     Dead rows get the above-range sentinel so they sort to the tail."""
+    from spark_rapids_tpu.ops import kernels as K
     cap = live.shape[0]
     packed = jnp.zeros(cap, jnp.int64)
     for i, (c, kind, b) in enumerate(zip(key_cols, spec.kinds, spec.bits)):
         if kind == KIND_DICT:
-            code = c.data["codes"].astype(jnp.int64)
+            # codes stand for the strings: equal where the strings are (a
+            # gathered flat column's are not until made so)
+            code = K.canonical_dict_codes(c).data["codes"].astype(jnp.int64)
         elif kind == KIND_BOOL:
             code = c.data.astype(jnp.int64)
         else:
@@ -193,12 +235,13 @@ def pack_keys_sort(spec: PackSpec, key_cols: Sequence[ColumnVector],
     only for order-significant keys (dict codes are not value-ordered;
     callers place dict keys only in grouping positions with (True, True)
     where any consistent order suffices)."""
+    from spark_rapids_tpu.ops import kernels as K
     cap = live.shape[0]
     packed = jnp.zeros(cap, jnp.int64)
     for i, (c, kind, b, (asc, nf)) in enumerate(
             zip(key_cols, spec.kinds, spec.bits, flags)):
         if kind == KIND_DICT:
-            v = c.data["codes"].astype(jnp.int64)
+            v = K.canonical_dict_codes(c).data["codes"].astype(jnp.int64)
             lo = jnp.int64(0)
             hi = jnp.int64(max(int(c.dict_size) - 1, 0))
         elif kind == KIND_BOOL:
@@ -337,17 +380,18 @@ def _exponent_scale(m: jax.Array) -> jax.Array:
     return scale
 
 
-def seg_sum_f64(vals_sorted: jax.Array, valid_sorted: jax.Array,
-                lay: GroupLayout) -> jax.Array:
-    """Segmented float sum via two exact int64 limb cumsums. Finite part
-    is summed with error <= 1 ulp of the largest |value| in the batch;
-    NaN/Inf propagate with Spark semantics (counted per segment through
-    the same cumsum-diff machinery — no 64-bit scatter anywhere)."""
-    v = vals_sorted.astype(jnp.float64)
-    nan = jnp.isnan(v) & valid_sorted
-    pinf = (v == jnp.inf) & valid_sorted
-    ninf = (v == -jnp.inf) & valid_sorted
-    finite = valid_sorted & ~nan & ~pinf & ~ninf
+def f64_sum_planes(v: jax.Array, valid: jax.Array
+                   ) -> Tuple[List[jax.Array], jax.Array]:
+    """The integer planes whose per-group sums ARE a float sum: two int64
+    limbs of a fixed-point decomposition scaled to the largest |value|
+    (every addend exact, so a sum of them is the same whatever the order
+    or the grouping), and the counts of NaN / +inf (one int64 plane,
+    nan<<31 | pinf) and of -inf (int32). Returns (planes, scale)."""
+    v = v.astype(jnp.float64)
+    nan = jnp.isnan(v) & valid
+    pinf = (v == jnp.inf) & valid
+    ninf = (v == -jnp.inf) & valid
+    finite = valid & ~nan & ~pinf & ~ninf
     clean = jnp.where(finite, v, jnp.float64(0.0))
 
     m = jnp.max(jnp.abs(clean))
@@ -355,23 +399,35 @@ def seg_sum_f64(vals_sorted: jax.Array, valid_sorted: jax.Array,
     scaled = clean * scale
     hi = jnp.floor(scaled)
     lo = jnp.round((scaled - hi) * np.float64(2.0) ** 36)
-    shi = _seg_diff(jnp.cumsum(hi.astype(jnp.int64)), hi.astype(jnp.int64), lay)
-    slo = _seg_diff(jnp.cumsum(lo.astype(jnp.int64)), lo.astype(jnp.int64), lay)
+    spec = (nan.astype(jnp.int64) << jnp.int64(31)) | pinf.astype(jnp.int64)
+    return [hi.astype(jnp.int64), lo.astype(jnp.int64), spec,
+            ninf.astype(jnp.int32)], scale
+
+
+def f64_sum_finish(shi: jax.Array, slo: jax.Array, sspec: jax.Array,
+                   n_ninf: jax.Array, scale: jax.Array) -> jax.Array:
+    """Per-group sums of f64_sum_planes' planes back to the float sum,
+    NaN/Inf with Spark's semantics."""
     total = (shi.astype(jnp.float64)
              + slo.astype(jnp.float64) * np.float64(2.0) ** -36) / scale
-
-    # special counts: (nan<<31 | pinf) in one i64 cumsum, ninf in an i32
-    spec = (nan.astype(jnp.int64) << jnp.int64(31)) | pinf.astype(jnp.int64)
-    sspec = _seg_diff(jnp.cumsum(spec), spec, lay)
     n_nan = sspec >> jnp.int64(31)
     n_pinf = sspec & ((jnp.int64(1) << jnp.int64(31)) - 1)
-    ni = ninf.astype(jnp.int32)
-    n_ninf = _seg_diff(jnp.cumsum(ni), ni, lay)
     is_nan = (n_nan > 0) | ((n_pinf > 0) & (n_ninf > 0))
     out = jnp.where(n_pinf > 0, jnp.float64(np.inf), total)
     out = jnp.where(n_ninf > 0, jnp.float64(-np.inf), out)
     out = jnp.where(is_nan, jnp.float64(np.nan), out)
     return out
+
+
+def seg_sum_f64(vals_sorted: jax.Array, valid_sorted: jax.Array,
+                lay: GroupLayout) -> jax.Array:
+    """Segmented float sum via two exact int64 limb cumsums. Finite part
+    is summed with error <= 1 ulp of the largest |value| in the batch;
+    NaN/Inf propagate with Spark semantics (counted per segment through
+    the same cumsum-diff machinery — no 64-bit scatter anywhere)."""
+    planes, scale = f64_sum_planes(vals_sorted, valid_sorted)
+    return f64_sum_finish(*(_seg_diff(jnp.cumsum(x), x, lay)
+                            for x in planes), scale)
 
 
 def _scatter_red(op: str, vals: jax.Array, gid: jax.Array, cap: int

@@ -1059,6 +1059,17 @@ class SparkPlanMeta:
             # kernel (one dispatch for scan-filter-partial-agg)
             pre_filter = child.plan.condition
             child = child.children[0]
+        if isinstance(child, X.ExpandExec) and pre_filter is None \
+                and child.children[0].num_partitions == 1:
+            # GROUP BY ROLLUP: the levels are prefixes of one key list,
+            # so one sort serves them all (exec/rollup.py); a batch it
+            # cannot take runs through the pair it stands for
+            from spark_rapids_tpu.exec.rollup import (RollupAggregateExec,
+                                                      rollup_shape)
+            shape = rollup_shape(p, child.plan)
+            if shape is not None:
+                return RollupAggregateExec(p, [child.children[0]], conf,
+                                           child.plan, shape)
         if child.num_partitions == 1:
             return X.HashAggregateExec(p, [child], conf, mode="complete",
                                        pre_filter=pre_filter)

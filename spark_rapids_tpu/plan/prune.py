@@ -13,7 +13,11 @@ column of its table, and each column a scan keeps is parsed by the host
 and uploaded whether or not a later operator reads it (PERF.md, PR 29:
 Q6 names 4 of lineitem's 16 columns).
 
-Three rewrites. Two are applied bottom-up:
+A Filter over a join first hands the conjuncts that read one side alone
+to that side (`push_filters`; the SQL text's WHERE sits above its joins,
+and a dimension filtered before a star join is a smaller build table and a
+probe that can be compacted early). Then three rewrites of columns. Two
+are applied bottom-up:
 - Project(Join(l, r)):   push the used-column subset below the join
 - Project(Window(c)):    push the used-column subset below the window
   (and Aggregate(Project(c)) folds the project into the aggregate).
@@ -25,6 +29,10 @@ their result:
   and the operators between it and the Project, Aggregate or Join that
   absorbs the change are rebuilt with remapped BoundRefs. The scan node
   itself is never touched: a view's scan is shared by later queries.
+  The same walk drops the columns nobody reads from a Project and an
+  Expand whose parent's use is known, and puts a column-subset Project
+  over a join input that cannot narrow itself (a CachedRelation, another
+  join): a join gathers every build column it is handed.
 """
 from __future__ import annotations
 
@@ -193,6 +201,86 @@ def _absorb_project_into_agg(a: P.Aggregate, pr: P.Project) -> P.Aggregate:
     return na
 
 
+def _conjuncts(e) -> list:
+    if isinstance(e, E.And):
+        return _conjuncts(e.children[0]) + _conjuncts(e.children[1])
+    return [e]
+
+
+def _filter_over(child: P.PlanNode, conjs: list) -> P.PlanNode:
+    """Filter(`conjs`, child), pushed on below `child` where it is a join."""
+    if not conjs:
+        return child
+    cond = conjs[0]
+    for c in conjs[1:]:
+        cond = E.And(cond, c)
+    f = P.Filter.__new__(P.Filter)
+    f.children = [child]
+    f.condition = cond
+    return _push_filter(f)
+
+
+def _push_filter(f: P.Filter) -> P.PlanNode:
+    """Filter(Join(l, r)) -> Filter'(Join(Filter(l), Filter(r))): each
+    conjunct that reads one side alone moves to that side, where the join
+    lets it (both sides of an inner join, the left side of a left, semi
+    or anti join). The join is a NEW node: the one in hand may be shared
+    with a sibling plan that has no such filter."""
+    j = f.children[0]
+    if not isinstance(j, P.Join) or \
+            j.how not in ("inner", "left", "left_semi", "left_anti"):
+        return f
+    nl = len(j.children[0].schema.fields)
+    to_l, to_r, stay = [], [], []
+    for c in _conjuncts(f.condition):
+        used: Set[int] = set()
+        _refs(c, used)
+        if not used or _contains_context(c):
+            stay.append(c)
+        elif all(i < nl for i in used):
+            to_l.append(c)
+        elif j.how == "inner" and all(i >= nl for i in used):
+            to_r.append(_remap(c, {i: i - nl for i in used}))
+        else:
+            stay.append(c)
+    if not to_l and not to_r:
+        return f
+    nj = P.Join.__new__(P.Join)
+    nj.children = [_filter_over(j.children[0], to_l),
+                   _filter_over(j.children[1], to_r)]
+    nj.left_keys, nj.right_keys = j.left_keys, j.right_keys
+    nj.how, nj.condition_raw, nj.condition = j.how, j.condition_raw, \
+        j.condition
+    if not stay:
+        return nj
+    f.children = [nj]  # `f` is new or the caller's own copy
+    f.condition = stay[0]
+    for c in stay[1:]:
+        f.condition = E.And(f.condition, c)
+    return f
+
+
+def _contains_context(e) -> bool:
+    """Expressions whose value depends on where they run (partition
+    context, rand, UDF tiers) stay where the query put them."""
+    from spark_rapids_tpu.plan.overrides import _contains_project_only
+    if _contains_project_only(e):
+        return True
+    return type(e).__name__ in ("Rand", "PythonRowUDF", "JaxColumnarUDF") \
+        or any(_contains_context(c) for c in e.children)
+
+
+def push_filters(p: P.PlanNode) -> P.PlanNode:
+    """Bottom-up over the plan; children are replaced in place as the
+    other rewrites do, a Filter that moves is rebuilt."""
+    p.children = [push_filters(c) for c in p.children]
+    if isinstance(p, P.Filter) and isinstance(p.children[0], P.Join):
+        f = P.Filter.__new__(P.Filter)
+        f.children, f.condition = list(p.children), p.condition
+        return _push_filter(f)
+    return p
+
+
 def _refs_of(exprs) -> Set[int]:
     out: Set[int] = set()
     for e in exprs:
@@ -269,7 +357,6 @@ def _rebuild_limit(p: P.Limit, child, m) -> P.Limit:
 #: expressions: (type, the child columns the node itself reads, whether
 #: the child's other columns pass through to the node's output, rebuild)
 _UNARY = (
-    (P.Project, lambda p: _refs_of(p.exprs), False, _rebuild_project),
     (P.Aggregate, lambda p: _refs_of(p.group_exprs)
      | _refs_of(a.fn for a in p.aggs), False, _rebuild_aggregate),
     (P.Filter, lambda p: _refs_of([p.condition]), True, _rebuild_filter),
@@ -300,6 +387,8 @@ def _narrow_scans(p: P.PlanNode, req: Optional[Set[int]]):
         return p, None
     if isinstance(p, P.Join):
         return _narrow_join(p, req)
+    if isinstance(p, (P.Project, P.Expand)):
+        return _narrow_projection(p, req)
     rule = next((r for r in _UNARY if isinstance(p, r[0])), None)
     if rule is None:
         _set_children(p, [_narrow_scans(c, None)[0] for c in p.children])
@@ -315,6 +404,39 @@ def _narrow_scans(p: P.PlanNode, req: Optional[Set[int]]):
     return rebuild(p, child, m), m if through else None
 
 
+def _narrow_projection(p, req: Optional[Set[int]]):
+    """A Project or an Expand under a parent that reads `req` of its
+    columns: the others are dropped (their expressions are deterministic
+    and context-free, or all stay), and the child is asked for what the
+    kept expressions read."""
+    lists = p.projections if isinstance(p, P.Expand) else [p.exprs]
+    n = len(lists[0])
+    keep = list(range(n))
+    if req is not None and len(req) < n and not any(
+            _contains_context(e) for row in lists
+            for i, e in enumerate(row) if i not in req):
+        keep = sorted(req)
+    child, m = _narrow_scans(p.children[0], _refs_of(
+        row[i] for row in lists for i in keep))
+    if m is None and len(keep) == n:
+        _set_children(p, [child])
+        return p, None
+    if m is None:
+        m = {i: i for i in range(len(child.schema.fields))}
+    if isinstance(p, P.Expand):
+        q = _rebuilt(p, child)
+        q.projections = [[_remap(row[i], m) for i in keep] for row in lists]
+        q.names = [p.names[i] for i in keep]
+    else:
+        sub = P.Project.__new__(P.Project)
+        sub.raw_exprs = [p.raw_exprs[i] for i in keep]
+        sub.exprs = [p.exprs[i] for i in keep]
+        sub.names = [p.names[i] for i in keep]
+        q = _rebuild_project(sub, child, m)
+    return q, ({old: new for new, old in enumerate(keep)}
+               if len(keep) < n else None)
+
+
 def _narrow_join(j: P.Join, req: Optional[Set[int]]):
     left, right = j.children
     nl = len(left.schema.fields)
@@ -327,6 +449,15 @@ def _narrow_join(j: P.Join, req: Optional[Set[int]]):
         rreq = {i - nl for i in used if i >= nl} | _refs_of(j.right_keys)
     nleft, ml = _narrow_scans(left, lreq)
     nright, mr = _narrow_scans(right, rreq)
+    # an input that cannot narrow itself hands on every column it has: a
+    # subset Project in front of the join keeps them out of its gathers
+    if ml is None and lreq is not None and len(lreq) < nl:
+        ml = {old: new for new, old in enumerate(sorted(lreq))}
+        nleft = _subset_project(nleft, sorted(lreq))
+    if mr is None and rreq is not None \
+            and len(rreq) < len(right.schema.fields):
+        mr = {old: new for new, old in enumerate(sorted(rreq))}
+        nright = _subset_project(nright, sorted(rreq))
     if ml is None and mr is None:
         _set_children(j, [nleft, nright])
         return j, None
@@ -343,8 +474,9 @@ def _narrow_join(j: P.Join, req: Optional[Set[int]]):
 
 
 def prune_plan(p: P.PlanNode) -> P.PlanNode:
-    """The bottom-up rewrites, then the scans narrowed top-down."""
-    return _narrow_scans(_prune_bottom_up(p), None)[0]
+    """Filters below joins, the bottom-up rewrites, then the scans and
+    the join inputs narrowed top-down."""
+    return _narrow_scans(_prune_bottom_up(push_filters(p)), None)[0]
 
 
 def _prune_bottom_up(p: P.PlanNode) -> P.PlanNode:

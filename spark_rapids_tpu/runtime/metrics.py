@@ -45,7 +45,34 @@ NUM_SCAN_COLUMNS = "numScanColumns"
 #: planner's column pruning (plan/prune.py) cut from it: what the host
 #: neither parses nor uploads
 NUM_SCAN_COLUMNS_PRUNED = "numScanColumnsPruned"
+#: live rows an ExpandExec handed on: a batch's rows once a projection
+#: (grouping set), from sizes the host has. A rollup that runs as one sort
+#: (RollupAggregateExec) expands nothing and counts nothing here
+EXPAND_ROWS = "expandRows"
+#: groups an aggregate emitted, where the host has the number (the
+#: rollup's read-back; an aggregate whose output count is a host int):
+#: the query's record keeps the largest
+AGG_GROUPS = "aggGroups"
 OP_TIME = "opTime"
+#: ns ExpandExec spent issuing its projections (inside no other span)
+EXPAND_TIME = "expandTime"
+#: ns WindowExec spent issuing its sort (keys packed, the shared argsort
+#: a plane), apart from the scans that follow; inside opTime
+WINDOW_SORT_TIME = "windowSortTime"
+#: ns the DEVICE took over WindowExec's sort by two planes (the key
+#: program and the shared argsort's passes), read at the next read-back
+#: that exists (runtime/obs/phases.device_mark); windowSortTime is their
+#: enqueue
+WINDOW_SORT_DEVICE_TIME = "windowSortDeviceTime"
+#: ns the DEVICE took over a rollup's programs (pack, argsort and scan up
+#: to the read-back of the levels' counts, then the emit), with whatever
+#: unmarked program its child enqueued just before them (device_mark)
+AGG_DEVICE_TIME = "aggDeviceTime"
+#: ns the DEVICE took over a mask-through inner, semi or anti join's
+#: probe: the cut to the build keys' span and its compaction where taken,
+#: the look-up and the gathers of the build's columns (device_mark);
+#: joinTime is their enqueue
+JOIN_DEVICE_TIME = "joinDeviceTime"
 SORT_TIME = "sortTime"
 AGG_TIME = "aggTime"
 JOIN_TIME = "joinTime"

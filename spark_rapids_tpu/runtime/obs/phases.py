@@ -44,7 +44,13 @@ the epilogue the account becomes one record in the obs ring
                               (numScanColumns, numScanColumnsPruned over
                               the query's Parquet scans: columns the host
                               parsed and uploaded, and columns the
-                              planner's pruning cut from the scans)
+                              planner's pruning cut from the scans),
+                              expand_rows (expandRows: rows ExpandExec
+                              wrote once a grouping set, summed; 0
+                              where a rollup runs as one sort),
+                              agg_groups (aggGroups: groups the query's
+                              largest aggregate emitted, where the host
+                              has the number)
     mesh                      only when a sharded stage ran: devices (the
                               mesh size) and shard_rows (per stage, the
                               live rows each shard's last body put out,
@@ -64,14 +70,29 @@ sort, the radix group-by and the bounds probe read back
 query.execute's host time: the read-backs of the exchange, window and
 partitioning execs (an `np.asarray`/`device_get` inside one exec).
 Process-wide like `keyed_dispatches`.
+
+`timers_ns.*DeviceTime` (joinDeviceTime, aggDeviceTime,
+windowSortDeviceTime) are the DEVICE's time for a step whose span times
+only its enqueue (`device_mark()`): the step names one of its outputs, and
+the next `device_wait()` of the thread, before its own wait, waits for
+each named output in turn and stamps the host's clock. A mark's time runs
+from the mark read before it, or from the step's first enqueue if that is
+later, to its own: the device takes its programs in the order they were
+enqueued, so that is the time it spent on the step and on whatever
+unmarked program went before it. No wait is added: every output named
+is ready before the value the host was about to wait for anyway. Exact
+while the host is ahead of the device; a mark the device has passed when
+the host comes to read it gives its timer nothing (`device_idle_share`
+says which of the two a cell is).
 """
 from __future__ import annotations
 
+import threading
 import time
 from typing import Dict, Optional
 
 from spark_rapids_tpu.runtime.metrics import (
-    ESSENTIAL, MESH_PUT_BYTES, NUM_SCAN_COLUMNS, NUM_SCAN_COLUMNS_PRUNED,
+    AGG_GROUPS, ESSENTIAL, EXPAND_ROWS, MESH_PUT_BYTES, NUM_SCAN_COLUMNS, NUM_SCAN_COLUMNS_PRUNED,
     SHARD_WAVES, UPLOAD_BYTES, GpuMetric, walk_exec_tree,
 )
 
@@ -79,7 +100,8 @@ from spark_rapids_tpu.runtime.metrics import (
 COUNTERS = {"upload_bytes": UPLOAD_BYTES, "shard_waves": SHARD_WAVES,
             "mesh_put_bytes": MESH_PUT_BYTES,
             "scan_columns_read": NUM_SCAN_COLUMNS,
-            "scan_columns_pruned": NUM_SCAN_COLUMNS_PRUNED}
+            "scan_columns_pruned": NUM_SCAN_COLUMNS_PRUNED,
+            "expand_rows": EXPAND_ROWS}
 
 #: phase -> the span that times it
 SPANS = {"parse": "sql.parse", "admit": "query.admit",
@@ -100,6 +122,46 @@ keyed_dispatches = 0
 device_wait_ns = 0
 
 
+class _Marks(threading.local):
+    """A thread's unread device marks, and when the device reached the
+    last one read."""
+
+    def __init__(self):
+        self.pending: list = []   # (timer, array, since_ns)
+        self.reached_ns = 0
+
+
+_marks = _Marks()
+
+#: unread marks a thread keeps (each holds one array alive): a thread
+#: that never waits again forgets the oldest
+_MARKS_KEPT = 16
+
+
+def device_mark(timer: GpuMetric, array, since_ns: int) -> None:
+    """The device's time for the step that was first enqueued at
+    `since_ns` (perf_counter_ns) and whose last program computes `array`
+    goes to `timer` at this thread's next device_wait() (module
+    docstring). `array` is kept until then: name a small output, and never
+    one the program only hands through (jit forwards those, ready at
+    once)."""
+    pending = _marks.pending
+    pending.append((timer, array, since_ns))
+    del pending[:-_MARKS_KEPT]
+
+
+def _read_marks() -> None:
+    m = _marks
+    pending, m.pending = m.pending, []
+    for timer, array, since_ns in pending:
+        passed = array.is_ready()
+        array.block_until_ready()
+        now = time.perf_counter_ns()
+        if not passed:
+            timer.add(now - max(m.reached_ns, since_ns))
+        m.reached_ns = now
+
+
 class _DeviceWait:
     """One block in which the host waits for a device value."""
 
@@ -107,6 +169,8 @@ class _DeviceWait:
 
     def __enter__(self):
         self.t0 = time.perf_counter_ns()
+        if _marks.pending:
+            _read_marks()
 
     def __exit__(self, *exc):
         global device_wait_ns
@@ -181,12 +245,15 @@ class QueryPhases:
         timers: Dict[str, int] = {}
         counters = {"keyed_dispatches": keyed_dispatches - self.dispatches0}
         counters.update((c, 0) for c in COUNTERS)
+        counters["agg_groups"] = 0
         for snap in self.peek_metrics().values():
             for name, v in snap.items():
                 if name.endswith("Time"):
                     timers[name] = timers.get(name, 0) + v
             for c, name in COUNTERS.items():
                 counters[c] += snap.get(name, 0)
+            counters["agg_groups"] = max(counters.get("agg_groups", 0),
+                                         snap.get(AGG_GROUPS, 0))
         for bucket, ns in (extra or {}).items():
             timers[bucket] = timers.get(bucket, 0) + int(ns)
         timers["deviceWaitTime"] = device_wait_ns - self.wait0

@@ -673,7 +673,8 @@ def test_recent_queries_one_record_per_top_level_action():
         assert set(r["counters"]) == {"keyed_dispatches", "upload_bytes",
                                       "shard_waves", "mesh_put_bytes",
                                       "scan_columns_read",
-                                      "scan_columns_pruned"}
+                                      "scan_columns_pruned",
+                                      "expand_rows", "agg_groups"}
         assert r["counters"]["scan_columns_read"] == 0  # no Parquet scan
         assert "mesh" not in r  # no sharded stage ran
         assert r["counters"]["upload_bytes"] > 0  # the in-memory scan's
