@@ -32,6 +32,7 @@ from jax import lax
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnVector
+from spark_rapids_tpu.runtime import compile_cache as _cc
 
 
 class SparkException(Exception):
@@ -335,6 +336,19 @@ class Literal(Expression):
             return int(decimal.Decimal(v).scaleb(self.dtype.scale).to_integral_value())
         return v
 
+    def _string_rows(self, cap: int) -> ColumnVector:
+        """The string value repeated over `cap` rows, flat."""
+        from spark_rapids_tpu.columnar.batch import round_capacity
+        bs = np.frombuffer(self.value.encode("utf-8"), np.uint8)
+        blen = len(bs)
+        rep = np.tile(bs, cap) if blen else np.zeros(0, np.uint8)
+        buf = np.zeros(round_capacity(max(len(rep), 1)), np.uint8)
+        buf[: len(rep)] = rep
+        offsets = jnp.asarray((np.arange(cap + 1) * blen).astype(np.int32))
+        return ColumnVector(self.dtype, {"offsets": offsets,
+                                         "bytes": jnp.asarray(buf)},
+                            jnp.ones(cap, jnp.bool_))
+
     def eval_tpu(self, ctx: EvalCtx) -> ColumnVector:
         cap = ctx.capacity
         if self.value is None:
@@ -347,16 +361,7 @@ class Literal(Expression):
                 data = jnp.zeros(cap, np_dt)
             return ColumnVector(dt, data, jnp.zeros(cap, jnp.bool_))
         if isinstance(self.dtype, T.StringType):
-            from spark_rapids_tpu.columnar.batch import round_capacity
-            bs = np.frombuffer(self.value.encode("utf-8"), np.uint8)
-            blen = len(bs)
-            rep = np.tile(bs, cap) if blen else np.zeros(0, np.uint8)
-            buf = np.zeros(round_capacity(max(len(rep), 1)), np.uint8)
-            buf[: len(rep)] = rep
-            offsets = jnp.asarray((np.arange(cap + 1) * blen).astype(np.int32))
-            return ColumnVector(self.dtype, {"offsets": offsets,
-                                             "bytes": jnp.asarray(buf)},
-                                jnp.ones(cap, jnp.bool_))
+            return self._string_rows(cap)
         val = self._scalar()
         data = jnp.full(cap, val, self.dtype.np_dtype)
         return ColumnVector(self.dtype, data, jnp.ones(cap, jnp.bool_))
@@ -869,6 +874,31 @@ def _string_eq_tpu(l: ColumnVector, r: ColumnVector) -> jax.Array:
     return eq
 
 
+def _flat_view(c: ColumnVector) -> ColumnVector:
+    """The vocab of a dict column viewed as a small flat string column."""
+    return ColumnVector(T.STRING, {"offsets": c.data["dict_offsets"],
+                                   "bytes": c.data["dict_bytes"]}, None)
+
+
+def _is_string_value(e: Expression) -> bool:
+    return isinstance(e, Literal) and e.value is not None and \
+        isinstance(e.dtype, T.StringType)
+
+
+def _vocab_eq_literal(c: ColumnVector, literal: "Literal") -> jax.Array:
+    """Dict-encoded column == string literal: every vocab entry (repeated
+    ones too) is compared with the literal once, and each row reads its
+    entry's answer by code. O(vocab bytes) byte work plus one gather of
+    the batch, where the flatten pays capacity x vocab bytes."""
+    vocab = _flat_view(c)
+    n = vocab.capacity
+    hit = _string_eq_tpu(vocab, literal._string_rows(n))
+    codes = c.data["codes"]
+    if isinstance(codes, jax.core.Tracer):
+        _cc.note_traced("vocab_predicates_traced")
+    return hit[jnp.clip(codes, 0, n - 1)]
+
+
 class BinaryComparison(BinaryExpression):
     op_tpu: Callable = None
     op_cpu: Callable = None
@@ -881,6 +911,9 @@ class BinaryComparison(BinaryExpression):
         r = self.right.eval_tpu(ctx)
         if isinstance(l.dtype, T.StringType):
             if type(self) in (EqualTo, EqualNullSafe):
+                for c, other in ((l, self.right), (r, self.left)):
+                    if c.is_dict and c.dict_size and _is_string_value(other):
+                        return l, r, _vocab_eq_literal(c, other)
                 return l, r, _string_eq_tpu(l, r)
             raise NotImplementedError("string ordering comparison on device")
         out = T.common_type(l.dtype, r.dtype)

@@ -24,7 +24,7 @@ from jax import lax
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnVector, round_capacity
 from spark_rapids_tpu.expr.core import (
-    CpuCol, EvalCtx, Expression, SparkException, _valid_of,
+    CpuCol, EvalCtx, Expression, SparkException, _flat_view, _valid_of,
 )
 
 
@@ -38,12 +38,6 @@ def _lens(col: ColumnVector) -> jax.Array:
 
 def _starts(col: ColumnVector) -> jax.Array:
     return col.data["offsets"][:-1]
-
-
-def _flat_view(c: ColumnVector) -> ColumnVector:
-    """The vocab of a dict column viewed as a small flat string column."""
-    return ColumnVector(T.STRING, {"offsets": c.data["dict_offsets"],
-                                   "bytes": c.data["dict_bytes"]}, None)
 
 
 def _flatten(c: ColumnVector, ctx) -> ColumnVector:

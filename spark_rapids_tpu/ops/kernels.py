@@ -887,6 +887,7 @@ def flatten_dict_column(col: ColumnVector, num_rows) -> ColumnVector:
     if isinstance(new_off, jax.Array) and not isinstance(new_off, _core.Tracer):
         out_cap = round_capacity(max(int(new_off[-1]), 1))
     else:
+        _cc.note_traced("dict_flattens_traced")
         out_cap = cap * int(vraw.shape[0])
         if out_cap > (1 << 28):
             raise NotImplementedError(

@@ -85,6 +85,14 @@ _STATS = {
     "xla_compile_ns": 0,  # summed backend-compile durations
     "persistent_hits": 0,    # persistent-cache executable loads
     "persistent_misses": 0,  # compile requests the persistent layer missed
+    # facts of TRACES, bumped by the traced code itself (note_traced):
+    # once where a program is traced, never at a dispatch. In the
+    # programs this process traced: string comparisons answered on a
+    # dict column's vocabulary (expr/core._vocab_eq_literal), and dict
+    # columns flattened at the static bound capacity x vocab bytes
+    # (ops/kernels.flatten_dict_column)
+    "vocab_predicates_traced": 0,
+    "dict_flattens_traced": 0,
 }
 
 #: set while a fresh entry's first call runs on this thread: the
@@ -280,6 +288,11 @@ def reset_stats_for_tests() -> None:
         _STATS[k] = 0
 
 
+def note_traced(counter: str) -> None:
+    """Bump one of the `*_traced` counters; the traced code calls this."""
+    _STATS[counter] += 1
+
+
 def stats() -> Dict[str, int]:
     """A point-in-time copy of the compile counters (the /healthz
     compile document and the smoke gates read this)."""
@@ -451,4 +464,6 @@ def doc() -> Dict[str, object]:
         "persistent_dir": s["persistent_dir"],
         "persistent_hits": s["persistent_hits"],
         "persistent_misses": s["persistent_misses"],
+        "vocab_predicates_traced": s["vocab_predicates_traced"],
+        "dict_flattens_traced": s["dict_flattens_traced"],
     }
