@@ -161,12 +161,13 @@ def materialize_counts(batches: Sequence["ColumnarBatch"]) -> None:
 
 
 def carry_host_stats(src_cols, dst_cols) -> None:
-    """Carry the host-side column stats (`bounds`, `str_width`: metadata,
-    not pytree leaves) from columns to their 1:1 row subsets or
-    permutations across a jit or device_put boundary."""
+    """Carry the host-side column stats (`bounds`, `str_width`,
+    `str_bytes`: metadata, not pytree leaves) from columns to their 1:1
+    row subsets or permutations across a jit or device_put boundary."""
     for src, dst in zip(src_cols, dst_cols):
         dst.bounds = src.bounds
         dst.str_width = src.str_width
+        dst.str_bytes = src.str_bytes
 
 
 def _pad_to(arr: np.ndarray, capacity: int, fill=0) -> np.ndarray:
@@ -222,6 +223,11 @@ class ColumnVector:
     #: from it instead of a device read-back (ops/kernels
     #: .static_string_chunks). Stays valid under any row subset.
     str_width: Optional[int] = None
+    #: flat string columns only: the bytes of the byte plane that rows
+    #: own (offsets[num_rows]), where the host that built the planes knew
+    #: (column_from_arrow, a concat). NOT part of the pytree, carried like
+    #: `bounds`: an upper bound under a row subset, which shares the plane
+    str_bytes: Optional[int] = None
 
     @property
     def capacity(self) -> int:
@@ -350,6 +356,42 @@ def _pad_offsets(offsets_np: np.ndarray, n: int, capacity: int) -> np.ndarray:
     return out
 
 
+#: a string column longer than this is sampled before it is
+#: dictionary-encoded whole: _SAMPLE_RUNS runs of _SAMPLE_RUN_ROWS
+#: consecutive rows, evenly spread
+_SAMPLE_ABOVE = 1 << 17
+_SAMPLE_RUNS, _SAMPLE_RUN_ROWS = 16, 4096
+
+
+def _mostly_distinct(arr, n: int) -> bool:
+    """Whether a long string column is so nearly all different values that
+    its vocabulary cannot be half its rows or fewer (the layout rule in
+    column_from_arrow), judged from a sample: hashing a million distinct
+    comments to learn that they should not have been hashed cost half a
+    second a batch (PERF.md, PR 36). More than 31 of 32 sampled rows
+    distinct means flat; anything less and the column is encoded whole and
+    the rule applied to its true vocabulary, as for every shorter column.
+    Values drawn evenly from a vocabulary of half the rows would show the
+    sample some 94% distinct, skewed ones fewer.
+
+    NOT the old rule's outcome in every case: a column whose repeats all
+    lie further apart than the sample sees (each value twice, half the
+    column apart) shows it no repeat and uploads flat, where its true
+    vocabulary, half its rows, took the dictionary layout. Flat is right
+    for any column, only larger there. And a column taken flat from the
+    sample is not KNOWN distinct (`flat_distinct` False: its strings were
+    not all seen), so a gather of it canonicalises its codes where a short
+    column's would not (ops/kernels.flat_string_as_dict)."""
+    if n <= _SAMPLE_ABOVE:
+        return False
+    import pyarrow as pa
+    import pyarrow.compute as pc
+    step = n // _SAMPLE_RUNS
+    sample = pa.concat_arrays([arr.slice(i * step, _SAMPLE_RUN_ROWS)
+                               for i in range(_SAMPLE_RUNS)])
+    return len(pc.unique(sample)) > len(sample) - len(sample) // 32
+
+
 def column_from_arrow(arr, dtype: T.DataType, capacity: int) -> ColumnVector:
     """Build a device ColumnVector from a pyarrow Array (one chunk)."""
     import pyarrow as pa
@@ -357,7 +399,7 @@ def column_from_arrow(arr, dtype: T.DataType, capacity: int) -> ColumnVector:
 
     n = len(arr)
     valid_np = _np_valid_from_arrow(arr)
-    str_width = None
+    str_width = str_bytes = None
     flat_distinct = False
 
     if isinstance(dtype, T.ArrayType):
@@ -402,12 +444,14 @@ def column_from_arrow(arr, dtype: T.DataType, capacity: int) -> ColumnVector:
     if isinstance(dtype, T.StringType):
         if pa.types.is_dictionary(arr.type):
             denc = arr
+        elif _mostly_distinct(arr, n):
+            denc = None     # flat, and nobody hashed the whole column
         else:
             denc = arr.dictionary_encode()
-        vocab = denc.dictionary
+        vocab = denc.dictionary if denc is not None else None
         # Dictionary layout pays off when the vocab is materially smaller
         # than the data; otherwise flat offsets+bytes (e.g. unique IDs).
-        if len(vocab) <= max(64, n // 2):
+        if vocab is not None and len(vocab) <= max(64, n // 2):
             codes = denc.indices
             if codes.null_count:
                 codes = pc.fill_null(codes, 0)
@@ -450,7 +494,10 @@ def column_from_arrow(arr, dtype: T.DataType, capacity: int) -> ColumnVector:
             "bytes": jnp.asarray(_pad_to(bytes_np, byte_cap)),
         }
         str_width = max_entry_len(offsets_np)
-        flat_distinct = len(vocab) == n - arr.null_count
+        str_bytes = byte_len
+        # a sampled column's strings were not all seen: not known distinct
+        flat_distinct = vocab is not None \
+            and len(vocab) == n - arr.null_count
     elif isinstance(dtype, T.BooleanType):
         np_arr = np.asarray(pc.fill_null(arr, False), dtype=np.bool_)
         data = jnp.asarray(_pad_to(np_arr, capacity))
@@ -485,7 +532,7 @@ def column_from_arrow(arr, dtype: T.DataType, capacity: int) -> ColumnVector:
     else:
         validity = jnp.asarray(_pad_to(valid_np.astype(np.bool_), capacity, fill=False))
     return ColumnVector(dtype, data, validity, str_width=str_width,
-                        flat_distinct=flat_distinct)
+                        flat_distinct=flat_distinct, str_bytes=str_bytes)
 
 
 def from_arrow(table, device=None) -> ColumnarBatch:

@@ -113,6 +113,11 @@ def make_fused_stage_exec():
             self.bodies = [m.stage_body() for m in members]
             self._key = ("fused_stage", tuple(b.key for b in self.bodies))
             self._failed = False
+            #: a member matches strings over a byte plane: the members run
+            #: apart from the first batch that brings a flat string column
+            #: (tpu_nodes.meets_flat_string)
+            self._matches_strings = any(X.stage_matches_strings(m)
+                                        for m in members)
 
         @property
         def schema(self):
@@ -155,6 +160,12 @@ def make_fused_stage_exec():
         def _unfused_chain(self, source):
             return rebuild_chain(self.members, source)
 
+        def _apart(self, ctx, pidx, batch, rest):
+            """`batch` and the `rest` of the input through the member
+            chain, each operator its own program."""
+            src = _ReplaySourceExec(self.children[0].schema, [batch], rest)
+            return self._unfused_chain(src).execute_partition(ctx, pidx)
+
         def _carry_bounds(self, in_batch, out_batch):
             bounds = [c.bounds for c in in_batch.columns]
             for b in self.bodies:
@@ -186,7 +197,12 @@ def make_fused_stage_exec():
             pid = jnp.int32(pidx)
             it = self.children[0].execute_partition(ctx, pidx)
             first = True
+            has_carry = any(b.has_carry for b in self.bodies)
             for batch in it:
+                if self._matches_strings and X.meets_flat_string(batch) \
+                        and (first or not has_carry):
+                    yield from self._apart(ctx, pidx, batch, it)
+                    return
                 self._acquire(ctx)
                 in_batches.add(1)
                 t0 = time.perf_counter_ns()
@@ -204,18 +220,14 @@ def make_fused_stage_exec():
                     # clean start falls back then.
                     from spark_rapids_tpu.expr.core import SparkException
                     if isinstance(ex, SparkException) or (
-                            not first
-                            and any(b.has_carry for b in self.bodies)):
+                            not first and has_carry):
                         raise
                     self._failed = True
                     OBS.note_exec_fallback("fused_stage")
                     log.warning(
                         "stage fusion trace failed for %s; falling back "
                         "to the unfused chain", self.name(), exc_info=True)
-                    src = _ReplaySourceExec(self.children[0].schema,
-                                            [batch], it)
-                    yield from self._unfused_chain(src).execute_partition(
-                        ctx, pidx)
+                    yield from self._apart(ctx, pidx, batch, it)
                     return
                 first = False
                 dt = time.perf_counter_ns() - t0

@@ -793,7 +793,82 @@ def _project_bounds_map(exprs):
     return bmap
 
 
-def project_stage_body(exprs, ansi: bool, trivial=None) -> fuse.StageBody:
+def matched_columns(exprs) -> list:
+    """The columns that a string match over a byte plane in `exprs`
+    (expr/strings: plane_match) reads directly, in order."""
+    from spark_rapids_tpu.expr.strings import plane_matches
+    return sorted({m.children[0].index for e in exprs
+                   for m in plane_matches(e)
+                   if isinstance(m.children[0], BoundRef)})
+
+
+def holds_string_match(exprs) -> bool:
+    from spark_rapids_tpu.expr.strings import plane_matches
+    return any(plane_matches(e) for e in exprs)
+
+
+def stage_matches_strings(node) -> bool:
+    """Does this Filter or Project match strings over a byte plane?"""
+    if isinstance(node, FilterExec):
+        return holds_string_match([node.plan.condition])
+    return isinstance(node, ProjectExec) \
+        and holds_string_match(node.plan.exprs)
+
+
+def meets_flat_string(batch: ColumnarBatch) -> bool:
+    """What a FUSED stage that matches strings looks for in each batch: over
+    dictionary columns the match is a look-up a row and stays in the
+    stage's one program; a flat column makes it passes over the column's
+    whole byte plane, and the stage then runs its operators apart
+    (FusedStageExec and HashAggregateExec, at run time, from what the
+    batch shows): the Filter alone takes the column's width from the host
+    (`match_widths`) and is timed (`_FilterRun`). In a batch still
+    encoded (a chain rooted at a device-decode scan) the strings are the
+    columns the host decoded, riding along (`EncodedColumn.cv`)."""
+    cols = (getattr(c, "cv", c) for c in batch.columns)
+    return any(getattr(c, "is_string", False) and not c.is_dict
+               for c in cols)
+
+
+def match_widths(matched, columns) -> tuple:
+    """((column, width), ...) of the flat string columns among `matched`
+    (`matched_columns`): the host's bound on their longest string
+    (ColumnVector.str_width), rounded up to the doubling window that
+    ops/strmatch takes (64 to 127 bytes are one program). Host stats do
+    not cross a jit boundary, so a stage body that matches strings takes
+    them as part of its key and stamps them back inside its trace
+    (`_stamp_widths`); a column without the stamp is left out and the
+    kernel falls back to the plane's own size."""
+    return tuple((i, (1 << columns[i].str_width.bit_length()) - 1)
+                 for i in matched if columns[i].is_string
+                 and not columns[i].is_dict
+                 and columns[i].str_width is not None)
+
+
+class _WidthKeyed:
+    """A lone stage's program: its body keyed by `match_widths`, built
+    again only when a batch brings other widths."""
+
+    def __init__(self, exprs, make_body):
+        self.matched = matched_columns(exprs)
+        self._make, self._widths, self._fn = make_body, None, None
+
+    def fn(self, columns):
+        widths = match_widths(self.matched, columns)
+        if self._fn is None or widths != self._widths:
+            body = self._make(widths)
+            self._fn = fuse.fused(body.key, body.builder)
+            self._widths = widths
+        return self._fn
+
+
+def _stamp_widths(batch: ColumnarBatch, str_widths) -> None:
+    for i, w in str_widths:
+        batch.columns[i].str_width = w
+
+
+def project_stage_body(exprs, ansi: bool, trivial=None,
+                       str_widths=()) -> fuse.StageBody:
     if trivial is not None:
         idx = tuple(trivial)
 
@@ -815,6 +890,7 @@ def project_stage_body(exprs, ansi: bool, trivial=None) -> fuse.StageBody:
 
     def build():
         def fn(batch, pid, row_base):
+            _stamp_widths(batch, str_widths)
             ectx = EvalCtx(batch.columns, traced_rows(batch.num_rows),
                            batch.capacity, ansi, live=batch.live_mask(),
                            partition_id=pid, row_base=row_base)
@@ -827,14 +903,15 @@ def project_stage_body(exprs, ansi: bool, trivial=None) -> fuse.StageBody:
         return fn
 
     key = ("project", tuple(e.fingerprint() for e in exprs), ansi,
-           needs_part_ctx)
+           needs_part_ctx, str_widths)
     return fuse.StageBody(key, build, bounds_map=_project_bounds_map(exprs),
                           has_carry=needs_part_ctx, name="Project")
 
 
-def filter_stage_body(cond, ansi: bool) -> fuse.StageBody:
+def filter_stage_body(cond, ansi: bool, str_widths=()) -> fuse.StageBody:
     def build():
         def fn(batch, pid, carry):
+            _stamp_widths(batch, str_widths)
             ectx = EvalCtx(batch.columns, traced_rows(batch.num_rows),
                            batch.capacity, ansi, live=batch.live_mask())
             pred = cond.eval_tpu(ectx)
@@ -850,7 +927,8 @@ def filter_stage_body(cond, ansi: bool) -> fuse.StageBody:
 
     # a filter's output columns are 1:1 row subsets of its input: bounds
     # (host metadata, valid under any row subset) pass straight through
-    return fuse.StageBody(("filter", cond.fingerprint(), ansi), build,
+    return fuse.StageBody(("filter", cond.fingerprint(), ansi, str_widths),
+                          build,
                           bounds_map=lambda bs: list(bs), name="Filter")
 
 
@@ -936,10 +1014,11 @@ class ProjectExec(TpuExec):
                 return None
         return idx
 
-    def stage_body(self) -> fuse.StageBody:
+    def stage_body(self, str_widths=()) -> fuse.StageBody:
         return project_stage_body(self.plan.exprs,
                                   self.conf.get(C.ANSI_ENABLED),
-                                  trivial=self._trivial_indices())
+                                  trivial=self._trivial_indices(),
+                                  str_widths=str_widths)
 
     def execute_partition(self, ctx, pidx):
         op_t = self.metrics.metric(M.OP_TIME)
@@ -951,12 +1030,12 @@ class ProjectExec(TpuExec):
                                     batch.num_rows, batch.row_mask)
             return
 
-        body = self.stage_body()
-        fn = fuse.fused(body.key, body.builder)
-        row_base = body.init_carry()
+        stage = _WidthKeyed(exprs, self.stage_body)
+        row_base = self.stage_body().init_carry()
         pid = jnp.int32(pidx)
         for batch in self.children[0].execute_partition(ctx, pidx):
             self._acquire(ctx)
+            fn = stage.fn(batch.columns)
             with self.span(op_t):
                 out, errs, row_base = fn(batch, pid, row_base)
             compiled.raise_errors(errs)
@@ -964,25 +1043,77 @@ class ProjectExec(TpuExec):
             yield out
 
 
+def _string_match_bytes(col: ColumnVector, num_rows) -> int:
+    """stringMatchBytes of one matched column, from sizes the host has."""
+    if col.is_dict:
+        return int(col.data["dict_bytes"].shape[0]) \
+            + 4 * (col.dict_size + 1) + 4 * col.capacity
+    rows = num_rows if isinstance(num_rows, int) else col.capacity
+    live = col.str_bytes if col.str_bytes is not None \
+        else int(col.data["bytes"].shape[0])
+    return live + 4 * (rows + 1)
+
+
+class _FilterRun:
+    """One partition's runs of a Filter's program for `owner`: a FilterExec,
+    or the HashAggregateExec that took the Filter into its update kernel
+    and runs it apart where it meets a flat column. Where the condition
+    matches a column's byte plane directly the program is timed as the
+    match: stringMatchTime its enqueue, stringMatchDeviceTime the device's
+    time for it, stringMatchBytes what it had to read."""
+
+    def __init__(self, owner, cond):
+        ansi = owner.conf.get(C.ANSI_ENABLED)
+        self.stage = _WidthKeyed(
+            [cond], lambda widths: filter_stage_body(cond, ansi, widths))
+        self.owner = owner
+        if self.stage.matched:
+            m = owner.metrics
+            self.match_t = m.metric(M.STRING_MATCH_TIME)
+            self.match_dev_t = m.metric(M.STRING_MATCH_DEVICE_TIME)
+            self.match_bytes = m.metric(M.STRING_MATCH_BYTES)
+
+    def __call__(self, batch, pid, carry):
+        fn = self.stage.fn(batch.columns)
+        matched = self.stage.matched
+        if not matched:
+            return fn(batch, pid, carry)
+        t0 = time.perf_counter_ns()
+        with self.owner.span(self.match_t):
+            out, errs, carry = fn(batch, pid, carry)
+        device_mark(self.match_dev_t, out.row_mask, t0)
+        self.match_bytes.add(sum(
+            _string_match_bytes(batch.columns[i], batch.num_rows)
+            for i in matched))
+        return out, errs, carry
+
+
 class FilterExec(TpuExec):
     """Predicate eval + compaction fused into ONE jitted computation per
-    batch; the surviving-row count stays on device (LazyRowCount)."""
+    batch; the surviving-row count stays on device (LazyRowCount). A
+    condition that matches strings over a byte plane is timed as the
+    match (`_FilterRun`)."""
 
-    def stage_body(self) -> fuse.StageBody:
+    def stage_body(self, str_widths=()) -> fuse.StageBody:
         return filter_stage_body(self.plan.condition,
-                                 self.conf.get(C.ANSI_ENABLED))
+                                 self.conf.get(C.ANSI_ENABLED), str_widths)
+
+    def tree_string(self, indent: int = 0) -> str:
+        head, nl, rest = super().tree_string(indent).partition("\n")
+        if holds_string_match([self.plan.condition]):
+            head += " [string match: own stage]"
+        return f"{head}{nl}{rest}"
 
     def execute_partition(self, ctx, pidx):
         op_t = self.metrics.metric(M.FILTER_TIME)
         out_rows = self.metrics.metric(M.NUM_OUTPUT_ROWS)
-        body = self.stage_body()
-        fn = fuse.fused(body.key, body.builder)
-        carry = body.init_carry()
+        run = _FilterRun(self, self.plan.condition)
+        carry = self.stage_body().init_carry()
         pid = jnp.int32(pidx)
         for batch in self.children[0].execute_partition(ctx, pidx):
             self._acquire(ctx)
             with self.span(op_t):
-                out, errs, carry = fn(batch, pid, carry)
+                out, errs, carry = run(batch, pid, carry)
             compiled.raise_errors(errs)
             # column-stat bounds are host metadata (not pytree leaves):
             # a filter's output columns are 1:1 row subsets of its input
@@ -3011,6 +3142,13 @@ class HashAggregateExec(TpuExec):
         # A filter condition absorbed into the update kernel (predicate
         # fusion): scan -> filter -> partial agg runs as ONE dispatch.
         self.pre_filter = pre_filter
+        #: the same kernels without the filter, for a batch in which the
+        #: filter's string match meets a flat column: the Filter then runs
+        #: as its own program before them (`meets_flat_string`)
+        self._kern_apart = _AggKernels(
+            plan.group_exprs, plan.group_names, plan.aggs, None) \
+            if pre_filter is not None and holds_string_match([pre_filter]) \
+            else None
         #: whole-stage vertical fusion (exec/stage_fusion.py): traced
         #: bodies of a narrow-operator chain composed BEFORE the update
         #: phase inside one jit — scan -> filter -> project -> partial agg
@@ -3084,18 +3222,24 @@ class HashAggregateExec(TpuExec):
         from spark_rapids_tpu.exec.stage_fusion import rebuild_chain
         return rebuild_chain(self.pre_chain_members, source)
 
+    def _chain_apart(self, ctx, pidx, batch, rest):
+        """`batch` and the `rest` of the input through the member chain,
+        each operator its own program."""
+        from spark_rapids_tpu.exec.stage_fusion import _ReplaySourceExec
+        src = _ReplaySourceExec(self.children[0].schema, [batch], rest)
+        return self._unfused_pre_chain(src).execute_partition(ctx, pidx)
+
     def tree_string(self, indent: int = 0) -> str:
+        notes = (f" [sharded n={self.shard_over}]" if self.shard_over
+                 else "") + (" [string match: apart over a flat column]"
+                             if self._kern_apart is not None else "")
         if not self.pre_chain_members:
-            line = super().tree_string(indent)
-            if not self.shard_over:
-                return line
-            head, nl, rest = line.partition("\n")
-            return f"{head} [sharded n={self.shard_over}]{nl}{rest}"
+            head, nl, rest = super().tree_string(indent).partition("\n")
+            return f"{head}{notes}{nl}{rest}"
         pad = "  " * indent
         sid = self.fused_stage_id
         lines = [f"{pad}*({sid}) {self.name()} <- {self.plan.describe()}"
-                 + (f" [sharded n={self.shard_over}]"
-                    if self.shard_over else "")]
+                 + notes]
         for m in reversed(self.pre_chain_members):
             lines.append(f"{pad}  *({sid}) {type(m).__name__} "
                          f"<- {m.plan.describe()} [fused]")
@@ -3184,6 +3328,9 @@ class HashAggregateExec(TpuExec):
             ansi = self.conf.get(C.ANSI_ENABLED)
             from spark_rapids_tpu.runtime.retry import with_retry
 
+            filter_apart = self._kern_apart and _FilterRun(
+                self, self.pre_filter)
+
             def plain_attempt(b):
                 # raise_errors inside the attempt so ANSI-mode syncs
                 # (and any device OOM they surface) are seen by the
@@ -3192,12 +3339,19 @@ class HashAggregateExec(TpuExec):
                 # point; the cooperative budget (SpillFramework.
                 # reserve) is the primary defense, this translation is
                 # best-effort.
-                out, errs = self.kern.update(b, ansi)
+                kern = self.kern
+                if filter_apart and meets_flat_string(b):
+                    kept, errs, _ = filter_apart(b, jnp.int32(pidx),
+                                                 jnp.int64(0))
+                    compiled.raise_errors(errs)
+                    carry_host_stats(b.columns, kept.columns)
+                    b, kern = kept, self._kern_apart
+                out, errs = kern.update(b, ansi)
                 compiled.raise_errors(errs)
                 return out
 
             attempt = plain_attempt
-            chain_live = False
+            chain_live = chain_matches = False
             chain_in_rows = [None]  # update-phase input rows (device)
             in_batches = self.metrics.metric(M.NUM_INPUT_BATCHES)
             if self.pre_chain and self._chain_failed:
@@ -3238,6 +3392,8 @@ class HashAggregateExec(TpuExec):
 
                 attempt = chain_attempt
                 chain_live = True
+                chain_matches = any(stage_matches_strings(m)
+                                    for m in self.pre_chain_members)
 
             if (self.conf.get(C.AGG_FORCE_SINGLE_PASS) and nkeys > 0) \
                     or self.kern.has_custom:
@@ -3260,9 +3416,18 @@ class HashAggregateExec(TpuExec):
                 if batch is None:
                     break
                 bi += 1
+                if chain_live and chain_matches and meets_flat_string(batch):
+                    # the absorbed chain matches strings and this batch
+                    # brings a flat column: the members run apart from
+                    # here on (no fault, nothing noted)
+                    chain_live, attempt = False, plain_attempt
+                    it = self._chain_apart(ctx, pidx, batch, it)
+                    bi -= 1
+                    continue
                 self._acquire(ctx)
                 in_batches.add(1)
                 n_before = len(partials)
+                t0 = time.perf_counter_ns()
                 try:
                     with self.span(agg_t):
                         # update is idempotent over its input batch:
@@ -3272,6 +3437,8 @@ class HashAggregateExec(TpuExec):
                             if nkeys == 0:
                                 out = ColumnarBatch(out.columns, 1)
                             partials.append(out)
+                    if len(partials) > n_before:
+                        self._mark_device(partials[-1], t0)
                 except Exception as ex:
                     from spark_rapids_tpu.expr.core import SparkException
                     if not chain_live or isinstance(ex, SparkException):
@@ -3296,13 +3463,7 @@ class HashAggregateExec(TpuExec):
                     _obs.note_exec_fallback("absorbed_chain")
                     chain_live = False
                     attempt = plain_attempt
-                    from spark_rapids_tpu.exec.stage_fusion import (
-                        _ReplaySourceExec,
-                    )
-                    src = _ReplaySourceExec(self.children[0].schema,
-                                            [batch], it)
-                    it = self._unfused_pre_chain(src).execute_partition(
-                        ctx, pidx)
+                    it = self._chain_apart(ctx, pidx, batch, it)
                     bi -= 1
                     continue
                 if bi == 0 and skip_ratio < 1.0 and nkeys > 0 \
@@ -3349,6 +3510,7 @@ class HashAggregateExec(TpuExec):
                     yield p
                 return
             self._acquire(ctx)
+            t0 = time.perf_counter_ns()
             with self.span(agg_t):
                 merged = self._merge(partials)
                 # no compact at yield: exchanges, downstream aggs, and the
@@ -3358,11 +3520,23 @@ class HashAggregateExec(TpuExec):
                 # sync (a host round trip)
                 if self.mode != "partial":
                     merged = self._evaluate(merged)
+            self._mark_device(merged, t0)
             out_rows.add(merged.num_rows)
             out_batches.add(1)
             yield merged
 
     # -- phase helpers -----------------------------------------------------
+
+    def _mark_device(self, out: ColumnarBatch, since_ns: int) -> None:
+        """aggDeviceTime: the device's time for the programs enqueued
+        since `since_ns` whose last one computes `out`'s last state (or
+        result) plane, read at the next read-back that exists; a plane
+        that a program only hands through is ready at once and adds
+        nothing."""
+        data = out.columns[-1].data if out.columns else None
+        if isinstance(data, jax.Array):
+            device_mark(self.metrics.metric(M.AGG_DEVICE_TIME), data,
+                        since_ns)
 
     def _merge(self, partials: List[ColumnarBatch]) -> ColumnarBatch:
         if len(partials) == 1 and not getattr(partials[0], "coalesced",
@@ -4775,8 +4949,15 @@ class _HashJoinBase(TpuExec):
 
     def _probe_stream(self, ctx, probe_iter, build, build_keys, join_t,
                       track_build_matches: bool):
-        """Yields joined batches; returns via StopIteration the build-side
-        matched mask (for right/full outer)."""
+        """Yields joined batches, counting their rows (joinOutputRows)."""
+        rows = self.metrics.metric(M.JOIN_OUTPUT_ROWS)
+        for out in self._probe_batches(ctx, probe_iter, build, build_keys,
+                                       join_t, track_build_matches):
+            rows.add(out.num_rows)
+            yield out
+
+    def _probe_batches(self, ctx, probe_iter, build, build_keys, join_t,
+                       track_build_matches: bool):
         how = self.plan.how
         matched_build = (jnp.zeros(build.capacity, jnp.bool_)
                          if track_build_matches else None)
@@ -4797,10 +4978,14 @@ class _HashJoinBase(TpuExec):
                         if how == "inner" and self._selective(probe, table):
                             probe = self._in_key_range(probe, table)
                         out = self._probe_masked(probe, build, table)
-                    if how != "left":   # a left join hands its mask through
+                    # a left join hands the probe's mask through: the
+                    # matches are its last build column's validity
+                    mark = out.row_mask if how != "left" \
+                        else out.columns[-1].validity
+                    if mark is not None:
                         device_mark(
                             self.metrics.metric(M.JOIN_DEVICE_TIME),
-                            out.row_mask, t0)
+                            mark, t0)
                     yield out
                 return
         # sub-partitioning applies to inner/left/semi/anti; right/full track
@@ -4964,6 +5149,10 @@ class _HashJoinBase(TpuExec):
                tuple(sorted(key_map.items())), plan)
         fn = fuse.fused(key, build_fn)
         out = fn(probe, build, table.slot_idx, table.bmin)
+        if how == "left":
+            # every probe row comes out once: the input's own row count
+            # object, a host int where the probe's was
+            out = ColumnarBatch(out.columns, probe.num_rows, out.row_mask)
         # probe planes pass through, build columns are gathered: both
         # keep their column stats (bounds, string widths)
         carry_host_stats(probe.columns, out.columns)

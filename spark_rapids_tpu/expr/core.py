@@ -877,7 +877,8 @@ def _string_eq_tpu(l: ColumnVector, r: ColumnVector) -> jax.Array:
 def _flat_view(c: ColumnVector) -> ColumnVector:
     """The vocab of a dict column viewed as a small flat string column."""
     return ColumnVector(T.STRING, {"offsets": c.data["dict_offsets"],
-                                   "bytes": c.data["dict_bytes"]}, None)
+                                   "bytes": c.data["dict_bytes"]}, None,
+                        str_width=c.str_width)
 
 
 def _is_string_value(e: Expression) -> bool:

@@ -13,6 +13,7 @@ discipline.
 """
 from __future__ import annotations
 
+import copy
 from typing import List
 
 import numpy as np
@@ -284,94 +285,105 @@ class ConcatStrings(Expression):
 
 
 class _LiteralMatch(Expression):
-    """startswith/endswith/contains with a literal pattern: sliding fixed
-    window compare over the byte plane."""
+    """A string against literal runs: it starts with `prefix`, ends with
+    `suffix`, and holds `middles` in order between them, nothing
+    overlapping (`LIKE 'prefix%m1%m2%suffix'`). One kernel over the byte
+    plane (ops/strmatch.match_runs) serves startswith, endswith, contains
+    and every LIKE made of literal runs; a dictionary column answers on its
+    vocabulary through the same kernel (_lift_unary)."""
 
-    mode = "starts"  # starts | ends | contains
+    #: the match runs over the bytes, not the rows: a fused stage that
+    #: holds one and meets a flat column runs its operators apart
+    #: (tpu_nodes.meets_flat_string), and the Filter is timed as the match
+    plane_match = True
 
-    def __init__(self, child, pattern: str):
+    def __init__(self, child, prefix: str = "", middles=(), suffix: str = ""):
         self.children = [child]
-        self.pattern = pattern
+        self.prefix, self.suffix = prefix, suffix
+        self.middles = tuple(middles)
 
     def data_type(self):
         return T.BOOLEAN
 
     def _params(self):
-        return repr(self.pattern)
+        return repr((self.prefix, self.middles, self.suffix))
 
     def with_children(self, children):
-        return type(self)(children[0], self.pattern)
+        new = copy.copy(self)   # keeps the subclass (its name is the
+        new.children = [children[0]]  # expression's fingerprint)
+        return new
 
     def eval_tpu(self, ctx):
         c = self.children[0].eval_tpu(ctx)
         return _lift_unary(ctx, c, self._compute)
 
     def _compute(self, flat, cap):
-        raw = flat.data["bytes"]
-        o = flat.data["offsets"]
-        lens = o[1:] - o[:-1]
-        pat = np.frombuffer(self.pattern.encode("utf-8"), np.uint8)
-        m = len(pat)
-        if m == 0:
-            return ColumnVector(T.BOOLEAN, jnp.ones(cap, jnp.bool_), None)
-        nb = raw.shape[0]
-
-        def window_eq(base):
-            eq = jnp.ones(base.shape, jnp.bool_)
-            for k in range(m):
-                idx = jnp.clip(base + k, 0, nb - 1)
-                eq = eq & (raw[idx] == pat[k])
-            return eq
-
-        fits = lens >= m
-        if self.mode == "starts":
-            res = fits & window_eq(o[:-1])
-        elif self.mode == "ends":
-            res = fits & window_eq(o[1:] - m)
-        else:  # contains: match at any byte start position
-            base = jnp.arange(nb, dtype=jnp.int32)
-            w = window_eq(base)
-            # map each byte position to its row; position must leave room
-            rowidx = jnp.searchsorted(o, base, side="right").astype(jnp.int32) - 1
-            rowidx = jnp.clip(rowidx, 0, cap - 1)
-            in_row = (base + m) <= o[rowidx + 1]
-            hit = w & in_row
-            per_row = jnp.zeros(cap, jnp.int32).at[rowidx].add(
-                hit.astype(jnp.int32), mode="drop")
-            res = fits & (per_row > 0)
+        from spark_rapids_tpu.ops.strmatch import match_runs
+        res = match_runs(flat.data["offsets"], flat.data["bytes"],
+                         flat.str_width, self.prefix.encode("utf-8"),
+                         [m.encode("utf-8") for m in self.middles],
+                         self.suffix.encode("utf-8"))
         return ColumnVector(T.BOOLEAN, res, None)
+
+    def matches(self, s: str) -> bool:
+        """The plain-Python twin (the CPU path and the tests' oracle)."""
+        if len(s) < len(self.prefix) + len(self.suffix) or \
+                not s.startswith(self.prefix) or not s.endswith(self.suffix):
+            return False
+        pos, stop = len(self.prefix), len(s) - len(self.suffix)
+        for m in self.middles:
+            i = s.find(m, pos, stop)
+            if i < 0:
+                return False
+            pos = i + len(m)
+        return True
 
     def eval_cpu(self, cols, ansi=False):
         c = self.children[0].eval_cpu(cols, ansi)
-        f = {"starts": str.startswith, "ends": str.endswith,
-             "contains": str.__contains__}[self.mode]
-        vals = np.array([bool(f(s, self.pattern)) if isinstance(s, str) else False
+        vals = np.array([self.matches(s) if isinstance(s, str) else False
                          for s in c.values], np.bool_)
         return CpuCol(T.BOOLEAN, vals, c.valid)
 
 
+def plane_matches(e) -> list:
+    """The string matches in `e` that run over a column's byte plane."""
+    own = [e] if getattr(e, "plane_match", False) else []
+    return own + [m for c in e.children for m in plane_matches(c)]
+
+
 class StartsWith(_LiteralMatch):
-    mode = "starts"
+    def __init__(self, child, pattern: str):
+        super().__init__(child, prefix=pattern)
 
 
 class EndsWith(_LiteralMatch):
-    mode = "ends"
+    def __init__(self, child, pattern: str):
+        super().__init__(child, suffix=pattern)
 
 
 class Contains(_LiteralMatch):
-    mode = "contains"
+    def __init__(self, child, pattern: str):
+        super().__init__(child, middles=(pattern,))
 
 
 class Like(Expression):
-    """SQL LIKE. Patterns reducible to starts/ends/contains/equality compile
-    to device kernels (the reference's regex-transpile-or-reject strategy,
-    RegexParser.scala); general patterns run on CPU via fnmatch-style
-    matching and mark the expression unsupported on device."""
+    """SQL LIKE. A pattern of literal runs between `%` is one pass kernel
+    over the byte plane (_LiteralMatch), one without `%` an equality; a
+    `_` goes to the device NFA (the reference's regex-transpile-or-reject
+    strategy, RegexParser.scala), and what that cannot take runs on the
+    CPU and marks the expression unsupported on device."""
 
     def __init__(self, child, pattern: str, escape: str = "\\"):
         self.children = [child]
         self.pattern = pattern
         self.escape = escape
+
+    @property
+    def plane_match(self) -> bool:
+        """Runs over the byte plane (_LiteralMatch.plane_match): not a
+        pattern without `%` (an equality) nor one of `%` alone."""
+        return self.pattern.replace("%", "") != "" and not isinstance(
+            self._transpile(), _StringEquals)
 
     def data_type(self):
         return T.BOOLEAN
@@ -423,18 +435,9 @@ class Like(Expression):
         child = self.children[0]
         if len(runs) == 1:
             return _StringEquals(child, runs[0])
-        if len(runs) == 2:
-            a, b = runs
-            if a == "" and b == "":
-                return None  # trivially true; handled below
-            if a == "":
-                return EndsWith(child, b)
-            if b == "":
-                return StartsWith(child, a)
-            return _AndExpr(StartsWith(child, a), EndsWith(child, b), min_len=len(a) + len(b))
-        if len(runs) == 3 and runs[0] == "" and runs[2] == "" and runs[1]:
-            return Contains(child, runs[1])
-        return None
+        if not any(runs):
+            return None  # only %: trivially true; handled below
+        return _LiteralMatch(child, runs[0], runs[1:-1], runs[-1])
 
     def _nfa(self):
         from spark_rapids_tpu.expr import regex as RX
@@ -487,6 +490,9 @@ class Like(Expression):
         c = self.children[0].eval_tpu(ctx)
 
         def compute(flat, cap):
+            if isinstance(flat.data["bytes"], jax.core.Tracer):
+                from spark_rapids_tpu.runtime import compile_cache as _cc
+                _cc.note_traced("like_nfa_traced")
             res = RX.nfa_eval(nfa, flat.data["offsets"], flat.data["bytes"], None)
             return ColumnVector(T.BOOLEAN, res, None)
 
@@ -528,6 +534,8 @@ class RLike(Expression):
     subset run as a bit-parallel NFA over byte planes (expr/regex.py);
     others fall back to CPU `re` — the reference's RegexParser
     transpile-or-reject contract."""
+
+    plane_match = True
 
     def __init__(self, child, pattern: str):
         self.children = [child]
@@ -791,37 +799,6 @@ class _StringEquals(Expression):
         vals = np.array([s == self.value if isinstance(s, str) else False
                          for s in c.values], np.bool_)
         return CpuCol(T.BOOLEAN, vals, c.valid)
-
-
-class _AndExpr(Expression):
-    def __init__(self, a, b, min_len=0):
-        self.children = [a, b]
-        self.min_len = min_len
-
-    def data_type(self):
-        return T.BOOLEAN
-
-    def with_children(self, children):
-        return _AndExpr(children[0], children[1], self.min_len)
-
-    def eval_tpu(self, ctx):
-        a = self.children[0].eval_tpu(ctx)
-        b = self.children[1].eval_tpu(ctx)
-        res = a.data & b.data
-        if self.min_len:
-            src = self.children[0].children[0].eval_tpu(ctx)
-            res = res & ((_lens(src)) >= self.min_len)
-        return ColumnVector(T.BOOLEAN, res, _valid_of(a, ctx) & _valid_of(b, ctx))
-
-    def eval_cpu(self, cols, ansi=False):
-        a = self.children[0].eval_cpu(cols, ansi)
-        b = self.children[1].eval_cpu(cols, ansi)
-        res = a.values & b.values
-        if self.min_len:
-            src = self.children[0].children[0].eval_cpu(cols, ansi)
-            lens = np.array([len(s) if isinstance(s, str) else 0 for s in src.values])
-            res = res & (lens >= self.min_len)
-        return CpuCol(T.BOOLEAN, res, a.valid & b.valid)
 
 
 # ---------------------------------------------------------------------------

@@ -1005,6 +1005,13 @@ def align_dict_columns(cols: List[ColumnVector]) -> List[ColumnVector]:
     return out
 
 
+@_cc.jit(donate_argnums=(0,))
+def _lay_at(plane: jax.Array, part: jax.Array, at) -> jax.Array:
+    """`part` written into `plane` from position `at`, in place (the
+    plane is donated: a 1 GiB byte plane is not copied once a part)."""
+    return jax.lax.dynamic_update_slice(plane, part, (at,))
+
+
 def _concat_columns(cols: List[ColumnVector], rows: List[int], cap: int) -> ColumnVector:
     dtype = cols[0].dtype
     if any(c.is_dict for c in cols) and not all(c.is_dict for c in cols):
@@ -1090,10 +1097,15 @@ def _concat_columns(cols: List[ColumnVector], rows: List[int], cap: int) -> Colu
             o = c.data["offsets"]
             off_parts.append(o[1: r + 1].astype(jnp.int32) + np.int32(base_bytes))
             src = c.data["bytes"]
-            part_cap = src.shape[0]
-            dest = jnp.where(jnp.arange(part_cap) < blen,
-                             base_bytes + jnp.arange(part_cap), out_byte_cap)
-            out_bytes = out_bytes.at[dest].set(src, mode="drop")
+            # the part's plane laid in place, one copy: what lies behind
+            # its live bytes is the next part's to overwrite, or dead
+            # bytes behind the last; a part whose dead tail has no room
+            # is cut to what is left (its live bytes fit: the plane holds
+            # total_bytes)
+            room = out_byte_cap - base_bytes
+            out_bytes = _lay_at(out_bytes,
+                                src if src.shape[0] <= room else src[:room],
+                                jnp.int32(base_bytes))
             base_rows += r
             base_bytes += blen
         offsets = jnp.concatenate(off_parts)
@@ -1101,7 +1113,8 @@ def _concat_columns(cols: List[ColumnVector], rows: List[int], cap: int) -> Colu
         if opad > 0:
             offsets = jnp.concatenate([offsets, jnp.broadcast_to(offsets[-1:], (opad,))])
         return ColumnVector(dtype, {"offsets": offsets, "bytes": out_bytes}, validity,
-                            str_width=_union_width(cols))
+                            str_width=_union_width(cols),
+                            str_bytes=total_bytes)
 
     merged = jnp.concatenate([c.data[:r] for c, r in zip(cols, rows)])
     if cap - merged.shape[0] > 0:

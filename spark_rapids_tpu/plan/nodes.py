@@ -431,9 +431,12 @@ class Aggregate(PlanNode):
         fields += [T.StructField(a.name, a.fn.result_type()) for a in self.aggs]
         return T.Schema(tuple(fields))
 
+    #: what the planner did to this node, shown after its description
+    note = ""
+
     def describe(self):
         return (f"Aggregate[keys=[{', '.join(self.group_names)}], "
-                f"aggs=[{', '.join(a.name for a in self.aggs)}]]")
+                f"aggs=[{', '.join(a.name for a in self.aggs)}]]{self.note}")
 
 
 def _bind_leaf(node, schema):

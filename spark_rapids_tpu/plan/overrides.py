@@ -145,7 +145,7 @@ def _like_check(e):
 
 expr_rule(S.Like, Sigs.COMMON, Sigs.COMMON, "SQL LIKE", extra=_like_check)
 expr_rule(S._StringEquals, Sigs.COMMON, Sigs.COMMON, "string equality")
-expr_rule(S._AndExpr, Sigs.COMMON, Sigs.COMMON, "internal AND")
+expr_rule(S._LiteralMatch, Sigs.COMMON, Sigs.COMMON, "literal runs match")
 
 
 def _rlike_check(e):

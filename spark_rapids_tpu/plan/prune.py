@@ -16,7 +16,12 @@ Q6 names 4 of lineitem's 16 columns).
 A Filter over a join first hands the conjuncts that read one side alone
 to that side (`push_filters`; the SQL text's WHERE sits above its joins,
 and a dimension filtered before a star join is a smaller build table and a
-probe that can be compacted early). Then three rewrites of columns. Two
+probe that can be compacted early); a join's own ON condition is split the
+same way (`_push_on`: a conjunct over the null-supplying side of an outer
+join filters that side's input, which is the same join; one over the
+preserved side stays on the join). An Aggregate of counts over a join's
+right side is then computed below the join, a key at a time
+(`_counts_below_join`). Then three rewrites of columns. Two
 are applied bottom-up:
 - Project(Join(l, r)):   push the used-column subset below the join
 - Project(Window(c)):    push the used-column subset below the window
@@ -220,6 +225,27 @@ def _filter_over(child: P.PlanNode, conjs: list) -> P.PlanNode:
     return _push_filter(f)
 
 
+def _by_side(cond, nl: int, ok_l: bool, ok_r: bool) -> tuple:
+    """The conjuncts of `cond`, bound over a join's two inputs end to end
+    (`nl` columns on the left), as (those that read the left input alone
+    and may move there, the same for the right, remapped to it, the
+    rest). One that reads no column, or whose value depends on where it
+    runs, stays."""
+    to_l, to_r, stay = [], [], []
+    for c in _conjuncts(cond):
+        used: Set[int] = set()
+        _refs(c, used)
+        if not used or _contains_context(c):
+            stay.append(c)
+        elif ok_l and all(i < nl for i in used):
+            to_l.append(c)
+        elif ok_r and all(i >= nl for i in used):
+            to_r.append(_remap(c, {i: i - nl for i in used}))
+        else:
+            stay.append(c)
+    return to_l, to_r, stay
+
+
 def _push_filter(f: P.Filter) -> P.PlanNode:
     """Filter(Join(l, r)) -> Filter'(Join(Filter(l), Filter(r))): each
     conjunct that reads one side alone moves to that side, where the join
@@ -230,19 +256,9 @@ def _push_filter(f: P.Filter) -> P.PlanNode:
     if not isinstance(j, P.Join) or \
             j.how not in ("inner", "left", "left_semi", "left_anti"):
         return f
-    nl = len(j.children[0].schema.fields)
-    to_l, to_r, stay = [], [], []
-    for c in _conjuncts(f.condition):
-        used: Set[int] = set()
-        _refs(c, used)
-        if not used or _contains_context(c):
-            stay.append(c)
-        elif all(i < nl for i in used):
-            to_l.append(c)
-        elif j.how == "inner" and all(i >= nl for i in used):
-            to_r.append(_remap(c, {i: i - nl for i in used}))
-        else:
-            stay.append(c)
+    to_l, to_r, stay = _by_side(
+        f.condition, len(j.children[0].schema.fields), True,
+        j.how == "inner")
     if not to_l and not to_r:
         return f
     nj = P.Join.__new__(P.Join)
@@ -270,15 +286,106 @@ def _contains_context(e) -> bool:
         or any(_contains_context(c) for c in e.children)
 
 
+#: the inputs of a join that a conjunct of its ON condition, reading
+#: that input alone, may filter instead: `L JOIN R ON k AND r(R)` is
+#: `L JOIN (R WHERE r) ON k` wherever R's rows without a partner are not
+#: kept. A conjunct over an input whose rows ARE kept (the left of a LEFT
+#: join) decides which of them find a partner, not which exist: it stays
+_ON_PUSHABLE = {"inner": (True, True), "left": (False, True),
+                "right": (True, False), "left_semi": (False, True),
+                "left_anti": (False, True)}
+
+
+def _push_on(j: P.Join) -> P.PlanNode:
+    """Join(l, r, keys, condition) -> Join'(Filter(l), Filter(r), keys,
+    what is left of the condition). The join is a NEW node (see
+    _push_filter)."""
+    to_l, to_r, stay = _by_side(
+        j.condition, len(j.children[0].schema.fields),
+        *_ON_PUSHABLE.get(j.how, (False, False)))
+    if not to_l and not to_r:
+        return j
+    nj = P.Join.__new__(P.Join)
+    nj.children = [_filter_over(j.children[0], to_l),
+                   _filter_over(j.children[1], to_r)]
+    nj.left_keys, nj.right_keys, nj.how = j.left_keys, j.right_keys, j.how
+    nj.condition = nj.condition_raw = None
+    for c in stay:
+        nj.condition = c if nj.condition is None else E.And(nj.condition, c)
+    return nj
+
+
 def push_filters(p: P.PlanNode) -> P.PlanNode:
     """Bottom-up over the plan; children are replaced in place as the
-    other rewrites do, a Filter that moves is rebuilt."""
+    other rewrites do, a Filter or a join whose condition moves is
+    rebuilt."""
     p.children = [push_filters(c) for c in p.children]
+    if isinstance(p, P.Join) and p.condition is not None and p.left_keys:
+        return _push_on(p)
     if isinstance(p, P.Filter) and isinstance(p.children[0], P.Join):
         f = P.Filter.__new__(P.Filter)
         f.children, f.condition = list(p.children), p.condition
         return _push_filter(f)
     return p
+
+
+def _counts_below_join(a: P.Aggregate) -> P.PlanNode:
+    """Aggregate[G; count(x)...](L JOIN R ON keys), with G over L's
+    columns and every x over R's, inner or left, no other condition ->
+    Aggregate[G; sum(coalesce(n, 0))...](L JOIN (Aggregate[R's keys;
+    count(x) as n...](R)) ON keys): a left row's partners are counted
+    once a key, below the join, instead of being paired with it and
+    counted above. The join's build keys are then unique, so it is the
+    sync-free mask-through probe whatever R's fan-out was, its output is
+    L's rows, and nothing of R crosses it but a count (TPC-H Q13: 7.4 M
+    orders against 750 K customers). Right for any L: a left row of group
+    g with key k adds count(x | key = k) to g either way, 0 where an outer
+    join found it no partner. Not for a GLOBAL aggregate: over no row at
+    all its count is 0, and a sum of no counts is NULL."""
+    from spark_rapids_tpu.expr import aggregates as A
+    j = a.children[0]
+    if not isinstance(j, P.Join) or j.how not in ("inner", "left") \
+            or j.condition is not None or not j.left_keys or not a.aggs \
+            or not a.group_exprs:
+        return a
+    left, right = j.children
+    nl = len(left.schema.fields)
+    if any(i >= nl for i in _refs_of(a.group_exprs)):
+        return a
+    for ag in a.aggs:
+        used = _refs_of(ag.fn.children)
+        if type(ag.fn) is not A.Count or not used \
+                or any(i < nl for i in used) \
+                or any(_contains_context(e) for e in ag.fn.children):
+            return a
+    if any(_contains_context(e) for e in j.right_keys):
+        return a
+    nk = len(j.right_keys)
+    down = {i: i - nl for i in range(nl, nl + len(right.schema.fields))}
+    below = P.Aggregate.__new__(P.Aggregate)
+    below.children = [right]
+    below.raw_group_exprs = list(j.right_keys)
+    below.group_exprs = list(j.right_keys)
+    below.group_names = [f"__key{i}" for i in range(nk)]
+    below.aggs = [A.NamedAgg(A.Count(*[_remap(e, down)
+                                       for e in ag.fn.children]),
+                             f"__count{i}") for i, ag in enumerate(a.aggs)]
+    below.note = " [counts below join]"
+    nj = P.Join.__new__(P.Join)
+    nj.children = [left, below]
+    nj.left_keys = j.left_keys
+    nj.right_keys = [E.BoundRef(i, e.data_type(), f"__key{i}")
+                     for i, e in enumerate(j.right_keys)]
+    nj.how, nj.condition, nj.condition_raw = j.how, None, None
+    above = P.Aggregate.__new__(P.Aggregate)
+    above.children = [nj]
+    above.raw_group_exprs = a.raw_group_exprs
+    above.group_exprs = a.group_exprs
+    above.group_names = a.group_names
+    above.aggs = [A.NamedAgg(A.Sum(E.Coalesce(
+        E.BoundRef(nl + nk + i, T.INT64, f"__count{i}"),
+        E.Literal(0, T.INT64))), ag.name) for i, ag in enumerate(a.aggs)]
+    return above
 
 
 def _refs_of(exprs) -> Set[int]:
@@ -493,5 +600,6 @@ def _prune_bottom_up(p: P.PlanNode) -> P.PlanNode:
     if isinstance(p, P.Aggregate):
         c = p.children[0]
         if isinstance(c, P.Project) and _absorbable_project(c):
-            return _absorb_project_into_agg(p, c)
+            p = _absorb_project_into_agg(p, c)
+        return _counts_below_join(p)
     return p

@@ -93,6 +93,11 @@ _STATS = {
     # (ops/kernels.flatten_dict_column)
     "vocab_predicates_traced": 0,
     "dict_flattens_traced": 0,
+    # a string matched against literal runs by passes over the byte plane
+    # (ops/strmatch.match_runs), and a LIKE that went to the NFA's loop
+    # over row positions (expr/strings.Like: a `_` in the pattern)
+    "like_plane_traced": 0,
+    "like_nfa_traced": 0,
 }
 
 #: set while a fresh entry's first call runs on this thread: the

@@ -66,13 +66,27 @@ WINDOW_SORT_TIME = "windowSortTime"
 WINDOW_SORT_DEVICE_TIME = "windowSortDeviceTime"
 #: ns the DEVICE took over a rollup's programs (pack, argsort and scan up
 #: to the read-back of the levels' counts, then the emit), with whatever
-#: unmarked program its child enqueued just before them (device_mark)
+#: unmarked program its child enqueued just before them (device_mark); and
+#: over a HashAggregateExec's update a batch, then its merge and evaluate
 AGG_DEVICE_TIME = "aggDeviceTime"
 #: ns the DEVICE took over a mask-through inner, semi or anti join's
 #: probe: the cut to the build keys' span and its compaction where taken,
 #: the look-up and the gathers of the build's columns (device_mark);
 #: joinTime is their enqueue
 JOIN_DEVICE_TIME = "joinDeviceTime"
+#: ns a Filter spent issuing a string match over a column's byte plane
+#: (expr/strings._LiteralMatch, Like, RLike): its enqueue
+STRING_MATCH_TIME = "stringMatchTime"
+#: ns the DEVICE took over that Filter's program (device_mark: read at
+#: the next read-back that exists)
+STRING_MATCH_DEVICE_TIME = "stringMatchDeviceTime"
+#: bytes of the columns such a Filter matched, once an evaluation, from
+#: sizes the host has: a flat column's live bytes and its offsets (4 a row
+#: and one more); a dictionary column's vocabulary planes and its codes
+STRING_MATCH_BYTES = "stringMatchBytes"
+#: rows the hash joins handed on, from counts the host has or comes to
+#: have anyway (a lazy count nobody forces is left out)
+JOIN_OUTPUT_ROWS = "joinOutputRows"
 SORT_TIME = "sortTime"
 AGG_TIME = "aggTime"
 JOIN_TIME = "joinTime"

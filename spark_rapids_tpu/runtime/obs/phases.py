@@ -50,7 +50,14 @@ the epilogue the account becomes one record in the obs ring
                               where a rollup runs as one sort),
                               agg_groups (aggGroups: groups the query's
                               largest aggregate emitted, where the host
-                              has the number)
+                              has the number),
+                              string_match_bytes (stringMatchBytes: the
+                              bytes of the columns the query's Filters
+                              matched against literal runs or a regex,
+                              once an evaluation, from sizes the host
+                              has), join_output_rows (joinOutputRows:
+                              rows its hash joins handed on, from counts
+                              the host has)
     mesh                      only when a sharded stage ran: devices (the
                               mesh size) and shard_rows (per stage, the
                               live rows each shard's last body put out,
@@ -72,7 +79,7 @@ partitioning execs (an `np.asarray`/`device_get` inside one exec).
 Process-wide like `keyed_dispatches`.
 
 `timers_ns.*DeviceTime` (joinDeviceTime, aggDeviceTime,
-windowSortDeviceTime) are the DEVICE's time for a step whose span times
+windowSortDeviceTime, stringMatchDeviceTime) are the DEVICE's time for a step whose span times
 only its enqueue (`device_mark()`): the step names one of its outputs, and
 the next `device_wait()` of the thread, before its own wait, waits for
 each named output in turn and stamps the host's clock. A mark's time runs
@@ -92,8 +99,9 @@ import time
 from typing import Dict, Optional
 
 from spark_rapids_tpu.runtime.metrics import (
-    AGG_GROUPS, ESSENTIAL, EXPAND_ROWS, MESH_PUT_BYTES, NUM_SCAN_COLUMNS, NUM_SCAN_COLUMNS_PRUNED,
-    SHARD_WAVES, UPLOAD_BYTES, GpuMetric, walk_exec_tree,
+    AGG_GROUPS, ESSENTIAL, EXPAND_ROWS, JOIN_OUTPUT_ROWS, MESH_PUT_BYTES, NUM_SCAN_COLUMNS,
+    NUM_SCAN_COLUMNS_PRUNED, SHARD_WAVES, STRING_MATCH_BYTES, UPLOAD_BYTES, GpuMetric,
+    walk_exec_tree,
 )
 
 #: record counter -> the exec metric summed into it over the exec tree
@@ -101,7 +109,9 @@ COUNTERS = {"upload_bytes": UPLOAD_BYTES, "shard_waves": SHARD_WAVES,
             "mesh_put_bytes": MESH_PUT_BYTES,
             "scan_columns_read": NUM_SCAN_COLUMNS,
             "scan_columns_pruned": NUM_SCAN_COLUMNS_PRUNED,
-            "expand_rows": EXPAND_ROWS}
+            "expand_rows": EXPAND_ROWS,
+            "string_match_bytes": STRING_MATCH_BYTES,
+            "join_output_rows": JOIN_OUTPUT_ROWS}
 
 #: phase -> the span that times it
 SPANS = {"parse": "sql.parse", "admit": "query.admit",

@@ -340,7 +340,11 @@ class DataFrame:
                 out.append(E.col(f.name))
         return self.select(*out)
 
-    def join(self, other: "DataFrame", on=None, how: str = "inner") -> "DataFrame":
+    def join(self, other: "DataFrame", on=None, how: str = "inner",
+             condition=None) -> "DataFrame":
+        """`condition` (with key pairs in `on`): what else a pair of rows
+        has to satisfy, bound against the two inputs' columns end to
+        end; it holds before an outer join fills its nulls."""
         how = {"leftsemi": "left_semi", "semi": "left_semi",
                "leftanti": "left_anti", "anti": "left_anti",
                "outer": "full", "fullouter": "full", "left_outer": "left",
@@ -365,7 +369,8 @@ class DataFrame:
             lk, rk = list(lk), list(rk)
         else:
             raise TypeError("join on= must be column name(s) or (left, right) pairs")
-        joined = DataFrame(P.Join(self.plan, other.plan, lk, rk, how), self.session)
+        joined = DataFrame(P.Join(self.plan, other.plan, lk, rk, how,
+                                  condition=condition), self.session)
         if dedupe_names and how not in ("left_semi", "left_anti"):
             # PySpark semantics: a single key column in the output. For right
             # joins the surviving values come from the right side.

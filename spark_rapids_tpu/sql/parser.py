@@ -134,6 +134,12 @@ def _has_marker(e):
     return any(_has_marker(c) for c in getattr(e, "children", []))
 
 
+def _cols_of(e) -> list:
+    if isinstance(e, E.Col):
+        return [e]
+    return [x for c in getattr(e, "children", []) for x in _cols_of(c)]
+
+
 def _and_all(conjs):
     out = conjs[0]
     for c in conjs[1:]:
@@ -709,6 +715,22 @@ class _Parser:
                 alias = v.lower()
             else:
                 alias = self.ident().lower()
+            if self.op("("):
+                # alias (c1, c2, ...): the table's columns renamed, by
+                # position (the spec's Q13: `as c_orders (c_custkey,
+                # c_count)`)
+                names = [self.ident()]
+                while self.op(","):
+                    names.append(self.ident())
+                self.expect_op(")")
+                fields = df.plan.schema.fields
+                if len(names) != len(fields):
+                    raise SparkException(
+                        f"SQL: alias {alias} names {len(names)} columns, "
+                        f"its table has {len(fields)}")
+                df = df.select(*[
+                    E.Alias(E.BoundRef(i, f.dtype, f.name), n)
+                    for i, (f, n) in enumerate(zip(fields, names))])
         if alias is not None:
             self._scope[alias] = {n.lower() for n in df.columns}
         return df
@@ -735,26 +757,76 @@ class _Parser:
                 how = "cross"
             else:
                 return df
+            before = set(self._scope)
             right = self._table()
             if how == "cross":
                 df = df.join(right, on=None, how="cross")
                 continue
             if not self.kw("on"):
                 raise SparkException("SQL: JOIN needs ON")
-            cond = self.expr()
-            pairs = self._equi_pairs(cond)
-            df = df.join(right, on=pairs, how=how)
+            pairs, rest = self._split_on(self.expr(), df, right,
+                                         set(self._scope) - before)
+            df = df.join(right, on=pairs, how=how, condition=rest)
 
-    def _equi_pairs(self, cond):
-        """Flatten `a = b AND c = d ...` into join key pairs."""
-        if isinstance(cond, E.And):
-            return self._equi_pairs(cond.children[0]) + \
-                self._equi_pairs(cond.children[1])
-        if isinstance(cond, E.EqualTo):
-            return [(cond.children[0], cond.children[1])]
-        raise SparkException(
-            "SQL: only equi-join ON conditions (a = b AND ...) are "
-            f"supported, got {cond!r}")
+    def _split_on(self, cond, left, right, right_aliases):
+        """An ON clause as (equi-join key pairs, the rest or None). A
+        conjunct `a = b` with one side reading each input is a key pair;
+        any other conjunct rides on the join as its condition, its
+        columns bound to the side that holds them (the planner moves it
+        below the join where the join's kind allows: plan/prune.py). An
+        ON clause with no key pair, or a conjunct that cannot be placed,
+        is rejected with its text."""
+        lcols = [n.lower() for n in left.columns]
+        rcols = [n.lower() for n in right.columns]
+
+        def side(c):
+            """'l', 'r', or '?' (an unqualified name both inputs have)."""
+            name = c.name.lower()
+            if isinstance(c, _QCol):
+                s_ = "r" if c.qualifier in right_aliases else "l"
+                if name in (rcols if s_ == "r" else lcols):
+                    return s_
+            in_l, in_r = name in lcols, name in rcols
+            return "?" if in_l == in_r else ("l" if in_l else "r")
+
+        def sides(e):
+            return {side(x) for x in _cols_of(e)}
+
+        def bound(e):
+            def f(x):
+                if not isinstance(x, E.Col):
+                    return x
+                s_ = side(x)
+                if s_ == "?":
+                    raise SparkException(
+                        "SQL: a column of a JOIN's ON condition must name "
+                        f"one input, got {x.name!r} in {cond!r}")
+                i = (lcols if s_ == "l" else rcols).index(x.name.lower())
+                fld = (left if s_ == "l" else right).plan.schema.fields[i]
+                return E.BoundRef(i + (len(lcols) if s_ == "r" else 0),
+                                  fld.dtype, fld.name)
+            return e.transform(f)
+
+        pairs, rest = [], []
+        for c in _split_and(cond):
+            if _has_marker(c):
+                raise SparkException(
+                    "SQL: a subquery in a JOIN's ON clause is not "
+                    f"supported, got {cond!r}")
+            if isinstance(c, E.EqualTo):
+                a, b = c.children
+                sa, sb = sides(a), sides(b)
+                if sa == {"r"} and sb == {"l"}:
+                    a, b, sa, sb = b, a, sb, sa
+                if sa and sb and sa <= {"l", "?"} and sb <= {"r", "?"}:
+                    pairs.append((a, b))    # as written: left key first
+                    continue
+            rest.append(bound(c))
+        if not pairs:
+            raise SparkException(
+                "SQL: only equi-join ON conditions (a = b AND ...) are "
+                f"supported, got {cond!r}")
+        return pairs, _and_all(rest) if rest else None
 
     def _select_core(self):
         if not self.kw("select"):

@@ -674,7 +674,9 @@ def test_recent_queries_one_record_per_top_level_action():
                                       "shard_waves", "mesh_put_bytes",
                                       "scan_columns_read",
                                       "scan_columns_pruned",
-                                      "expand_rows", "agg_groups"}
+                                      "expand_rows", "agg_groups",
+                                      "string_match_bytes",
+                                      "join_output_rows"}
         assert r["counters"]["scan_columns_read"] == 0  # no Parquet scan
         assert "mesh" not in r  # no sharded stage ran
         assert r["counters"]["upload_bytes"] > 0  # the in-memory scan's
