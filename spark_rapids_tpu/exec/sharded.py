@@ -19,7 +19,12 @@ Where the operands come from (``MeshWave``):
   ``jax.make_array_from_single_device_arrays`` — no copy, no host hop —
   and only the columns the bodies name become operands. What leaves the
   chips is the stage's output (for the aggregate: partial states, a few
-  rows a shard). ``meshPutBytes`` stays 0.
+  rows a shard), in ONE ``device_get`` (``read_back``): numpy planes,
+  host-int row counts, one vocabulary object a string column. A grouped
+  aggregate's exchange hands such states on without exchanging them
+  while they are few (``ShuffleExchangeExec._bypass``: one batch for the
+  final aggregate, laid together by numpy); a global aggregate's merge
+  lays them together the same way. ``meshPutBytes`` stays 0.
 - **host pack**: any other input. One batch per partition is packed into
   ``[n_shards * capacity]`` planes and ``device_put`` across the ``part``
   axis; the bytes are counted in ``meshPutBytes``.
@@ -35,8 +40,9 @@ tree):
   shapes differ per shard, so they cannot pack into one uniform SPMD
   operand. Dictionary-coded strings of a placed cache share one
   vocabulary and shard as their codes; anywhere else dict columns still
-  cross the mesh through ShuffleExchangeExec's ICI all-to-all, which
-  aligns vocabs host-side before the collective;
+  cross the mesh through ShuffleExchangeExec's ICI all-to-all (where
+  there are rows enough to exchange), which aligns vocabs host-side
+  before the collective;
 - a chain rooted at DeviceDecodeScanExec is excluded for the same
   raggedness reason (encoded vocab planes vary per batch).
 
@@ -399,17 +405,17 @@ class MeshWave:
             k = x.shape[0] // m
             return x[i * k:(i + 1) * k]
 
+        # every shard ran one program over one vocabulary: the slots
+        # share slot 0's copy, ONE object a plane, so a consumer that
+        # asks "same vocabulary?" by identity hears yes
+        vocabs = [{k: part(p[k], 0) for k in ("dict_offsets", "dict_bytes")}
+                  if "codes" in p else {} for p in out_planes]
         batches = {}
         for i in present:
             cols = []
-            for p, dt in zip(out_planes, out_dtypes):
+            for p, vocab, dt in zip(out_planes, vocabs, out_dtypes):
                 sl = {k: part(v, i) for k, v in p.items()}
-                if "codes" in sl:
-                    # every shard ran one program over one vocabulary:
-                    # the slots share slot 0's copy, so a consumer that
-                    # asks "same vocabulary?" by identity hears yes
-                    sl["dict_offsets"] = part(p["dict_offsets"], 0)
-                    sl["dict_bytes"] = part(p["dict_bytes"], 0)
+                sl.update(vocab)
                 cols.append(compiled._col_from_planes(sl, dt))
             batches[i] = ColumnarBatch(cols, int(out_rows[i]),
                                        part(out_live, i))

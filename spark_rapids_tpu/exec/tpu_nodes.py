@@ -3544,9 +3544,14 @@ class HashAggregateExec(TpuExec):
             # A single partial already has unique keys — merging is
             # identity. NOT true of an exchange-coalesced batch: that is
             # a concat of several partials (duplicate keys across the
-            # seams), exactly what the merge kernel below exists to fold.
+            # seams: tiny post-shuffle slices, or the whole of a bypassed
+            # exchange's input, ShuffleExchangeExec._bypass), exactly
+            # what the merge kernel below exists to fold.
             return partials[0]
-        batch = K.concat_batches(partials)
+        # partials a sharded update read back to the host (a global
+        # aggregate's, through the collect) are laid together there
+        batch = K.concat_host_batches(partials) \
+            or K.concat_batches(partials)
         nkeys = len(self.plan.group_exprs)
         if nkeys == 0 and batch.num_rows <= 1:
             return batch
@@ -3633,6 +3638,14 @@ def _partitioning_mode(conf) -> str:
             "spark.rapids.shuffle.partitioning must be 'compact' or "
             f"'masked', got {v!r}")
     return v
+
+
+def _tiny_rows(exchange) -> int:
+    """spark.rapids.shuffle.coalesceTinyRows, or the measured cost pass's
+    override of it; a coalesced batch holds at most 4x this."""
+    override = getattr(exchange, "_tiny_override", None)
+    return int(override) if override is not None \
+        else int(exchange.conf.get(C.SHUFFLE_COALESCE_TINY_ROWS))
 
 
 class ExchangeExec(TpuExec):
@@ -4006,9 +4019,7 @@ class ExchangeExec(TpuExec):
         count is still on device pass through untouched, as do masked
         batches and lazily-deserialized shuffle blobs). Merges count
         into shuffleCoalescedBatches — visible in EXPLAIN ANALYZE."""
-        override = getattr(self, "_tiny_override", None)
-        tiny = int(override) if override is not None \
-            else int(self.conf.get(C.SHUFFLE_COALESCE_TINY_ROWS))
+        tiny = _tiny_rows(self)
         if tiny <= 0 or getattr(self, "n_out", 1) <= 1:
             yield from batches
             return
@@ -4077,12 +4088,34 @@ class ShuffleExchangeExec(ExchangeExec):
     realization of the reference's UCX transport replacement (SURVEY.md
     §2.7 "TPU-native equivalent"). Falls back to MULTITHREADED when the
     device count or column layout doesn't fit (flat strings / differing
-    vocabs can't ride a fixed-width collective)."""
+    vocabs can't ride a fixed-width collective).
 
-    def __init__(self, plan, children, conf, keys: List[Expression], n_out: int):
+    Neither runs where there is nothing to exchange (`_bypass`): an
+    exchange the planner built between the halves of one aggregate
+    (`may_bypass`), whose whole input is already on the host with
+    host-int row counts and within the tiny-coalescing budget (a sharded
+    partial aggregate's read-back: a few groups a shard), lays those
+    rows together with numpy as ONE batch of partition 0 and leaves the
+    other partitions empty. Every row of a key is then in one partition,
+    which is all the final aggregate asks. An exchange under a join never
+    does this (both sides must be co-partitioned)."""
+
+    def __init__(self, plan, children, conf, keys: List[Expression], n_out: int,
+                 may_bypass: bool = False):
         super().__init__(plan, children, conf)
         self.keys = keys
         self.n_out = n_out
+        #: set by the planner where the sole consumer is the final
+        #: aggregate of the same plan node (plan/overrides.py)
+        self.may_bypass = may_bypass
+        #: rows the last run passed without exchanging them, or None
+        self._bypassed_rows: Optional[int] = None
+
+    def tree_string(self, indent: int = 0) -> str:
+        head, nl, rest = super().tree_string(indent).partition("\n")
+        if self._bypassed_rows is not None:
+            head += f" [bypassed: {self._bypassed_rows} rows on the host]"
+        return f"{head}{nl}{rest}"
 
     @property
     def num_partitions(self):
@@ -4113,6 +4146,9 @@ class ShuffleExchangeExec(ExchangeExec):
         return super()._item_rows(item, pidx)
 
     def _repartition(self, child_results):
+        out = self._bypass(child_results)
+        if out is not None:
+            return out
         mode = self.conf.get(C.SHUFFLE_MODE).upper()
         if self._ici_first:
             with self.span(self.metrics.metric(M.PARTITION_TIME)):
@@ -4122,6 +4158,35 @@ class ShuffleExchangeExec(ExchangeExec):
         if mode == "SERIALIZED":
             return self._repartition_serialized(child_results)
         return self._repartition_device(child_results)
+
+    def _bypass(self, child_results):
+        """The input as one batch of partition 0, the other partitions
+        empty, where the planner allows it and nothing needs exchanging:
+        every batch on the host with a host-int row count (no
+        LazyRowCount: the decision adds no sync) and the rows in all
+        within what a coalesced batch may hold (4x coalesceTinyRows; 0
+        turns this off with the coalescing). None otherwise, and for a
+        live stream of batches, which cannot be looked at twice: the
+        caller then exchanges as it always did. The batch is marked
+        `coalesced` (it is a concat of partials: the final aggregate must
+        run its merge kernel on it) and skips the skew split, which would
+        cut sixteen rows against three empty partitions."""
+        tiny = _tiny_rows(self)
+        if not self.may_bypass or tiny <= 0 \
+                or not all(isinstance(p, list) for p in child_results):
+            return None
+        batches = [b for part in child_results for b in part]
+        merged = K.concat_host_batches(batches, 4 * tiny)
+        if merged is None:
+            return None
+        self._bypassed_rows = merged.num_rows
+        self.metrics.metric(M.EXCHANGE_BYPASSED).add(1)
+        self.metrics.metric(M.NUM_OUTPUT_ROWS).add(merged.num_rows)
+        merged.coalesced = True
+        out: List[List[ColumnarBatch]] = [[] for _ in range(self.n_out)]
+        if merged.num_rows:
+            out[0].append(merged)
+        return out
 
     def _repartition_device(self, child_results):
         """In-memory device partitioning (the MULTITHREADED mode body and
@@ -4280,6 +4345,9 @@ class ShuffleExchangeExec(ExchangeExec):
 
     def execute_partition(self, ctx, pidx):
         out = self._materialize()
+        if self._bypassed_rows is not None:
+            yield from out[pidx]
+            return
 
         def decoded():
             for item in out[pidx]:
@@ -4346,7 +4414,12 @@ class ShuffleExchangeExec(ExchangeExec):
 
     def _repartition_ici(self, child_results):
         """One shard per device, rows moved by lax.all_to_all inside a
-        single shard_map program (parallel/exchange.py)."""
+        single shard_map program (parallel/exchange.py). Reached only
+        where `_bypass` declined: the join exchanges, partial states
+        whose counts are lazy or whose planes are on the device, and
+        states of more rows than a coalesced batch holds (a group-by of
+        many keys). None where the layout does not fit the collective;
+        the caller then partitions on the device."""
         if not self._ici_eligible(child_results):
             return None
         from jax.sharding import NamedSharding, PartitionSpec as PS

@@ -903,8 +903,13 @@ def flatten_dict_column(col: ColumnVector, num_rows) -> ColumnVector:
                         col.validity, str_width=col.str_width)
 
 
-def _same_array(a, b) -> bool:
-    return a is b
+def _shared_vocab(cols: List[ColumnVector]) -> bool:
+    """One vocabulary OBJECT under all of these dictionary columns: the
+    only sameness that costs no read of the planes."""
+    d0 = cols[0].data
+    return all(c.data["dict_offsets"] is d0["dict_offsets"]
+               and c.data["dict_bytes"] is d0["dict_bytes"]
+               for c in cols[1:])
 
 
 def concat_batches(batches: List[ColumnarBatch]) -> ColumnarBatch:
@@ -933,6 +938,78 @@ def concat_batches(batches: List[ColumnarBatch]) -> ColumnarBatch:
         rows = [int(b.num_rows) for b in nonempty]
         out_cols.append(_concat_columns(cols, rows, round_capacity(total)))
     return ColumnarBatch(out_cols, total)
+
+
+def _on_host(plane) -> bool:
+    return plane is None or isinstance(plane, np.ndarray)
+
+
+def concat_host_batches(batches: List[ColumnarBatch],
+                        limit: Optional[int] = None
+                        ) -> Optional[ColumnarBatch]:
+    """`batches` as ONE compact batch (no mask, a host-int row count)
+    assembled by numpy in source order, or None where that would cost a
+    sync or a program: a row count still on the device, a plane that is
+    not numpy, a flat-string or nested column, dictionary columns whose
+    vocabulary is not one object (equal strings must stay one code), or,
+    where a limit is given, more than `limit` rows in all. What a sharded
+    stage's read-back (exec/sharded.MeshWave.read_back) hands on is
+    exactly this shape, and a few rows of it are cheaper to lay together
+    here than through concat_batches' eager slices and concatenates. The
+    planes stay numpy: the consumer's jitted kernel uploads them."""
+    if not batches:
+        return None
+    live = 0
+    for b in batches:
+        if not isinstance(b.num_rows, int) or not _on_host(b.row_mask):
+            return None
+        live += b.num_rows
+    if limit is not None and live > limit:
+        return None
+    by_col = [[b.columns[ci] for b in batches]
+              for ci in range(batches[0].num_cols)]
+    for parts in by_col:
+        if all(c.is_dict for c in parts):
+            if not _shared_vocab(parts):
+                return None
+            planes = [c.data["codes"] for c in parts]
+        elif any(isinstance(c.data, dict) for c in parts):
+            return None
+        else:
+            planes = [c.data for c in parts]
+        if not all(isinstance(p, np.ndarray) for p in planes) \
+                or not all(_on_host(c.validity) for c in parts):
+            return None
+    # the rows each source keeps, in its own order (a masked batch's
+    # count is its mask's)
+    keep = [slice(0, b.num_rows) if b.row_mask is None else b.row_mask
+            for b in batches]
+    cap = round_capacity(max(live, 1))
+
+    def laid(planes, dtype):
+        out = np.zeros(cap, dtype)
+        out[:live] = np.concatenate([p[k] for p, k in zip(planes, keep)])
+        return out
+
+    cols = []
+    for parts in by_col:
+        c0 = parts[0]
+        validity = laid([np.ones(c.capacity, np.bool_) if c.validity is None
+                         else c.validity for c in parts], np.bool_)
+        if c0.is_dict:
+            codes = laid([c.data["codes"] for c in parts],
+                         c0.data["codes"].dtype)
+            cols.append(ColumnVector(
+                c0.dtype, {"codes": codes,
+                           "dict_offsets": c0.data["dict_offsets"],
+                           "dict_bytes": c0.data["dict_bytes"]}, validity,
+                dict_unique=all(c.dict_unique for c in parts),
+                str_width=_union_width(parts)))
+        else:
+            cols.append(ColumnVector(
+                c0.dtype, laid([c.data for c in parts], c0.data.dtype),
+                validity, bounds=_union_bounds(parts)))
+    return ColumnarBatch(cols, live)
 
 
 def _union_bounds(cols: List[ColumnVector]):
@@ -983,12 +1060,7 @@ def align_dict_columns(cols: List[ColumnVector]) -> List[ColumnVector]:
     """NEW dict columns whose codes index ONE shared union vocabulary
     (inputs untouched). No-op (returns the same objects) when the vocab
     planes are already identical."""
-    same = all(_same_array(c.data["dict_offsets"],
-                           cols[0].data["dict_offsets"])
-               and _same_array(c.data["dict_bytes"],
-                               cols[0].data["dict_bytes"])
-               for c in cols[1:])
-    if same:
+    if _shared_vocab(cols):
         return list(cols)
     uoff, ubytes, remaps = unify_vocabs(cols)
     doff = jnp.asarray(uoff)
@@ -1023,10 +1095,7 @@ def _concat_columns(cols: List[ColumnVector], rows: List[int], cap: int) -> Colu
         validity = jnp.concatenate([validity, jnp.zeros(pad, jnp.bool_)])
 
     if all(c.is_dict for c in cols):
-        shared = all(_same_array(c.data["dict_offsets"], cols[0].data["dict_offsets"])
-                     and _same_array(c.data["dict_bytes"], cols[0].data["dict_bytes"])
-                     for c in cols[1:])
-        if shared:
+        if _shared_vocab(cols):
             codes = jnp.concatenate([c.data["codes"][:r] for c, r in zip(cols, rows)])
             if pad > 0:
                 codes = jnp.concatenate([codes, jnp.zeros(pad, codes.dtype)])

@@ -1106,8 +1106,12 @@ class SparkPlanMeta:
         if nkeys and not single_device and not _measured_collapse():
             keys = [E.BoundRef(i, e.data_type(), n) for i, (e, n) in
                     enumerate(zip(p.group_exprs, p.group_names))]
+            # its sole consumer is the final aggregate below, which asks
+            # only that a key's rows share a partition: the one exchange
+            # that may pass a few host-resident rows on unexchanged
             exch = X.ShuffleExchangeExec(p, [partial], conf, keys,
-                                         n_out=child.num_partitions)
+                                         n_out=child.num_partitions,
+                                         may_bypass=True)
         else:
             # one device: a hash exchange between partial and final states
             # only re-slices arrays that already live together — collect

@@ -141,6 +141,13 @@ MESH_PUT_BYTES = "meshPutBytes"
 #: attribution exclude it so exchange time is never double-counted;
 #: the attribution 'ici_exchange' view reports it separately.
 ICI_EXCHANGE_TIME = "iciExchangeTime"
+#: hash exchanges a query did not execute: the planner allowed it (the
+#: sole consumer is the final aggregate of the same plan node), every
+#: input batch was on the host with a host-int row count, and the rows in
+#: all stayed within the tiny-coalescing budget, so they went as ONE
+#: batch to partition 0 (ShuffleExchangeExec._bypass); its rows count
+#: into numOutputRows as any exchange's do
+EXCHANGE_BYPASSED = "exchangeBypassed"
 #: post-shuffle sub-batches merged by tiny-partition coalescing
 #: (spark.rapids.shuffle.coalesceTinyRows): adjacent device sub-batches
 #: under the threshold concat into one batch before downstream

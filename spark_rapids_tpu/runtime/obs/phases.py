@@ -40,6 +40,10 @@ the epilogue the account becomes one record in the obs ring
                               (meshPutBytes: table planes moved onto or
                               between chips to feed a mesh; 0 when the
                               shards are consumed where they live),
+                              exchange_bypassed (exchangeBypassed: hash
+                              exchanges whose input, a few rows already
+                              on the host, went whole to one partition
+                              and was never exchanged),
                               scan_columns_read, scan_columns_pruned
                               (numScanColumns, numScanColumnsPruned over
                               the query's Parquet scans: columns the host
@@ -99,14 +103,15 @@ import time
 from typing import Dict, Optional
 
 from spark_rapids_tpu.runtime.metrics import (
-    AGG_GROUPS, ESSENTIAL, EXPAND_ROWS, JOIN_OUTPUT_ROWS, MESH_PUT_BYTES, NUM_SCAN_COLUMNS,
-    NUM_SCAN_COLUMNS_PRUNED, SHARD_WAVES, STRING_MATCH_BYTES, UPLOAD_BYTES, GpuMetric,
-    walk_exec_tree,
+    AGG_GROUPS, ESSENTIAL, EXCHANGE_BYPASSED, EXPAND_ROWS, JOIN_OUTPUT_ROWS, MESH_PUT_BYTES,
+    NUM_SCAN_COLUMNS, NUM_SCAN_COLUMNS_PRUNED, SHARD_WAVES, STRING_MATCH_BYTES, UPLOAD_BYTES,
+    GpuMetric, walk_exec_tree,
 )
 
 #: record counter -> the exec metric summed into it over the exec tree
 COUNTERS = {"upload_bytes": UPLOAD_BYTES, "shard_waves": SHARD_WAVES,
             "mesh_put_bytes": MESH_PUT_BYTES,
+            "exchange_bypassed": EXCHANGE_BYPASSED,
             "scan_columns_read": NUM_SCAN_COLUMNS,
             "scan_columns_pruned": NUM_SCAN_COLUMNS_PRUNED,
             "expand_rows": EXPAND_ROWS,

@@ -34,6 +34,13 @@ ON = C.MULTICHIP_ENABLED.key
 DEVICES = C.MULTICHIP_DEVICES.key
 
 
+#: a group for every lineitem row: its partial states are more rows than
+#: an exchange passes on unexchanged (4 x spark.rapids.shuffle
+#: .coalesceTinyRows), so its merge rides the all_to_all
+WIDE_GROUP_BY = ("select l_orderkey, l_linenumber, sum(l_quantity) as q "
+                 "from lineitem group by l_orderkey, l_linenumber")
+
+
 def _mesh_session(n):
     return TpuSession({ON: "true", DEVICES: n})
 
@@ -246,12 +253,21 @@ def test_phase_account_carries_the_mesh(tpch):
     rec = obs.recent_queries(1)[0]
     assert rec["counters"]["shard_waves"] == 1
     assert rec["counters"]["mesh_put_bytes"] == 0  # consumed in place
-    for timer in ("shardDispatchTime", "shardReadbackTime",
-                  "iciExchangeTime"):
+    for timer in ("shardDispatchTime", "shardReadbackTime"):
         assert rec["timers_ns"][timer] > 0, timer
+    # Q1's sixteen rows of partial state are not exchanged (ISSUE 37)
+    assert rec["counters"]["exchange_bypassed"] == 1
+    assert "iciExchangeTime" not in rec["timers_ns"]
     assert rec["mesh"]["devices"] == 4
     (rows,) = rec["mesh"]["shard_rows"]
     assert len(rows) == 4 and sum(rows) == plain["lineitem"].num_rows
+    # a group a row is more than a coalesced batch holds: the merge of
+    # those states still rides the all_to_all
+    sess.sql(WIDE_GROUP_BY).to_pydict()
+    wide = obs.recent_queries(1)[0]
+    assert wide["counters"]["shard_waves"] == 1
+    assert wide["counters"]["exchange_bypassed"] == 0
+    assert wide["timers_ns"]["iciExchangeTime"] > 0
     # a host-packed wave (no cache under it) counts what it puts
     data = {"g": [i % 5 for i in range(4000)],
             "v": [float(i) for i in range(4000)]}
@@ -294,14 +310,18 @@ def test_a_profiler_capture_holds_the_sharded_spans(tpch, tmp_path):
     _tables, plain = tpch
     sess = _mesh_session(4)
     _place(sess, {"lineitem": plain["lineitem"]})
-    text = harness.load_query("q1")
-    sess.sql(text).to_pydict()  # compile outside the capture
+    # Q1 for the sharded update; the wide group-by for the exchange, which
+    # Q1's few rows of state no longer take (ISSUE 37)
+    texts = (harness.load_query("q1"), WIDE_GROUP_BY)
+    for text in texts:
+        sess.sql(text).to_pydict()  # compile outside the capture
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     options.host_tracer_level = 2
     jax.profiler.start_trace(str(tmp_path), profiler_options=options)
     try:
-        sess.sql(text).to_pydict()
+        for text in texts:
+            sess.sql(text).to_pydict()
     finally:
         jax.profiler.stop_trace()
     files = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)

@@ -672,6 +672,7 @@ def test_recent_queries_one_record_per_top_level_action():
         assert r["timers_ns"]["copyToDeviceTime"] > 0
         assert set(r["counters"]) == {"keyed_dispatches", "upload_bytes",
                                       "shard_waves", "mesh_put_bytes",
+                                      "exchange_bypassed",
                                       "scan_columns_read",
                                       "scan_columns_pruned",
                                       "expand_rows", "agg_groups",
