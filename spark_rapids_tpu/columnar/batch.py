@@ -71,7 +71,7 @@ class LazyRowCount:
 
     def materialize(self) -> int:
         if self._val is None:
-            self._val = host_int(self._dev)
+            self._val = host_int(self._dev, "count")
         return self._val
 
     @property
@@ -133,12 +133,25 @@ def traced_rows(n):
     return n.traced() if isinstance(n, LazyRowCount) else n
 
 
-def host_int(dev) -> int:
-    """A device scalar as a host int: a sync, so the query's phase
-    account times it as device wait (runtime/obs/phases.py). Hand it the
-    already-enqueued scalar: `host_int(jnp.sum(x))`."""
-    with device_wait():
+def host_int(dev, site: str) -> int:
+    """A device scalar as a host int: a sync at door `site`, so the
+    query's phase account counts and times it (runtime/obs/phases.py);
+    a host scalar as it is, with no sync. Hand it the already-enqueued
+    scalar: `host_int(jnp.sum(x), "size")`."""
+    if not isinstance(dev, jax.Array):
         return int(dev)
+    with device_wait(site):
+        return int(dev)
+
+
+def host_planes(tree, site: str):
+    """`tree` (planes, lists, tuples, dicts of them; None allowed) with
+    every device array in it brought to the host in ONE read at door
+    `site`; as it is, with no sync, where nothing in it is on the device."""
+    if any(isinstance(x, jax.Array) for x in jax.tree_util.tree_leaves(tree)):
+        with device_wait(site):
+            return jax.device_get(tree)
+    return tree
 
 
 def rows_int(n) -> int:
@@ -153,9 +166,7 @@ def materialize_counts(batches: Sequence["ColumnarBatch"]) -> None:
               if isinstance(b.num_rows, LazyRowCount) and not b.num_rows.is_materialized]
     if not lazies:
         return
-    import jax as _jax
-    with device_wait():
-        vals = _jax.device_get([lz._dev for lz in lazies])
+    vals = host_planes([lz._dev for lz in lazies], "count")
     for lz, v in zip(lazies, vals):
         lz._val = int(v)
 
@@ -663,25 +674,27 @@ def column_to_numpy(col: ColumnVector, num_rows: int, sel=None):
     return vals, valid
 
 
-def fetch_batch_host(batch: ColumnarBatch) -> ColumnarBatch:
+def fetch_batch_host(batch: ColumnarBatch, site: str) -> ColumnarBatch:
     """Pull every plane of a batch to host in ONE bulk transfer (a
-    per-plane np.asarray costs a round trip each). Returns a batch whose
-    planes are host numpy arrays; the lazy row count rides along."""
+    per-plane np.asarray costs a round trip each) at door `site`. Returns
+    a batch whose planes are host numpy arrays; the lazy row count rides
+    along."""
     leaves, treedef = jax.tree_util.tree_flatten(batch)
-    with device_wait():
-        host = jax.device_get(leaves)
-    out = jax.tree_util.tree_unflatten(treedef, host)
+    out = jax.tree_util.tree_unflatten(treedef, host_planes(leaves, site))
     n = int(out.num_rows)
     if isinstance(batch.num_rows, LazyRowCount):
         batch.num_rows._val = n
     return ColumnarBatch(out.columns, n, out.row_mask)
 
 
-def to_arrow(batch: ColumnarBatch, names: Optional[Sequence[str]] = None):
-    """Device ColumnarBatch -> pyarrow Table (C2R boundary). Selection-mask
-    compaction happens host-side with numpy (free next to the transfer)."""
+def to_arrow(batch: ColumnarBatch, names: Optional[Sequence[str]] = None,
+             site: str = "fetch"):
+    """Device ColumnarBatch -> pyarrow Table (C2R boundary), downloaded at
+    door `site` (a result's rows by default; "host" where an operator
+    takes the rows to the host mid-query). Selection-mask compaction
+    happens host-side with numpy (free next to the transfer)."""
     import pyarrow as pa
-    batch = fetch_batch_host(batch)
+    batch = fetch_batch_host(batch, site)
     n = batch.num_rows
     sel = None
     if batch.row_mask is not None:

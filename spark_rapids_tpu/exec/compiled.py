@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import (
-    ColumnVector, ColumnarBatch, carry_host_stats, host_int,
+    ColumnVector, ColumnarBatch, carry_host_stats, host_planes,
 )
 from spark_rapids_tpu.expr.core import EvalCtx, Expression, SparkException
 from spark_rapids_tpu.runtime import compile_cache as _cc
@@ -126,10 +126,12 @@ def carry_bounds(exprs, in_cols, out_cols) -> None:
 
 def raise_errors(err: Dict[str, jax.Array]) -> None:
     """Check ANSI error planes from a fused stage. Only synchronizes when
-    the stage ran in ANSI mode and produced error masks."""
+    the stage ran in ANSI mode and produced error masks: ONE read of
+    every plane's any()."""
     if err:
-        for code, mask in err.items():
-            if host_int(jnp.any(mask)):
+        hit = host_planes([jnp.any(mask) for mask in err.values()], "flags")
+        for code, h in zip(err, hit):
+            if h:
                 raise SparkException(f"[{code}] ANSI mode error in stage")
 
 

@@ -225,7 +225,7 @@ def apply_node(plan: P.PlanNode, children: List[List[CpuCol]],
         tables = []
         for r in range(plan.n_reduce):
             for b in read_partition_batches(plan.root, r):
-                tables.append(to_arrow(b, plan.schema.names))
+                tables.append(to_arrow(b, plan.schema.names, "host"))
         table = pa.concat_tables(tables) if tables else \
             pa.table({n: pa.array([], T.to_arrow(t))
                       for n, t in zip(plan.schema.names, plan.schema.types)})

@@ -44,7 +44,8 @@ import jax.numpy as jnp
 from spark_rapids_tpu import config as C
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import (
-    ColumnVector, ColumnarBatch, LazyRowCount, round_capacity, traced_rows,
+    ColumnVector, ColumnarBatch, LazyRowCount, host_planes, round_capacity,
+    traced_rows,
 )
 from spark_rapids_tpu.exec import compiled, fuse
 from spark_rapids_tpu.exec import tpu_nodes as X
@@ -54,7 +55,7 @@ from spark_rapids_tpu.ops import radix as R
 from spark_rapids_tpu.ops.pallas_decode import _cumsum
 from spark_rapids_tpu.plan import nodes as P
 from spark_rapids_tpu.runtime import metrics as M
-from spark_rapids_tpu.runtime.obs.phases import device_mark, device_wait
+from spark_rapids_tpu.runtime.obs.phases import device_mark
 
 #: state reductions that are differences of prefix sums
 _OPS = frozenset({"sum", "count", "count_all"})
@@ -437,9 +438,8 @@ class RollupAggregateExec(X.TpuExec):
             sp, shared, before, whole, scales, counts_d = scan(
                 planes, perm, values)
             device_mark(dev_t, counts_d, t0)
-            with device_wait():
-                # tpulint: deferred-fetch the levels' counts size the output
-                counts = [int(c) for c in jax.device_get(counts_d)]
+            # tpulint: deferred-fetch the levels' counts size the output
+            counts = [int(c) for c in host_planes(counts_d, "size")]
             t0 = time.perf_counter_ns()
             plain, counts = counts[-1] == 0, counts[:-1]
             total = sum(counts)

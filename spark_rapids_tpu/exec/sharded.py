@@ -77,7 +77,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from spark_rapids_tpu import config as C
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import (ColumnVector, ColumnarBatch,
-                                             traced_rows)
+                                             host_planes, traced_rows)
 from spark_rapids_tpu.exec import compiled, fuse
 from spark_rapids_tpu.exec.stage_fusion import (_ReplaySourceExec,
                                                 fused_stage_cls)
@@ -85,7 +85,6 @@ from spark_rapids_tpu.parallel import mesh as MESH
 from spark_rapids_tpu.plan.prune import _refs
 from spark_rapids_tpu.runtime import metrics as M
 from spark_rapids_tpu.runtime import obs as OBS
-from spark_rapids_tpu.runtime.obs.phases import device_wait
 
 log = logging.getLogger("spark_rapids_tpu")
 
@@ -391,9 +390,8 @@ class MeshWave:
         Returns ({slot: batch}, rows per body [m])."""
         from spark_rapids_tpu.analysis import kernel_audit as KA
         m = self.m
-        with device_wait():
-            out_planes, out_live, out_rows, errs_all, rows = \
-                jax.device_get(out)
+        out_planes, out_live, out_rows, errs_all, rows = \
+            host_planes(out, "shard")
         for errs in errs_all:
             compiled.raise_errors(errs)
         KA.note_shards(m, rows[-1])
@@ -484,17 +482,21 @@ def make_sharded_stage_exec():
             if any(isinstance(c.data, dict)
                    for b in batches for c in b.columns):
                 return batches  # ragged dict planes: per-batch waves
-            live = np.concatenate(
-                [np.asarray(b.live_mask()) for b in batches])
+            # every plane of every batch in ONE read (none where the
+            # batches are the host's already)
+            live, data, valid = host_planes((
+                [b.live_mask() for b in batches],
+                [[c.data for c in b.columns] for b in batches],
+                [[c.validity for c in b.columns] for b in batches]), "shard")
+            live = np.concatenate([np.asarray(v) for v in live])
             cols = []
             for j in range(len(batches[0].columns)):
                 parts = [b.columns[j] for b in batches]
-                data = np.concatenate(
-                    [np.asarray(c.data) for c in parts])
+                plane = np.concatenate([np.asarray(d[j]) for d in data])
                 validity = np.concatenate(
-                    [np.ones(c.capacity, np.bool_) if c.validity is None
-                     else np.asarray(c.validity) for c in parts])
-                cols.append(ColumnVector(parts[0].dtype, data, validity))
+                    [np.ones(c.capacity, np.bool_) if v[j] is None
+                     else np.asarray(v[j]) for c, v in zip(parts, valid)])
+                cols.append(ColumnVector(parts[0].dtype, plane, validity))
             return [ColumnarBatch(cols, int(live.sum()), live)]
 
         def _out_bounds(self, in_bounds, out_cols):

@@ -45,7 +45,7 @@ from typing import List, Optional
 import jax.numpy as jnp
 
 from spark_rapids_tpu import config as C
-from spark_rapids_tpu.columnar.batch import LazyRowCount
+from spark_rapids_tpu.columnar.batch import LazyRowCount, host_planes
 from spark_rapids_tpu.exec import compiled, fuse
 from spark_rapids_tpu.runtime import metrics as M
 from spark_rapids_tpu.runtime import obs as OBS
@@ -265,8 +265,8 @@ def make_fused_stage_exec():
                 # every later batch is all-dead — stop consuming input
                 # (one scalar fetch per batch, only when a limit member
                 # exists; the unfused LimitExec pays the same sync)
-                if exhaust_idx and all(int(carries[i]) <= 0
-                                       for i in exhaust_idx):
+                if exhaust_idx and all(int(c) <= 0 for c in host_planes(
+                        [carries[i] for i in exhaust_idx], "count")):
                     return
 
     return FusedStageExec

@@ -30,7 +30,7 @@ from spark_rapids_tpu import config as C
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import (
     ColumnVector, ColumnarBatch, LazyRowCount, carry_host_stats, from_arrow,
-    to_arrow, round_capacity, rows_int, traced_rows,
+    host_int, host_planes, to_arrow, round_capacity, rows_int, traced_rows,
 )
 from spark_rapids_tpu.exec import compiled
 from spark_rapids_tpu.exec import cpu_backend as CPU
@@ -734,8 +734,7 @@ def _attach_column_stats(batch: ColumnarBatch) -> None:
         pending.extend([lo, hi])
     if not idxs:
         return
-    with device_wait():
-        vals = jax.device_get(pending)
+    vals = host_planes(pending, "range")
     for j, i in enumerate(idxs):
         lo, hi = int(vals[2 * j]), int(vals[2 * j + 1])
         if lo <= hi:
@@ -1566,7 +1565,7 @@ class TopNExec(TpuExec):
                 # waiting for an idle device to begin
                 from spark_rapids_tpu.runtime.pipeline import start_d2h
                 start_d2h(cnt_d)
-                cnt = int(cnt_d)
+                cnt = host_int(cnt_d, "size")
                 if cnt <= bound:
                     out_cap = round_capacity(bound)
 
@@ -1676,7 +1675,7 @@ class SortExec(TpuExec):
                     per_col.append((k[: int(b.num_rows)],
                                     nulls[: int(b.num_rows)]))
             key_planes.append(per_col)
-            tables.append(to_arrow(b, names))  # stages the data off-device
+            tables.append(to_arrow(b, names, "sort"))  # stages the data off-device
         keys = []
         pi = 0
         for o, w in zip(self.plan.orders, widths):
@@ -1687,8 +1686,7 @@ class SortExec(TpuExec):
                 pi += 1
         n = int(keys[0][0].shape[0])
         perm_d = K.lexsort_indices(keys, n)
-        with device_wait():
-            perm = np.asarray(perm_d)[:n]
+        perm = host_planes(perm_d, "sort")[:n]
         table = pa.concat_tables(tables) if len(tables) > 1 else tables[0]
         sorted_table = table.take(perm)
         step = self.conf.get(C.MAX_READER_BATCH_SIZE_ROWS)
@@ -1747,8 +1745,7 @@ def _probe_key_ranges(key_cols, live, key_exprs=None):
     probe = fuse.fused(("radix_probe", tuple(kinds)),
                        lambda: R.probe_ranges)
     ranges = probe(key_cols, live)
-    with device_wait():
-        return ranges, np.asarray(jax.device_get(ranges))
+    return ranges, host_planes(ranges, "range")
 
 
 def _probe_pack_spec(key_cols, live, key_exprs=None):
@@ -3807,7 +3804,8 @@ class ExchangeExec(TpuExec):
                     label=f"{self.name()}@p{p}",
                     stall_metric=self.metrics.metric(M.PIPELINE_STALL_TIME),
                     producer_metric=self.metrics.metric(
-                        M.PIPELINE_PRODUCER_TIME)))
+                        M.PIPELINE_PRODUCER_TIME),
+                    stalls_metric=self.metrics.metric(M.PIPELINE_STALLS)))
         except Exception:  # noqa: BLE001 - setup fallback: synchronous
             for s in streams:
                 s.close()
@@ -3863,8 +3861,9 @@ class ExchangeExec(TpuExec):
         disp.add(1)
         LC.check_current()  # per-batch exchange checkpoint: the offsets
         FLT.site("exchange.fetch")  # sync is where a shuffle blocks
-        offsets = np.asarray(jax.device_get(off_dev))
-        fetch.add(1)
+        with device_wait("exchange"):
+            offsets = np.asarray(jax.device_get(off_dev))
+            fetch.add(1)
         for p, sub in enumerate(
                 RP.compact_slices(sorted_b, offsets, self.n_out)):
             if sub is None:
@@ -4455,6 +4454,12 @@ class ShuffleExchangeExec(ExchangeExec):
             out[: a.shape[0]] = a.astype(dt, copy=False)
             return out
 
+        # every plane the packing reads, of every source, in ONE read:
+        # (data or codes, validity) a column, then the live mask
+        host = host_planes([None if b is None else (
+            [(c.data["codes"] if c.is_dict else c.data, c.validity)
+             for c in b.columns], b.live_mask()) for b in batches],
+            "exchange")
         planes = {}
         per_col_meta = []
         for ci, c in enumerate(schema_cols):
@@ -4462,30 +4467,27 @@ class ShuffleExchangeExec(ExchangeExec):
             if c.is_dict:
                 per_col_meta.append(("dict", c.dtype, c.data["dict_offsets"],
                                      c.data["dict_bytes"], c.dict_unique))
-                shards = [pad_plane(b.columns[ci].data["codes"], 0, np.int32)
-                          if b is not None else np.zeros(cap, np.int32)
-                          for b in batches]
+                dt = np.dtype(np.int32)
             else:
                 dt = np.dtype(c.data.dtype)
                 per_col_meta.append(("fixed", c.dtype, None, None, True))
-                shards = [pad_plane(b.columns[ci].data, 0, dt)
-                          if b is not None else np.zeros(cap, dt)
-                          for b in batches]
-            planes[key] = np.concatenate(shards)
+            planes[key] = np.concatenate([
+                pad_plane(h[0][ci][0], 0, dt) if h is not None
+                else np.zeros(cap, dt) for h in host])
             vshards = []
-            for b in batches:
+            for b, h in zip(batches, host):
                 if b is None:
                     vshards.append(np.zeros(cap, np.bool_))
                 else:
-                    col = b.columns[ci]
-                    v = col.validity if col.validity is not None else \
-                        (np.arange(col.capacity) <
-                         int(traced_rows(b.num_rows)))
+                    v = h[0][ci][1]
+                    if v is None:
+                        v = np.arange(b.columns[ci].capacity) < \
+                            int(traced_rows(b.num_rows))
                     vshards.append(pad_plane(v, False, np.bool_))
             planes[key + "_v"] = np.concatenate(vshards)
         live = np.concatenate([
-            pad_plane(b.live_mask(), False, np.bool_) if b is not None
-            else np.zeros(cap, np.bool_) for b in batches])
+            pad_plane(h[1], False, np.bool_) if h is not None
+            else np.zeros(cap, np.bool_) for h in host])
 
         # target partition ids from the key hash, computed globally, plus
         # per-(source, destination) counts for the right-sizing pass.
@@ -4527,7 +4529,8 @@ class ShuffleExchangeExec(ExchangeExec):
             pid_all, counts_dev = hfn(
                 [planes[f"c{ci}"] for ci in range(n_cols)],
                 [planes[f"c{ci}_v"] for ci in range(n_cols)], live)
-            target, counts_host = jax.device_get((pid_all, counts_dev))
+            target, counts_host = host_planes((pid_all, counts_dev),
+                                              "exchange")
             counts_host = np.asarray(counts_host)
         else:
             keys = self.keys
@@ -4550,18 +4553,15 @@ class ShuffleExchangeExec(ExchangeExec):
             sfn = fuse.fused(
                 ("ici_hash_src", n,
                  tuple(e.fingerprint() for e in keys)), _build_src_hash)
-            tgt_parts = []
-            count_parts = []
-            for b in batches:
-                if b is None:
-                    tgt_parts.append(np.zeros(cap, np.int32))
-                    count_parts.append(jnp.zeros(n, jnp.int32))
-                    continue
-                pid, counts = sfn(b)
-                count_parts.append(counts)
-                tgt_parts.append(pad_plane(pid, 0, np.int32))
-            target = np.concatenate(tgt_parts)
-            counts_host = np.asarray(jax.device_get(jnp.stack(count_parts)))
+            # every source's ids and lane counts in ONE read
+            pid_counts = host_planes([None if b is None else sfn(b)
+                                      for b in batches], "exchange")
+            target = np.concatenate([
+                np.zeros(cap, np.int32) if pc is None
+                else pad_plane(pc[0], 0, np.int32) for pc in pid_counts])
+            counts_host = np.stack([
+                np.zeros(n, np.int32) if pc is None else np.asarray(pc[1])
+                for pc in pid_counts])
         # ONE host fetch sizes the send lanes: C = max rows any source
         # sends any destination, rounded to a capacity bucket — the ICI
         # collective then moves ~rows/P per lane instead of the whole
@@ -4608,7 +4608,7 @@ class ShuffleExchangeExec(ExchangeExec):
         # keep the host numpy views — consumers feed them into jitted
         # kernels (which accept numpy) or host packers, and re-uploading
         # each of the n*n*planes slices measured ~0.15ms apiece.
-        out_planes, out_live = jax.device_get((out_planes, out_live))
+        out_planes, out_live = host_planes((out_planes, out_live), "exchange")
         out: List[List[ColumnarBatch]] = []
         shard_rows = n * send_cap  # each device receives n*send_cap slots
         for p in range(n):
@@ -4841,7 +4841,7 @@ class RangeExchangeExec(ExchangeExec):
                     planes, live = keyfn(batch)
                     per_batch.append((batch, planes))
                     # tpulint: disable=TPU-L004 range bounds need the sample values on host before the slicing kernels can be BUILT — there is no later point to consume a deferred fetch
-                    host = jax.device_get(list(planes) + [live])
+                    host = host_planes(list(planes) + [live], "exchange")
                     lv = host[-1]
                     idx = np.flatnonzero(lv)
                     if len(idx) > budget:
@@ -5774,7 +5774,7 @@ class CpuFallbackExec(TpuExec):
         for p in range(child.num_partitions):
             with TaskContext(partition_id=p) as tctx:
                 for batch in child.execute_partition(tctx, p):
-                    tables.append(to_arrow(batch, child.schema.names))
+                    tables.append(to_arrow(batch, child.schema.names, "host"))
         if not tables:
             import pyarrow as pa
             fields = [pa.field(f.name, T.to_arrow(f.dtype))

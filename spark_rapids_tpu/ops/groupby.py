@@ -50,7 +50,7 @@ def group_segments(key_cols: List[ColumnVector], num_rows: int, live=None
 
 
 def num_groups(boundary: jax.Array) -> int:
-    return host_int(jnp.sum(boundary.astype(jnp.int32)))
+    return host_int(jnp.sum(boundary.astype(jnp.int32)), "size")
 
 
 def _float_minmax_prep(op: str, values: jax.Array, valid: jax.Array):

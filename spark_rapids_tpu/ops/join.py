@@ -29,9 +29,8 @@ import jax
 import jax.numpy as jnp
 
 from spark_rapids_tpu.columnar.batch import (
-    ColumnVector, host_int, round_capacity,
+    ColumnVector, host_int, host_planes, round_capacity,
 )
-from spark_rapids_tpu.runtime.obs.phases import device_wait
 from spark_rapids_tpu.ops import kernels as K
 from spark_rapids_tpu.runtime import compile_cache as _cc
 
@@ -114,14 +113,13 @@ def prepare_dense_build(build_keys: List[ColumnVector], build_rows: int,
     bmin_d = jnp.min(jnp.where(b_in, bv, jnp.int64(2**62)))
     bmax_d = jnp.max(jnp.where(b_in, bv, jnp.int64(-2**62)))
     nbuild_d = jnp.sum(b_in.astype(jnp.int32))
-    with device_wait():
-        bmin, bmax, nbuild = (int(x) for x in
-                              jax.device_get([bmin_d, bmax_d, nbuild_d]))
+    bmin, bmax, nbuild = (int(x) for x in host_planes(
+        [bmin_d, bmax_d, nbuild_d], "range"))
     span = bmax - bmin + 1
     if nbuild <= 0 or not (0 < span <= DENSE_KEY_RANGE_LIMIT):
         return None
     starts, cnt_max = _dense_counts(bv, b_in, bcap, jnp.int64(bmin), span)
-    max_dup = host_int(cnt_max)
+    max_dup = host_int(cnt_max, "size")
     # the rows in key order: a placement when the keys are unique (the
     # star-schema shape: no sort, which the TPU compiler takes half a
     # minute over at a dimension's size), else the stable sort by key
@@ -209,7 +207,7 @@ def join_pairs(build_keys: List[ColumnVector], build_rows: int,
     sorted_orig = jnp.where(bidx >= 0, bidx, -1)[order]
 
     lo, hi = _merge_rank_ranges(sorted_h, bcount, ph, p_in)
-    total = host_int(jnp.sum((hi - lo).astype(jnp.int64)))
+    total = host_int(jnp.sum((hi - lo).astype(jnp.int64)), "size")
 
     probe_i, build_pos = K.expand_ranges(lo, hi, total)
     build_i = jnp.where(build_pos >= 0,
@@ -280,7 +278,7 @@ def _dense_int_pairs(table: DenseBuildTable, pv, p_in, pcap):
         out_b = jnp.where(idx >= 0,
                           sorted_orig[jnp.clip(bpos, 0, bcap - 1)], -1)
         return out_p, out_b, match_count
-    total = host_int(jnp.sum(counts.astype(jnp.int64)))
+    total = host_int(jnp.sum(counts.astype(jnp.int64)), "size")
     probe_i, build_pos = K.expand_ranges(lo, hi, total)
     build_i = jnp.where(build_pos >= 0,
                         sorted_orig[jnp.clip(build_pos, 0, bcap - 1)], -1)

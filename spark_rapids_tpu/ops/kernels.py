@@ -26,7 +26,8 @@ from jax import lax
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import (
     ColumnVector, ColumnarBatch, LazyRowCount, carry_host_stats, host_int,
-    materialize_counts, max_entry_len, round_capacity, traced_rows,
+    host_planes, materialize_counts, max_entry_len, round_capacity,
+    traced_rows,
 )
 from spark_rapids_tpu.ops.pallas_decode import _cumsum
 from spark_rapids_tpu.runtime import compile_cache as _cc
@@ -345,7 +346,7 @@ def string_chunk_count(col: ColumnVector) -> int:
     if n is not None:
         return n
     off = col.data["dict_offsets"] if col.is_dict else col.data["offsets"]
-    return _chunks_for(host_int(_max_entry_len(off)))
+    return _chunks_for(host_int(_max_entry_len(off), "width"))
 
 
 def string_chunk_keys(col: ColumnVector, num_rows: int, n_chunks: int,
@@ -793,7 +794,7 @@ def _compact_indices(mask: jax.Array, num_rows, out_cap: int) -> jax.Array:
 def filter_indices(mask: jax.Array, num_rows: int) -> Tuple[jax.Array, int]:
     """mask: bool[capacity]. One device->host scalar readback for the count
     (the price of a dynamic result size; paid per batch, not per element)."""
-    count = int(_count_true(mask, num_rows))
+    count = host_int(_count_true(mask, num_rows), "size")
     out_cap = round_capacity(max(count, 1))
     return _compact_indices(mask, num_rows, out_cap), count
 
@@ -885,7 +886,7 @@ def flatten_dict_column(col: ColumnVector, num_rows) -> ColumnVector:
                                jnp.cumsum(lens).astype(jnp.int32)])
     import jax.core as _core
     if isinstance(new_off, jax.Array) and not isinstance(new_off, _core.Tracer):
-        out_cap = round_capacity(max(int(new_off[-1]), 1))
+        out_cap = round_capacity(max(host_int(new_off[-1], "width"), 1))
     else:
         _cc.note_traced("dict_flattens_traced")
         out_cap = cap * int(vraw.shape[0])
@@ -926,18 +927,44 @@ def concat_batches(batches: List[ColumnarBatch]) -> ColumnarBatch:
         # gather, no per-row work. Capacity grows to the sum of inputs; the
         # consumer (or an explicit compact) shrinks when worthwhile.
         mask = jnp.concatenate([b.live_mask() for b in nonempty])
-        out_cols = []
-        for ci in range(nonempty[0].num_cols):
-            cols = [b.columns[ci] for b in nonempty]
-            caps = [b.capacity for b in nonempty]
-            out_cols.append(_concat_columns(cols, caps, sum(caps)))
-        return ColumnarBatch(out_cols, total, mask)
-    out_cols = []
-    for ci in range(nonempty[0].num_cols):
-        cols = [b.columns[ci] for b in nonempty]
+        rows = [b.capacity for b in nonempty]
+        cap = sum(rows)
+    else:
+        mask = None
         rows = [int(b.num_rows) for b in nonempty]
-        out_cols.append(_concat_columns(cols, rows, round_capacity(total)))
-    return ColumnarBatch(out_cols, total)
+        cap = round_capacity(total)
+    by_col = [[b.columns[ci] for b in nonempty]
+              for ci in range(nonempty[0].num_cols)]
+    ends = _offset_ends(by_col, rows)
+    out_cols = [_concat_columns(cols, rows, cap, ends.get(ci))
+                for ci, cols in enumerate(by_col)]
+    return ColumnarBatch(out_cols, total, mask)
+
+
+def _ends_of(cols: List[ColumnVector]) -> bool:
+    """Does concatenating these parts need each part's offsets[rows]
+    from the host: a flat string column, or an array or map column."""
+    dtype = cols[0].dtype
+    if isinstance(dtype, T.StringType):
+        return not any(c.is_dict for c in cols)
+    return isinstance(dtype, (T.ArrayType, T.MapType))
+
+
+def _read_ends(by_col, rows) -> List[List[int]]:
+    """offsets[r] of every part of every column in `by_col`: ONE read."""
+    ends = host_planes([[c.data["offsets"][r] for c, r in zip(cols, rows)]
+                        for cols in by_col], "width")
+    return [[int(e) for e in col_ends] for col_ends in ends]
+
+
+def _offset_ends(by_col, rows) -> dict:
+    """{column index: each part's offsets[r]} for the columns of a concat
+    whose destination offsets are static (flat strings, arrays, maps),
+    read together: one sync for all of them, not one a column."""
+    want = [ci for ci, cols in enumerate(by_col) if _ends_of(cols)]
+    if not want:
+        return {}
+    return dict(zip(want, _read_ends([by_col[ci] for ci in want], rows)))
 
 
 def _on_host(plane) -> bool:
@@ -1037,7 +1064,7 @@ def unify_vocabs(cols: List[ColumnVector]):
     vocab_planes = []
     for c in cols:
         vocab_planes.extend([c.data["dict_offsets"], c.data["dict_bytes"]])
-    host = jax.device_get(vocab_planes)
+    host = host_planes(vocab_planes, "vocab")
     union: dict = {}
     remaps = []
     for i in range(len(cols)):
@@ -1084,7 +1111,11 @@ def _lay_at(plane: jax.Array, part: jax.Array, at) -> jax.Array:
     return jax.lax.dynamic_update_slice(plane, part, (at,))
 
 
-def _concat_columns(cols: List[ColumnVector], rows: List[int], cap: int) -> ColumnVector:
+def _concat_columns(cols: List[ColumnVector], rows: List[int], cap: int,
+                    ends: Optional[List[int]] = None) -> ColumnVector:
+    """The parts' live rows as one column of capacity `cap`. `ends`: each
+    part's offsets[r] where the caller read them already (concat_batches
+    reads every column's at once)."""
     dtype = cols[0].dtype
     if any(c.is_dict for c in cols) and not all(c.is_dict for c in cols):
         cols = [flatten_dict_column(c, r) if c.is_dict else c
@@ -1131,8 +1162,8 @@ def _concat_columns(cols: List[ColumnVector], rows: List[int], cap: int) -> Colu
         # Host readback of per-part element counts keeps destination
         # offsets static (same discipline as string concat below); child
         # planes concat recursively, so arrays of strings/structs compose.
-        elem_lens = [int(np.asarray(c.data["offsets"][r]))
-                     for c, r in zip(cols, rows)]
+        elem_lens = ends if ends is not None else \
+            _read_ends([cols], rows)[0]
         total_elems = sum(elem_lens)
         child_cap = round_capacity(max(total_elems, 1))
         off_parts = [jnp.zeros(1, jnp.int32)]
@@ -1155,7 +1186,8 @@ def _concat_columns(cols: List[ColumnVector], rows: List[int], cap: int) -> Colu
     if isinstance(dtype, T.StringType):
         # Host readback of per-part byte lengths keeps destination offsets
         # static; concat happens between batches, off the jitted hot path.
-        byte_lens = [int(np.asarray(c.data["offsets"][r])) for c, r in zip(cols, rows)]
+        byte_lens = ends if ends is not None else \
+            _read_ends([cols], rows)[0]
         total_bytes = sum(byte_lens)
         out_byte_cap = round_capacity(max(total_bytes, 1))
         out_bytes = jnp.zeros(out_byte_cap, jnp.uint8)

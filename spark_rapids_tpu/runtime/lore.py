@@ -76,7 +76,7 @@ class LoreDumper:
             pdir = os.path.join(_dir, f"part{pidx}")
             os.makedirs(pdir, exist_ok=True)
             for batch in _inner(ctx, pidx):
-                pq.write_table(to_arrow(batch, _names),
+                pq.write_table(to_arrow(batch, _names, "host"),
                                os.path.join(pdir, f"batch{seq:04d}.parquet"))
                 seq += 1
                 yield batch

@@ -34,7 +34,7 @@ import jax
 
 from spark_rapids_tpu.analysis import sanitizer as _san
 from spark_rapids_tpu import config as C
-from spark_rapids_tpu.columnar.batch import ColumnarBatch
+from spark_rapids_tpu.columnar.batch import ColumnarBatch, host_planes
 
 DEVICE, HOST, DISK = "device", "host", "disk"
 
@@ -108,7 +108,7 @@ class SpillableHandle:
             if self._tier != DEVICE or self._closed or self._pinned:
                 return 0
             leaves, treedef = jax.tree_util.tree_flatten(self._device)
-            self._host = jax.device_get(leaves)
+            self._host = host_planes(leaves, "host")
             self._treedef = treedef
             self._device = None
             self._tier = HOST

@@ -166,6 +166,9 @@ PIPELINE_DEPTH = "pipelineDepth"
 #: the producer (device starved by host decode — the number a deeper
 #: lookahead or more reader threads would shrink)
 PIPELINE_STALL_TIME = "pipelineStallTime"
+#: times the consumer of a pipeline boundary found the queue empty and
+#: blocked (each one a `pipeline.stall` span of pipelineStallTime)
+PIPELINE_STALLS = "pipelineStalls"
 #: ns the producer side spent decoding/uploading upstream batches on the
 #: host pool — work that overlapped downstream compute instead of
 #: sitting serially in the critical path
@@ -222,13 +225,13 @@ class GpuMetric:
     def value(self) -> int:
         with self._lock:
             if self._deferred:
-                from spark_rapids_tpu.columnar.batch import LazyRowCount
-                import jax as _jax
+                from spark_rapids_tpu.columnar.batch import (
+                    LazyRowCount, host_planes)
                 pending = [v for v in self._deferred
                            if isinstance(v, LazyRowCount) and not v.is_materialized]
                 if pending:  # ONE bulk fetch, not one round trip per count
-                    for lz, val in zip(pending,
-                                       _jax.device_get([p._dev for p in pending])):
+                    vals = host_planes([p._dev for p in pending], "count")
+                    for lz, val in zip(pending, vals):
                         lz._val = int(val)
                 self._value += sum(int(v) for v in self._deferred)
                 self._deferred = []

@@ -61,7 +61,13 @@ the epilogue the account becomes one record in the obs ring
                               once an evaluation, from sizes the host
                               has), join_output_rows (joinOutputRows:
                               rows its hash joins handed on, from counts
-                              the host has)
+                              the host has), host_syncs (the syncs
+                              below, counted), pipeline_stalls
+                              (pipelineStalls: times a scan pipeline's
+                              consumer found its queue empty and waited)
+    syncs                     {site: [count, ns]}: each door of SYNC_SITES
+                              the query's threads waited at, how often and
+                              how long (only the sites that synced)
     mesh                      only when a sharded stage ran: devices (the
                               mesh size) and shard_rows (per stage, the
                               live rows each shard's last body put out,
@@ -69,18 +75,16 @@ the epilogue the account becomes one record in the obs ring
     wall_ms, error_class, finished_unix[, degraded_reason, slo_breach]
                               what ObsState.last_query always carried
 
-`timers_ns.deviceWaitTime` is the host time blocked on the device where
-the engine itself brings a device value to the host (`device_wait()`):
-the three doors of columnar/batch.py, which are a forced LazyRowCount and
-every other scalar a host decision needs (`host_int`: join and group
-sizes, string widths, ANSI error flags), the counts' bulk fetch
-(`materialize_counts`) and a batch's download (`fetch_batch_host`, under
-every `to_arrow`, so under every query.fetch); and the small arrays the
-sort, the radix group-by and the bounds probe read back
-(exec/tpu_nodes.py). Summed over threads. Still outside it, in
-query.execute's host time: the read-backs of the exchange, window and
-partitioning execs (an `np.asarray`/`device_get` inside one exec).
-Process-wide like `keyed_dispatches`.
+A host sync is a `device_wait(site)` block: every device->host read the
+engine makes goes through one, most through columnar/batch.py's
+`host_int` (a scalar) and `host_planes` (many arrays in one read; no sync
+where none is on the device). The block is
+the span `sync.<site>` (ESSENTIAL, category `sync`: a `rapids.sync.<site>`
+annotation while a profiler capture runs, an entry of the flight ring)
+feeding that site's process-wide count and nanoseconds, plain ints
+bumped with no lock like `keyed_dispatches`. `counters.host_syncs` is the
+sum of the record's `syncs` counts and `timers_ns.deviceWaitTime` the sum
+of their nanoseconds, summed over threads.
 
 `timers_ns.*DeviceTime` (joinDeviceTime, aggDeviceTime,
 windowSortDeviceTime, stringMatchDeviceTime) are the DEVICE's time for a step whose span times
@@ -104,8 +108,8 @@ from typing import Dict, Optional
 
 from spark_rapids_tpu.runtime.metrics import (
     AGG_GROUPS, ESSENTIAL, EXCHANGE_BYPASSED, EXPAND_ROWS, JOIN_OUTPUT_ROWS, MESH_PUT_BYTES,
-    NUM_SCAN_COLUMNS, NUM_SCAN_COLUMNS_PRUNED, SHARD_WAVES, STRING_MATCH_BYTES, UPLOAD_BYTES,
-    GpuMetric, walk_exec_tree,
+    NUM_SCAN_COLUMNS, NUM_SCAN_COLUMNS_PRUNED, PIPELINE_STALLS, SHARD_WAVES, STRING_MATCH_BYTES,
+    UPLOAD_BYTES, GpuMetric, _Timer, walk_exec_tree,
 )
 
 #: record counter -> the exec metric summed into it over the exec tree
@@ -116,12 +120,30 @@ COUNTERS = {"upload_bytes": UPLOAD_BYTES, "shard_waves": SHARD_WAVES,
             "scan_columns_pruned": NUM_SCAN_COLUMNS_PRUNED,
             "expand_rows": EXPAND_ROWS,
             "string_match_bytes": STRING_MATCH_BYTES,
-            "join_output_rows": JOIN_OUTPUT_ROWS}
+            "join_output_rows": JOIN_OUTPUT_ROWS,
+            "pipeline_stalls": PIPELINE_STALLS}
 
 #: phase -> the span that times it
 SPANS = {"parse": "sql.parse", "admit": "query.admit",
          "plan": "query.plan", "execute": "query.execute",
          "fetch": "query.fetch", "epilogue": "query.epilogue"}
+
+#: the doors a query's thread waits at for the device (`device_wait`):
+#: site -> what the host reads there
+SYNC_SITES = {
+    "count": "a forced LazyRowCount; the counts' bulk fetch",
+    "size": "join, group, compaction and rollup output sizes",
+    "width": "string widths and byte lengths",
+    "flags": "ANSI error planes",
+    "range": "key ranges: a join's, the radix probe's, column bounds",
+    "sort": "a host-side sort's permutation",
+    "shard": "a sharded wave's output (MeshWave.read_back)",
+    "exchange": "partition offsets and counts, exchanged planes",
+    "vocab": "string vocabularies unified on the host",
+    "host": "a batch taken whole to the host mid-query: a CPU fallback, "
+            "a spill, a LORE dump",
+    "fetch": "the result's download (query.fetch)",
+}
 
 #: records the obs ring keeps (the newest top-level actions)
 RING_SIZE = 256
@@ -132,9 +154,28 @@ RING_SIZE = 256
 keyed_dispatches = 0
 
 
-#: host nanoseconds inside device_wait() blocks, process-wide and
-#: monotonic like keyed_dispatches
-device_wait_ns = 0
+class _Site:
+    """One door's process-wide account, monotonic: syncs and the host
+    nanoseconds blocked in them. It is the metric of the door's span
+    (`add` is what the span's exit, or the bare timer, calls)."""
+
+    __slots__ = ("span", "count", "wait_ns")
+
+    def __init__(self, site: str):
+        self.span = "sync." + site
+        self.count = 0
+        self.wait_ns = 0
+
+    def add(self, ns: int) -> None:
+        self.count += 1
+        self.wait_ns += ns
+
+    def ns(self):
+        return _Timer(self)
+
+
+#: site -> its account
+SYNCS = {site: _Site(site) for site in SYNC_SITES}
 
 
 class _Marks(threading.local):
@@ -178,23 +219,35 @@ def _read_marks() -> None:
 
 
 class _DeviceWait:
-    """One block in which the host waits for a device value."""
+    """One block in which the host waits for a device value: the span of
+    its site, with the thread's device marks read first inside it."""
 
-    __slots__ = ("t0",)
+    __slots__ = ("span",)
+
+    def __init__(self, span):
+        self.span = span
 
     def __enter__(self):
-        self.t0 = time.perf_counter_ns()
+        self.span.__enter__()
         if _marks.pending:
             _read_marks()
 
     def __exit__(self, *exc):
-        global device_wait_ns
-        device_wait_ns += time.perf_counter_ns() - self.t0
-        return False
+        return self.span.__exit__(*exc)
 
 
-def device_wait() -> _DeviceWait:
-    return _DeviceWait()
+def device_wait(site: str) -> _DeviceWait:
+    """THE door for a device->host read: `with device_wait("size"):
+    n = int(dev)`. `site` is a key of SYNC_SITES."""
+    from spark_rapids_tpu.runtime import trace as TR
+    acct = SYNCS[site]
+    return _DeviceWait(TR.metric_span(acct.span, acct, cat="sync",
+                                      level=ESSENTIAL))
+
+
+def syncs_now() -> Dict[str, tuple]:
+    """{site: (count, ns)} so far, process-wide."""
+    return {s: (a.count, a.wait_ns) for s, a in SYNCS.items()}
 
 
 def clock(phase: str) -> GpuMetric:
@@ -210,7 +263,7 @@ def span(phase: str, clk: GpuMetric):
 class QueryPhases:
     """One top-level action's account (module docstring)."""
 
-    __slots__ = ("t0_ns", "parse_ns", "clocks", "dispatches0", "wait0",
+    __slots__ = ("t0_ns", "parse_ns", "clocks", "dispatches0", "syncs0",
                  "exec_root", "_peeked", "_shard_waves")
 
     def __init__(self, plan):
@@ -218,7 +271,7 @@ class QueryPhases:
         self.parse_ns = int(getattr(plan, "_sql_parse_ns", 0))
         self.clocks = {p: clock(p) for p in SPANS if p != "parse"}
         self.dispatches0 = keyed_dispatches
-        self.wait0 = device_wait_ns
+        self.syncs0 = syncs_now()
         self.exec_root = None
         self._peeked: Optional[Dict[str, dict]] = None
         self._shard_waves: list = []  # exec/sharded.MeshWave, by the walk
@@ -271,13 +324,19 @@ class QueryPhases:
                                          snap.get(AGG_GROUPS, 0))
         for bucket, ns in (extra or {}).items():
             timers[bucket] = timers.get(bucket, 0) + int(ns)
-        timers["deviceWaitTime"] = device_wait_ns - self.wait0
+        syncs = {}
+        for site, (n, ns) in syncs_now().items():
+            n0, ns0 = self.syncs0[site]
+            if n != n0:
+                syncs[site] = [n - n0, ns - ns0]
+        counters["host_syncs"] = sum(n for n, _ in syncs.values())
+        timers["deviceWaitTime"] = sum(ns for _, ns in syncs.values())
         rec = {
             "seq": None,  # set by the ring
             "query_id": query_id, "status": status,
             "t0_ns": self.t0_ns, "wall_ns": wall_ns,
             "phases_ns": phases, "timers_ns": timers,
-            "counters": counters,
+            "counters": counters, "syncs": syncs,
             "wall_ms": round(duration_ns / 1e6, 3),
             "error_class": type(error).__name__ if error else None,
             "finished_unix": time.time(),

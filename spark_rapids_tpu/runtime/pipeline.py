@@ -126,13 +126,15 @@ class PipelinedIterator:
 
     def __init__(self, source: Iterator, depth: int, ctx=None,
                  conf=None, label: str = "pipeline",
-                 stall_metric=None, producer_metric=None):
+                 stall_metric=None, producer_metric=None,
+                 stalls_metric=None):
         from spark_rapids_tpu.runtime.host_pool import get_host_pool
         self._source = source
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1, int(depth)))
         self._ctx = ctx
         self._label = label
         self._stall = stall_metric
+        self._stalls = stalls_metric
         self._prod = producer_metric
         from spark_rapids_tpu.analysis import sanitizer as _san
         from spark_rapids_tpu.runtime.obs import live as _live
@@ -266,20 +268,25 @@ class PipelinedIterator:
     # -- consumer side -----------------------------------------------------
 
     def __iter__(self):
+        from spark_rapids_tpu.runtime import trace
         while True:
             self._ensure_refill()
             try:
                 item = self._q.get_nowait()
             except queue.Empty:
-                t0 = time.perf_counter_ns()
-                _stall_enter()
-                try:
-                    item = self._q.get()
-                finally:
-                    _stall_exit()
-                dt = time.perf_counter_ns() - t0
-                if self._stall is not None:
-                    self._stall.add(dt)
+                # the consumer's wait: a span on the query's thread,
+                # timed into the stall metric and counted
+                with trace.metric_span("pipeline.stall", self._stall,
+                                       cat="pipeline",
+                                       args={"label": self._label},
+                                       level=trace.ESSENTIAL):
+                    _stall_enter()
+                    try:
+                        item = self._q.get()
+                    finally:
+                        _stall_exit()
+                if self._stalls is not None:
+                    self._stalls.add(1)
             if item is _DONE:
                 return
             if isinstance(item, _ProducerError):
@@ -381,7 +388,8 @@ def make_pipeline_exec():
                     label=f"{type(self.children[0]).__name__}@p{pidx}",
                     stall_metric=self.metrics.metric(M.PIPELINE_STALL_TIME),
                     producer_metric=self.metrics.metric(
-                        M.PIPELINE_PRODUCER_TIME))
+                        M.PIPELINE_PRODUCER_TIME),
+                    stalls_metric=self.metrics.metric(M.PIPELINE_STALLS))
             except QueryCancelledError:
                 # a cancelled query's unwind is not a setup failure:
                 # running the stage synchronously would resurrect the

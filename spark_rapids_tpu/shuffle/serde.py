@@ -398,7 +398,7 @@ def serialize_batch(batch: ColumnarBatch, codec: str = "auto") -> bytes:
     from spark_rapids_tpu.columnar.batch import fetch_batch_host
     if batch.row_mask is not None:
         batch = K.compact_batch(batch)
-    host = fetch_batch_host(batch)
+    host = fetch_batch_host(batch, "exchange")
     n = int(host.num_rows)
     planes: List[np.ndarray] = []
     cols = [_describe_column(c, n, planes) for c in host.columns]

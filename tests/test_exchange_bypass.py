@@ -136,9 +136,9 @@ def test_host_concat_lays_live_rows_in_source_order():
         # a source with no rows
         _host_batch([0, 0], [99, 99], vocab, mask=[False, False]),
         _host_batch([2], [30], vocab)]
-    w0 = phases.device_wait_ns
+    w0 = phases.syncs_now()
     out = K.concat_host_batches(parts, limit=5)
-    assert phases.device_wait_ns == w0
+    assert phases.syncs_now() == w0
     assert out.num_rows == 5 and out.row_mask is None
     assert _rows(out) == [{"s": "a", "v": 10}, {"s": "bb", "v": 11},
                           {"s": "bb", "v": 20}, {"s": None, "v": 21},
@@ -203,10 +203,10 @@ def test_host_concat_declines_what_would_cost_a_sync_or_a_program(why):
     assert K.concat_host_batches([a, b], 4).num_rows == 4  # within reach
     spoiled = _SPOILED.get(why, lambda b: b)(b)
     parts = [] if why == "no_batches" else [a, spoiled]
-    w0 = phases.device_wait_ns
+    w0 = phases.syncs_now()
     assert K.concat_host_batches(
         parts, 3 if why == "over_the_limit" else 4) is None
-    assert phases.device_wait_ns == w0
+    assert phases.syncs_now() == w0
     if why == "a_lazy_count":
         assert not spoiled.num_rows.is_materialized
 
@@ -354,9 +354,9 @@ def test_a_lazy_count_never_bypasses_and_nothing_waits():
     assert "bypassed" not in sess._last_exec.tree_string()
     # the same exchange handed a host batch but for its count
     lazy = _lazy(_host_batch([0, 1], [1, 2], _vocab()))
-    w0 = phases.device_wait_ns
+    w0 = phases.syncs_now()
     assert ex._bypass([[lazy], [], [], []]) is None
-    assert phases.device_wait_ns == w0
+    assert phases.syncs_now() == w0
     assert not lazy.num_rows.is_materialized
     # and a live stream of batches, which cannot be looked at twice
     host = _host_batch([0, 1], [1, 2], _vocab())

@@ -677,7 +677,8 @@ def test_recent_queries_one_record_per_top_level_action():
                                       "scan_columns_pruned",
                                       "expand_rows", "agg_groups",
                                       "string_match_bytes",
-                                      "join_output_rows"}
+                                      "join_output_rows", "host_syncs",
+                                      "pipeline_stalls"}
         assert r["counters"]["scan_columns_read"] == 0  # no Parquet scan
         assert "mesh" not in r  # no sharded stage ran
         assert r["counters"]["upload_bytes"] > 0  # the in-memory scan's
@@ -691,7 +692,7 @@ def test_recent_queries_one_record_per_top_level_action():
 def test_device_wait_is_the_time_in_the_download_doors():
     """timers_ns.deviceWaitTime: a scalar a host decision needs (a forced
     LazyRowCount among them), the counts' bulk fetch, a batch's download
-    (so the one inside every query.fetch)."""
+    (so the one inside every query.fetch), each door at its own site."""
     import jax.numpy as jnp
     from spark_rapids_tpu.columnar.batch import (
         ColumnarBatch, LazyRowCount, fetch_batch_host, materialize_counts)
@@ -704,20 +705,28 @@ def test_device_wait_is_the_time_in_the_download_doors():
     assert 0 < wait <= p["execute"]  # one partition: one thread
 
     def grows(door):
-        before = phases.device_wait_ns
+        """{site: (syncs, ns)} the door added"""
+        before = phases.syncs_now()
         door()
-        return phases.device_wait_ns - before
+        return {site: (n - before[site][0], ns - before[site][1])
+                for site, (n, ns) in phases.syncs_now().items()
+                if n != before[site][0]}
+
+    def one_at(site, door):
+        got = grows(door)
+        return list(got) == [site] and got[site][0] == 1 \
+            and got[site][1] > 0
 
     from spark_rapids_tpu.columnar.batch import host_int
-    assert grows(lambda: host_int(jnp.asarray(9))) > 0
+    assert one_at("size", lambda: host_int(jnp.asarray(9), "size"))
     n = LazyRowCount(jnp.asarray(7))
-    assert grows(lambda: int(n)) > 0 and int(n) == 7
-    assert grows(lambda: int(n)) == 0  # forced once
+    assert one_at("count", lambda: int(n)) and int(n) == 7
+    assert grows(lambda: int(n)) == {}  # forced once
     lazy = ColumnarBatch([], LazyRowCount(jnp.asarray(5)))
-    assert grows(lambda: materialize_counts([lazy])) > 0
-    assert grows(lambda: materialize_counts([lazy])) == 0
-    assert grows(lambda: fetch_batch_host(
-        ColumnarBatch([], LazyRowCount(jnp.asarray(3))))) > 0
+    assert one_at("count", lambda: materialize_counts([lazy]))
+    assert grows(lambda: materialize_counts([lazy])) == {}
+    assert one_at("fetch", lambda: fetch_batch_host(
+        ColumnarBatch([], LazyRowCount(jnp.asarray(3))), "fetch"))
 
 
 def test_a_breaching_query_says_so_in_its_record():

@@ -260,20 +260,20 @@ def test_a_device_mark_is_read_at_the_next_wait_that_exists(monkeypatch):
     PH._marks.pending += [(a, Out(50), 10),    # enqueued at 10, reached at 150
                           (b, Out(30), 20),    # behind a: 150 to 180
                           (a, Out(0), 30)]     # passed: its moment is gone
-    with PH.device_wait():
+    with PH.device_wait("size"):
         Clock.now += 5
     assert (a.peek(), b.peek()) == (140, 30)
     assert PH._marks.pending == [] and PH._marks.reached_ns == 180
     Clock.now = 300
     PH._marks.pending.append((b, Out(7), 290))  # enqueued after the last
-    with PH.device_wait():
+    with PH.device_wait("size"):
         pass
     assert b.peek() == 30 + 17
     x = jnp.arange(4) + 1
     for _ in range(3 * PH._MARKS_KEPT):
         PH.device_mark(a, x, 0)
     assert len(PH._marks.pending) == PH._MARKS_KEPT
-    with PH.device_wait():
+    with PH.device_wait("size"):
         pass
     assert PH._marks.pending == []
 

@@ -183,9 +183,9 @@ def test_row_count_rides_through(table, count):
         b = ColumnarBatch(b.columns,
                           LazyRowCount(jnp.sum(mask.astype(jnp.int32))), mask)
         rows = [r for r in rows if r["rid"] % 3 == 1]
-    wait0 = phases.device_wait_ns
+    wait0 = phases.syncs_now()
     out = N._sort_in_core(_bound(spec, _schema_of(table)), b)
-    assert phases.device_wait_ns == wait0
+    assert phases.syncs_now() == wait0
     assert out.row_mask is None and out.capacity == b.capacity
     if count == "host":
         assert out.num_rows == N_ROWS and isinstance(out.num_rows, int)
@@ -220,17 +220,17 @@ def test_q1_shape_is_one_keyed_dispatch_and_no_read_back(monkeypatch):
     spec = [("l_returnflag", True, True), ("l_linestatus", True, True)]
     orders = _bound(spec, _schema_of(t))
 
-    def no_read_back(dev):
+    def no_read_back(dev, site):
         raise AssertionError("host_int on the sort's path")
     monkeypatch.setattr(K, "host_int", no_read_back)
     monkeypatch.setattr(B, "host_int", no_read_back)
     keys = []
     fuse.set_dispatch_hook(keys.append)
     try:
-        n0, wait0 = phases.keyed_dispatches, phases.device_wait_ns
+        n0, wait0 = phases.keyed_dispatches, phases.syncs_now()
         out = N._sort_in_core(orders, b)
         assert phases.keyed_dispatches == n0 + 1
-        assert phases.device_wait_ns == wait0
+        assert phases.syncs_now() == wait0
     finally:
         fuse.set_dispatch_hook(None)
     assert len(keys) == 1 and keys[0][0] == "sort"
@@ -266,7 +266,7 @@ def test_string_width_comes_from_the_host(case, monkeypatch):
     calls = []
     real = K.host_int
     monkeypatch.setattr(K, "host_int",
-                        lambda d: calls.append(1) or real(d))
+                        lambda d, site: calls.append(site) or real(d, site))
     if case == "stamp_dict":
         c = from_arrow(pa.table({"s": long_words})).columns[0]
         assert c.is_dict and c.str_width == 17
@@ -294,7 +294,7 @@ def test_string_width_comes_from_the_host(case, monkeypatch):
         c = from_arrow(pa.table({"s": long_words})).columns[0]
         c.str_width = None
         assert K.static_string_chunks(c) is None
-        assert K.string_chunk_count(c) == 4 and calls == [1]
+        assert K.string_chunk_count(c) == 4 and calls == ["width"]
         return
     assert K.static_string_chunks(c) == want
     assert K.string_chunk_count(c) == want and not calls

@@ -35,7 +35,7 @@ the per-query phase account (runtime/obs/phases.py) adds to every query
 beside its spans, timed in a loop over the finished query's exec tree:
 the account's clocks, the one peek walk, the attribution it now feeds
 for every query with its /metrics counters, the record and the ring, and
-the device_wait() blocks of the query. It uses the public entry points
+the device_wait(site) blocks of the query. It uses the public entry points
 only, so a copy of this file under the parent's tools/ gives the figure
 before a change to the instrumentation (where the package has no
 account, that part reads 0):
@@ -209,8 +209,12 @@ def query_report(rows: int, iters: int = 100_000) -> dict:
                                       labels={"phase": phase}).inc(secs)
             obs.publish_query_record(ph.record(1, "ok", wall_ns))
 
+        # the door takes its site since PR 38; a parent's takes none
+        door = (lambda: PH.device_wait("count")) \
+            if hasattr(PH, "SYNC_SITES") else PH.device_wait
+
         def wait():
-            with PH.device_wait():
+            with door():
                 pass
 
         enter = PH._DeviceWait.__enter__
