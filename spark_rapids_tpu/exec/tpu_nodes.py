@@ -2516,12 +2516,46 @@ class _AggKernels:
             if c.validity is not None:
                 code = jnp.where(c.validity, code, null_code)
             bucket = bucket * s + jnp.clip(code, 0, null_code)
-        if B <= self._MATMUL_LIMIT:
-            # keep the whole tiny-B path scatter-FREE: XLA fuses all the
-            # per-bucket masked reductions (occupancy + every agg state)
-            # into a handful of passes over the shared input planes; one
-            # scatter in the middle splits that fusion island and was
-            # measured to cost ~8x on a 30M-row q1 shape
+        states = []
+        # one (values, valid) pair an input expression and state dtype:
+        # avg(x) beside sum(x) hands the digit path the SAME planes, which
+        # it shares by identity (each evaluation traced its own arrays)
+        planes = {}
+        for ai, a in enumerate(self.aggs):
+            for (sname, sdt), (op, idx) in zip(a.fn.state_schema(), a.fn.update_ops()):
+                if idx >= 0:
+                    src = input_cols[ai][idx]
+                    if (src.is_string or src.is_nested) and \
+                            op not in ("count", "count_all"):
+                        raise NotImplementedError(
+                            "string agg state on device")
+                    key = (a.fn.children[idx].fingerprint(),
+                           np.dtype(sdt.np_dtype).str)
+                    if key not in planes:
+                        vals = src.data \
+                            if not (src.is_string or src.is_nested) \
+                            else jnp.zeros(batch.capacity, sdt.np_dtype)
+                        vals = vals.astype(sdt.np_dtype) if vals.dtype != sdt.np_dtype else vals
+                        planes[key] = (vals, live if src.validity is None
+                                       else (src.validity & live))
+                    vals, valid = planes[key]
+                else:
+                    vals = jnp.zeros(batch.capacity, sdt.np_dtype)
+                    valid = live
+                states.append((op, vals, valid, sdt))
+        from spark_rapids_tpu.ops import pallas_kernels as PK
+        digit_ok = B <= self._MATMUL_LIMIT and PK.enabled() and all(
+            op in G.DIGIT_OPS and (op != "sum" or np.dtype(sdt.np_dtype) in (
+                np.dtype(np.float64), np.dtype(np.float32)))
+            for op, _v, _valid, sdt in states)
+        if digit_ok:
+            # every state one exact-digit MXU contraction, occupancy
+            # from its live-count lane: one pass over the planes
+            outs, occupancy = G.bucket_digit_agg(
+                [s[:3] for s in states], bucket, live, B)
+        elif B <= self._MATMUL_LIMIT:
+            # keep the rest of the tiny-B path scatter-free: one masked
+            # reduction a bucket (occupancy and every state)
             occupancy = jnp.stack([jnp.any(live & (bucket == b))
                                    for b in range(B)])
         else:
@@ -2545,25 +2579,14 @@ class _AggKernels:
                 out_cols.append(ColumnVector(c.dtype, data, kvalid))
             else:
                 out_cols.append(ColumnVector(c.dtype, code.astype(c.data.dtype), kvalid))
-        for ai, a in enumerate(self.aggs):
-            for (sname, sdt), (op, idx) in zip(a.fn.state_schema(), a.fn.update_ops()):
-                if idx >= 0:
-                    src = input_cols[ai][idx]
-                    if (src.is_string or src.is_nested) and \
-                            op not in ("count", "count_all"):
-                        raise NotImplementedError(
-                            "string agg state on device")
-                    vals = src.data \
-                        if not (src.is_string or src.is_nested) \
-                        else jnp.zeros(batch.capacity, sdt.np_dtype)
-                    vals = vals.astype(sdt.np_dtype) if vals.dtype != sdt.np_dtype else vals
-                    valid = live if src.validity is None else (src.validity & live)
-                else:
-                    vals = jnp.zeros(batch.capacity, sdt.np_dtype)
-                    valid = live
+        for i, (op, vals, valid, sdt) in enumerate(states):
+            if digit_ok:
+                ov, oval = outs[i]
+                ov = ov.astype(sdt.np_dtype)
+            else:
                 ov, oval = G.bucket_agg(op, vals, valid, bucket, B,
                                         matmul_ok=B <= self._MATMUL_LIMIT)
-                out_cols.append(ColumnVector(sdt, ov, oval))
+            out_cols.append(ColumnVector(sdt, ov, oval))
         n_groups = LazyRowCount(jnp.sum(occupancy.astype(jnp.int32)))
         return ColumnarBatch(out_cols, n_groups, occupancy)
 

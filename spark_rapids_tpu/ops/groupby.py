@@ -21,6 +21,7 @@ from spark_rapids_tpu.columnar.batch import (
     ColumnVector, ColumnarBatch, host_int, round_capacity,
 )
 from spark_rapids_tpu.ops import kernels as K
+from spark_rapids_tpu.ops import pallas_segsum as PS
 
 
 def group_segments(key_cols: List[ColumnVector], num_rows: int, live=None
@@ -121,13 +122,95 @@ def global_agg(op: str, values: jax.Array, valid: jax.Array
     raise ValueError(f"unknown global op {op}")
 
 
+#: state ops bucket_digit_agg reduces (a float `sum`; counts)
+DIGIT_OPS = frozenset({"sum", "count", "count_all"})
+
+
+def exact_split(values: jax.Array, valid: jax.Array, scale
+                ) -> Tuple[jax.Array, jax.Array]:
+    """(q, n) int32 planes with q * 2^24 + n == round(v * scale) for each
+    valid finite v (|v * scale| < 2^48, scale a power of two; 0 where
+    invalid), and q = PS.SPECIAL + flag bits where v is NaN (3), +inf (1)
+    or -inf (2). The high half comes from the value's nearest f32 and
+    f32 rounding; only the residual below 2^24 is rounded in 64-bit. Exact
+    on IEEE f64 and on the chip's f32-pair f64: s - q*2^24 is exact in
+    both."""
+    v = values.astype(jnp.float64)
+    finite = valid & jnp.isfinite(v)
+    s = jnp.where(finite, v, 0.0) * scale
+    q = jnp.round(s.astype(jnp.float32) * np.float32(2.0 ** -24))
+    n = jnp.round(s - q.astype(jnp.float64) * np.float64(2.0 ** 24))
+    flags = jnp.where(jnp.isnan(v), 3, jnp.where(v > 0, 1, 2))
+    q = jnp.where(valid & ~finite, PS.SPECIAL + flags, q.astype(jnp.int32))
+    return q, n.astype(jnp.int32)
+
+
+def bucket_digit_agg(states, bucket: jax.Array, live: jax.Array, B: int):
+    """Every float `sum`, `count` and `count_all` state of a tiny-bucket
+    aggregate in ONE pass (PS.bucket_digit_sums): each row adds the
+    one-hot of its bucket times its lanes, contracted on the MXU. A sum's
+    lanes are the exact integer digits of its finite values scaled to the
+    plane's max (47 bits below it, as the Pallas segsum path), plus two
+    lanes that count NaN or +inf and NaN or -inf rows: both seen means
+    NaN (a NaN, or both infinities), one alone its infinity. Counts are
+    0/1 lanes.
+
+    states: (op, values, valid) in order, `valid` already ANDed with
+    `live`. Planes are shared by identity (avg(x) beside sum(x) reads x's
+    digits once). Returns ([(value, validity)] per state, occupancy)."""
+    from spark_rapids_tpu.ops import radix as R
+    from spark_rapids_tpu.runtime import compile_cache as _cc
+    _cc.note_traced("bucket_digit_traced")
+    counts = [(live,)]   # distinct 0/1 planes; live (count_all) first
+    sums = []            # distinct (values, valid) pairs
+
+    def index(seen, planes):
+        for i, known in enumerate(seen):
+            if all(a is b for a, b in zip(known, planes)):
+                return i
+        seen.append(planes)
+        return len(seen) - 1
+
+    plan = [(index(counts, (live if op == "count_all" else valid,)),
+             index(sums, (values, valid)) if op == "sum" else None)
+            for op, values, valid in states]
+    scales = [R._exponent_scale(jnp.max(jnp.abs(jnp.where(
+        valid & jnp.isfinite(v), v.astype(jnp.float64), 0.0))))
+        * np.float64(2.0 ** 11) for v, valid in sums]
+    tot = PS.bucket_digit_sums(
+        bucket, [c.astype(jnp.int32) for c, in counts],
+        [exact_split(v, valid, scale)
+         for (v, valid), scale in zip(sums, scales)], B)     # [B, P]
+
+    out = []
+    for c, s in plan:
+        n = tot[:, c]
+        if s is None:
+            out.append((n, jnp.ones(B, jnp.bool_)))
+            continue
+        start = len(counts) + 8 * s
+        total = PS.digits_to_f64([tot[:, start + i]
+                                  for i in range(len(PS.SHIFTS))]) \
+            / scales[s]
+        pos, neg = tot[:, start + 6] > 0, tot[:, start + 7] > 0
+        total = jnp.where(pos, np.inf, total)
+        total = jnp.where(neg, -np.inf, total)
+        total = jnp.where(pos & neg, np.nan, total)
+        out.append((total, n > 0))
+    return out, tot[:, 0] > 0
+
+
 def bucket_agg(op: str, values: jax.Array, valid: jax.Array,
                bucket: jax.Array, B: int, matmul_ok: bool
                ) -> Tuple[jax.Array, jax.Array]:
-    """Segmented reduction into a DENSE bucket space with no sort: the MXU
-    answer to grouped aggregation (one-hot matmul for tiny B, bounded
-    scatter otherwise). values/valid/bucket are in original row order;
-    invalid rows route to the overflow bucket B and are dropped."""
+    """Segmented reduction into a DENSE bucket space with no sort: masked
+    reductions a bucket for tiny B, a bounded scatter otherwise.
+    values/valid/bucket are in original row order; invalid rows route to
+    the overflow bucket B and are dropped. A tiny-B aggregate of float
+    sums and counts alone takes bucket_digit_agg instead: here each
+    bucket is a full pass over the plane (on a v5e twelve masked f64 sums
+    over 33.5 M rows took 8.9 ms, where any one f64 op over the plane
+    took 2.5)."""
     vdt = values.dtype
     safe_bucket = jnp.where(valid, bucket, B)
     if op in ("count", "count_all"):
@@ -144,10 +227,11 @@ def bucket_agg(op: str, values: jax.Array, valid: jax.Array,
         v = jnp.where(valid, v, jnp.zeros_like(v))
         nvalid = bucket_agg("count", values, valid, bucket, B, matmul_ok)[0]
         if matmul_ok:
-            # Tiny bucket spaces: one masked tree-reduction per bucket.
-            # B full passes over the plane are bandwidth-cheap, keep full
-            # f64 precision (an MXU one-hot matmul accumulates f64 sums
-            # with ~1e-6 relative error on TPU), and need no scatter.
+            # Tiny bucket spaces: one masked tree-reduction per bucket,
+            # no scatter. A one-hot matmul over the DOUBLES would round
+            # them (~1e-6 relative on the chip); over exact integer
+            # digits it does not (bucket_digit_agg, for the aggregates
+            # whose every state it covers).
             out = jnp.stack([
                 jnp.sum(jnp.where(bucket == b, v, jnp.zeros_like(v)))
                 for b in range(B)])

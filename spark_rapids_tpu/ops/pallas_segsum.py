@@ -120,6 +120,115 @@ def segsum_window(gid: jax.Array, payload: jax.Array, outcap: int
     return jnp.where(lo_keep, lo, 0.0) + jnp.where(hi_keep, hi, 0.0)
 
 
+#: rows a grid step of bucket_digit_sums
+DIGIT_TILE = 8192
+#: rows a block of its output: a block's digit sums stay within 2^30
+#: (|digit| <= 2^8), exact in int32
+DIGIT_BLOCK_ROWS = 1 << 22
+#: a non-finite value's q plane: above every finite q (|q| <= 2^24);
+#: bit 0 marks +inf or NaN, bit 1 -inf or NaN
+SPECIAL = 1 << 26
+
+
+def signed_bytes(part: jax.Array) -> List[jax.Array]:
+    """int32 |part| <= 2^24 as three balanced base-2^8 digits, most
+    significant first: |d2| <= 2^8, |d1|, |d0| <= 2^7 (bf16-exact)."""
+    d0 = ((part + 128) & 255) - 128
+    part = (part - d0) >> 8
+    d1 = ((part + 128) & 255) - 128
+    return [(part - d1) >> 8, d1, d0]
+
+
+def _tile_sums(b, counts, splits, B: int, P: int) -> jax.Array:
+    """One tile's per-bucket lane sums, i32[B, P]: its rows' lanes (the
+    counts, then a sum's six balanced digits of q and n and its two flag
+    bits) and the one-hot of their buckets, contracted on the MXU (bf16
+    in, f32 out: a tile's sums reach its rows * 2^8, exact)."""
+    lanes = list(counts)
+    for q, n in splits:
+        special = q >= SPECIAL
+        lanes += signed_bytes(jnp.where(special, 0, q)) + signed_bytes(n)
+        lanes += [(special & ((q & 1) != 0)).astype(jnp.int32),
+                  (special & ((q & 2) != 0)).astype(jnp.int32)]
+    lanes += [jnp.zeros_like(b)] * (P - len(lanes))
+    pay = jnp.stack(lanes).astype(jnp.float32).astype(jnp.bfloat16)
+    onehot = (lax.broadcasted_iota(jnp.int32, (B, b.shape[0]), 0)
+              == b[None, :]).astype(jnp.float32).astype(jnp.bfloat16)
+    return lax.dot_general(onehot, pay, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32
+                           ).astype(jnp.int32)
+
+
+def _digit_kernel_factory(n_counts: int, n_sums: int, B: int, P: int):
+    from jax.experimental import pallas as pl
+
+    def kernel(bucket_ref, *refs):
+        *ins, out_ref = refs
+        vals = [r[...] for r in ins]
+        part = _tile_sums(bucket_ref[...], vals[:n_counts],
+                          [(vals[n_counts + 2 * s], vals[n_counts + 2 * s + 1])
+                           for s in range(n_sums)], B, P)
+
+        @pl.when(pl.program_id(1) == 0)
+        def _init():
+            out_ref[...] = part
+
+        @pl.when(pl.program_id(1) != 0)
+        def _accumulate():
+            out_ref[...] += part
+
+    return kernel
+
+
+def bucket_digit_sums(bucket: jax.Array, counts: List[jax.Array],
+                      splits: List[Tuple[jax.Array, jax.Array]], B: int
+                      ) -> jax.Array:
+    """Per-bucket sums of a tiny-bucket aggregate's lanes in one pass.
+
+    bucket i32[N] in [0, B); counts: 0/1 i32[N] planes; splits: each
+    sum's (q, n) i32[N] planes, value = q * 2^24 + n (or q = SPECIAL +
+    its flag bits). A grid step forms its rows' lanes and one-hot in VMEM
+    and contracts them on the MXU (`_tile_sums`); a block's steps add in
+    int32. Returns int64[B, P]: the counts' sums, then eight lanes a sum
+    (digits weighted 2^SHIFTS, then the two flag counts)."""
+    from jax.experimental import pallas as pl
+    from spark_rapids_tpu.ops.pallas_kernels import _interpret, _x64_off
+    planes = [bucket] + list(counts) + [p for qn in splits for p in qn]
+    n = bucket.shape[0]
+    tile = min(DIGIT_TILE, -(-n // 1024) * 1024)
+    block = min(DIGIT_BLOCK_ROWS, -(-n // tile) * tile)
+    pad = -n % block
+    planes = [(jnp.pad(p, (0, pad)) if pad else p).astype(jnp.int32)
+              for p in planes]
+    steps, nb = block // tile, (n + pad) // block
+    Bp = -(-B // 16) * 16
+    P = -(-(len(counts) + 8 * len(splits)) // 16) * 16
+    nc = len(counts)
+    if _interpret() and jax.typeof(bucket).vma:
+        # the interpreter would run the body on one shard's varying values
+        # beside unvarying constants, which shard_map refuses: the same
+        # tiles through XLA (Mosaic strips the mesh axes and needs none)
+        tiles = [p.reshape(-1, tile) for p in planes]
+        parts = jax.vmap(lambda b, *v: _tile_sums(
+            b, v[:nc], list(zip(v[nc::2], v[nc + 1::2])), Bp, P))(*tiles)
+        out = jnp.sum(parts.reshape(nb, steps, Bp, P), axis=1)
+    else:
+        with _x64_off():
+            out = pl.pallas_call(
+                _digit_kernel_factory(nc, len(splits), Bp, P),
+                grid=(nb, steps),
+                in_specs=[pl.BlockSpec((tile,),
+                                       lambda i, j: (i * steps + j,))
+                          for _ in planes],
+                out_specs=pl.BlockSpec((None, Bp, P), lambda i, j: (i, 0, 0)),
+                # under shard_map the sums vary over the mesh as rows do
+                out_shape=jax.ShapeDtypeStruct((nb, Bp, P), jnp.int32,
+                                               vma=jax.typeof(bucket).vma),
+                interpret=_interpret(),
+            )(*planes)
+    return jnp.sum(out.astype(jnp.int64), axis=0)[:B]
+
+
 def float_digits(clean: jax.Array, scale) -> List[jax.Array]:
     """8-bit balanced digit planes of round(clean*scale) (f32 each)."""
     s = jnp.round(clean * scale)

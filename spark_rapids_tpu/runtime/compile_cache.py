@@ -98,6 +98,9 @@ _STATS = {
     # over row positions (expr/strings.Like: a `_` in the pattern)
     "like_plane_traced": 0,
     "like_nfa_traced": 0,
+    # a tiny-bucket aggregate's float sums and counts contracted as exact
+    # integer digits on the MXU in one pass (ops/groupby.bucket_digit_agg)
+    "bucket_digit_traced": 0,
 }
 
 #: set while a fresh entry's first call runs on this thread: the
@@ -471,4 +474,5 @@ def doc() -> Dict[str, object]:
         "persistent_misses": s["persistent_misses"],
         "vocab_predicates_traced": s["vocab_predicates_traced"],
         "dict_flattens_traced": s["dict_flattens_traced"],
+        "bucket_digit_traced": s["bucket_digit_traced"],
     }
