@@ -577,8 +577,8 @@ def _exec_aggregate(plan: P.Aggregate, child: List[CpuCol], ansi: bool) -> List[
 
 def _agg_by_gid(a: NamedAgg, inp, gid: np.ndarray,
                 n_groups: int) -> CpuCol:
-    from spark_rapids_tpu.expr.aggregates import SegmentedAgg
-    if isinstance(a.fn, SegmentedAgg):
+    from spark_rapids_tpu.expr.aggregates import MergedState, SegmentedAgg
+    if isinstance(a.fn, (SegmentedAgg, MergedState)):
         return a.fn.eval_cpu_groups(inp, gid, n_groups)
     spec = a.fn.pandas_spec()
     rt = a.fn.result_type()
@@ -705,11 +705,11 @@ def _agg_float_np(spec, rt, inp: CpuCol, gid: np.ndarray, n_groups: int) -> CpuC
 
 
 def _global_agg(plan: P.Aggregate, agg_inputs, n: int) -> List[CpuCol]:
+    from spark_rapids_tpu.expr.aggregates import MergedState, SegmentedAgg
     out = []
     gid = np.zeros(max(n, 0), np.int64)
     for a, inp in zip(plan.aggs, agg_inputs):
-        if n == 0:
-            from spark_rapids_tpu.expr.aggregates import SegmentedAgg
+        if n == 0 and not isinstance(a.fn, MergedState):
             rt = a.fn.result_type()
             if isinstance(a.fn, SegmentedAgg):
                 if isinstance(rt, T.ArrayType):  # collect_* of empty = []

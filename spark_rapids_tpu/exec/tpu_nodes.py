@@ -1761,6 +1761,15 @@ def _probe_pack_spec(key_cols, live, key_exprs=None):
     return R.plan_packing(key_cols, ranges_host), ranges, ranges_host
 
 
+def _with_inputs(fn, refs):
+    """Aggregate `fn` (a distinct level's: a plain aggregate or a
+    PartialState of one) over the inputs `refs`."""
+    from spark_rapids_tpu.expr.aggregates import PartialState
+    if isinstance(fn, PartialState):
+        return PartialState(_with_inputs(fn.fn, refs), fn.j)
+    return type(fn)(*refs)
+
+
 class _AggKernels:
     """Aggregation kernel builders holding ONLY expression-level state.
 
@@ -1783,6 +1792,7 @@ class _AggKernels:
         self.aggs = aggs
         self.pre_filter = pre_filter
         self._packed_ok = self._packed_static_ok()
+        self._over = None   # over_inputs(), built once
 
     def _fp(self):
         return (tuple(e.fingerprint() for e in self.group_exprs),
@@ -1861,19 +1871,8 @@ class _AggKernels:
 
     def _build_packed_update(self, ansi: bool, spec):
         def fn(batch, ranges):
+            batch, errs = self._filtered(batch, ansi)
             live = batch.live_mask()
-            errs = {}
-            if self.pre_filter is not None:
-                pctx = EvalCtx(batch.columns, traced_rows(batch.num_rows),
-                               batch.capacity, ansi, live=live)
-                pred = self.pre_filter.eval_tpu(pctx)
-                live = live & pred.data.astype(jnp.bool_)
-                if pred.validity is not None:
-                    live = live & pred.validity
-                batch = ColumnarBatch(
-                    batch.columns,
-                    LazyRowCount(jnp.sum(live.astype(jnp.int32))), live)
-                errs.update(pctx.errors)
             ectx = EvalCtx(batch.columns, traced_rows(batch.num_rows),
                            batch.capacity, ansi, live=live)
             nkeys = len(self.group_exprs)
@@ -2343,29 +2342,71 @@ class _AggKernels:
         from spark_rapids_tpu.expr.aggregates import SegmentedAgg
         return any(isinstance(a.fn, SegmentedAgg) for a in self.aggs)
 
+    def _filtered(self, batch: ColumnarBatch, ansi: bool):
+        """(batch masked by the absorbed filter, its errors), traced."""
+        if self.pre_filter is None:
+            return batch, {}
+        live = batch.live_mask()
+        pctx = EvalCtx(batch.columns, traced_rows(batch.num_rows),
+                       batch.capacity, ansi, live=live)
+        pred = self.pre_filter.eval_tpu(pctx)
+        live = live & pred.data.astype(jnp.bool_)
+        if pred.validity is not None:
+            live = live & pred.validity
+        return ColumnarBatch(batch.columns,
+                             LazyRowCount(jnp.sum(live.astype(jnp.int32))),
+                             live), dict(pctx.errors)
+
     def _build_update(self, ansi: bool):
         """Build the fused update phase: expression eval + sort-group +
         segmented reductions as ONE traced computation over batch pytrees."""
         def fn(batch):
-            live = batch.live_mask()
-            errs = {}
-            if self.pre_filter is not None:
-                pctx = EvalCtx(batch.columns, traced_rows(batch.num_rows),
-                               batch.capacity, ansi, live=live)
-                pred = self.pre_filter.eval_tpu(pctx)
-                live = live & pred.data.astype(jnp.bool_)
-                if pred.validity is not None:
-                    live = live & pred.validity
-                batch = ColumnarBatch(
-                    batch.columns,
-                    LazyRowCount(jnp.sum(live.astype(jnp.int32))), live)
-                errs.update(pctx.errors)
+            batch, errs = self._filtered(batch, ansi)
             ectx = EvalCtx(batch.columns, traced_rows(batch.num_rows),
-                           batch.capacity, ansi, live=live)
+                           batch.capacity, ansi, live=batch.live_mask())
             out = self._update_batch(batch, ectx)
             errs.update(ectx.errors)
             return out, errs
         return fn
+
+    # -- the distinct level's input (plan/distinct.py) ----------------------
+
+    def _inputs(self) -> dict:
+        """{fingerprint: expression} of the keys and aggregate inputs,
+        each once (avg(x) beside count(x) reads one x)."""
+        out = {}
+        for e in self._state_input_exprs():
+            out.setdefault(e.fingerprint(), e)
+        return out
+
+    def project_inputs(self, batch: ColumnarBatch, ansi: bool):
+        """The absorbed filter, then every key and aggregate input, over
+        `batch` as one program: (a batch of those columns alone, masked
+        by the filter; errors). `over_inputs` aggregates it."""
+        def build():
+            def fn(b):
+                b, errs = self._filtered(b, ansi)
+                ectx = EvalCtx(b.columns, traced_rows(b.num_rows),
+                               b.capacity, ansi, live=b.live_mask())
+                cols = [e.eval_tpu(ectx) for e in self._inputs().values()]
+                errs.update(ectx.errors)
+                return ColumnarBatch(cols, b.num_rows, b.live_mask()), errs
+            return fn
+        return fuse.fused(("hashagg_inputs", self._fp(), ansi), build)(batch)
+
+    def over_inputs(self) -> "_AggKernels":
+        """These kernels over `project_inputs`' columns: keys and inputs
+        become column references, and no filter is left."""
+        if self._over is None:
+            ref = {fp: BoundRef(i, e.data_type(), f"in{i}")
+                   for i, (fp, e) in enumerate(self._inputs().items())}
+            aggs = [P.NamedAgg(_with_inputs(a.fn, [
+                ref[c.fingerprint()] for c in a.fn.children]), a.name)
+                for a in self.aggs]
+            self._over = _AggKernels(
+                [ref[e.fingerprint()] for e in self.group_exprs],
+                self.group_names, aggs, None)
+        return self._over
 
     def _update_batch(self, batch: ColumnarBatch, ectx) -> ColumnarBatch:
         from spark_rapids_tpu.expr.aggregates import SegmentedAgg
@@ -3332,7 +3373,9 @@ class HashAggregateExec(TpuExec):
         return outs
 
     def execute_partition(self, ctx, pidx):
-        agg_t = self.metrics.metric(M.AGG_TIME)
+        agg_t = self.metrics.metric(M.DISTINCT_AGG_TIME
+                                    if self.plan.distinct_level
+                                    else M.AGG_TIME)
         if self.shard_over:
             states = self._sharded_states(ctx)
             if states is not None:
@@ -3366,6 +3409,9 @@ class HashAggregateExec(TpuExec):
                     compiled.raise_errors(errs)
                     carry_host_stats(b.columns, kept.columns)
                     b, kern = kept, self._kern_apart
+                if self.plan.distinct_level:
+                    b, kern = self._distinct_inputs(b, kern, ansi), \
+                        kern.over_inputs()
                 out, errs = kern.update(b, ansi)
                 compiled.raise_errors(errs)
                 return out
@@ -3541,22 +3587,44 @@ class HashAggregateExec(TpuExec):
                 if self.mode != "partial":
                     merged = self._evaluate(merged)
             self._mark_device(merged, t0)
+            if self.plan.distinct_level and self.mode != "partial":
+                # the (keys, value) groups, compacted: the merge level
+                # groups over them alone, and the count is the host's
+                merged = K.compact_batch(merged)
+                self.metrics.metric(M.DISTINCT_KEYS).add(merged.num_rows)
             out_rows.add(merged.num_rows)
             out_batches.add(1)
             yield merged
 
     # -- phase helpers -----------------------------------------------------
 
+    def _distinct_inputs(self, batch: ColumnarBatch, kern, ansi: bool
+                         ) -> ColumnarBatch:
+        """The distinct level's input: the filter and the keys and inputs
+        as one program, its live rows compacted (one count sync sizes
+        them) so that the group-by sorts those rows and not the batch's
+        capacity."""
+        t0 = time.perf_counter_ns()
+        masked, errs = kern.project_inputs(batch, ansi)
+        compiled.raise_errors(errs)
+        if isinstance(masked.num_rows, LazyRowCount):
+            device_mark(self.metrics.metric(M.DISTINCT_AGG_DEVICE_TIME),
+                        masked.num_rows.traced(), t0)
+        small = K.compact_batch(masked)
+        self.metrics.metric(M.DISTINCT_INPUT_ROWS).add(small.num_rows)
+        return small
+
     def _mark_device(self, out: ColumnarBatch, since_ns: int) -> None:
-        """aggDeviceTime: the device's time for the programs enqueued
-        since `since_ns` whose last one computes `out`'s last state (or
-        result) plane, read at the next read-back that exists; a plane
-        that a program only hands through is ready at once and adds
-        nothing."""
+        """aggDeviceTime (distinctAggDeviceTime on a distinct level): the
+        device's time for the programs enqueued since `since_ns` whose
+        last one computes `out`'s last state (or result) plane, read at
+        the next read-back that exists; a plane that a program only hands
+        through is ready at once and adds nothing."""
         data = out.columns[-1].data if out.columns else None
         if isinstance(data, jax.Array):
-            device_mark(self.metrics.metric(M.AGG_DEVICE_TIME), data,
-                        since_ns)
+            device_mark(self.metrics.metric(
+                M.DISTINCT_AGG_DEVICE_TIME if self.plan.distinct_level
+                else M.AGG_DEVICE_TIME), data, since_ns)
 
     def _merge(self, partials: List[ColumnarBatch]) -> ColumnarBatch:
         if len(partials) == 1 and not getattr(partials[0], "coalesced",

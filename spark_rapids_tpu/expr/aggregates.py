@@ -30,6 +30,11 @@ from spark_rapids_tpu.expr.core import CpuCol, Expression, SparkException
 class AggFunction:
     """Base; children are input expressions evaluated before aggregation."""
 
+    #: count/sum/avg(DISTINCT e) (`distinct_of`): part of the aggregate's
+    #: identity. No kernel sees it set: the planner takes every distinct
+    #: aggregate apart into two levels (plan/distinct.py)
+    distinct = False
+
     def __init__(self, *children: Expression):
         self.children = list(children)
 
@@ -57,7 +62,7 @@ class AggFunction:
 
     def fingerprint(self) -> str:
         kids = ",".join(c.fingerprint() for c in self.children)
-        return f"{type(self).__name__}({kids})"
+        return f"{type(self).__name__}({'DISTINCT ' * self.distinct}{kids})"
 
     def over(self, spec):
         """agg OVER window-spec -> WindowExpr (pyspark F.sum(c).over(w))."""
@@ -66,6 +71,7 @@ class AggFunction:
 
     def transform(self, fn):
         clone = type(self)(*[c.transform(fn) for c in self.children])
+        clone.distinct = self.distinct
         return clone
 
     def alias(self, name):
@@ -339,6 +345,125 @@ class StddevPop(_MomentAgg):
 
     def pandas_spec(self):
         return ("std", 0)
+
+
+def distinct_of(fn: AggFunction) -> AggFunction:
+    """`fn` over the distinct values of its one input: count, sum and avg
+    (Spark's count/sum/avg(DISTINCT e))."""
+    if not isinstance(fn, (Count, Sum, Average)) or len(fn.children) != 1:
+        raise SparkException(
+            f"DISTINCT inside {type(fn).__name__.lower()}() is not "
+            "supported")
+    out = fn.transform(lambda e: e)
+    out.distinct = True
+    return out
+
+
+#: the CPU path's pandas reduction for a segmented op
+_PANDAS_OF_OP = {"sum": "sum", "count": "count", "count_all": "size",
+                 "min": "min", "max": "max", "first": "first",
+                 "last": "last"}
+
+
+class PartialState(AggFunction):
+    """State column `j` of `fn`, updated from `fn`'s inputs and handed on
+    as it is (Spark's Partial mode): how the distinct level carries the
+    other aggregates' states up to the level that finishes them."""
+
+    def __init__(self, fn: AggFunction, j: int):
+        super().__init__(*fn.children)
+        self.fn, self.j = fn, j
+
+    def fingerprint(self):
+        return f"PartialState[{self.j}]({self.fn.fingerprint()})"
+
+    def transform(self, f):
+        return PartialState(self.fn.transform(f), self.j)
+
+    def result_type(self):
+        return self.fn.state_schema()[self.j][1]
+
+    def state_schema(self):
+        return [self.fn.state_schema()[self.j]]
+
+    def update_ops(self):
+        return [self.fn.update_ops()[self.j]]
+
+    def merge_ops(self):
+        return [self.fn.merge_ops()[self.j]]
+
+    def evaluate_tpu(self, state_cols, n_groups):
+        return state_cols[0]
+
+    def pandas_spec(self):
+        op = self.update_ops()[0][0]
+        if op not in _PANDAS_OF_OP:
+            raise NotImplementedError(
+                f"{type(self.fn).__name__}'s {op} state runs on the device "
+                "only")
+        return _PANDAS_OF_OP[op]
+
+
+class MergedState(AggFunction):
+    """`fn` finished from the states a lower level handed on (Spark's
+    PartialMerge, then Final): each child reads one state column, merged
+    by `fn`'s own merge op, and `fn` evaluates the result. avg's (sum,
+    count) pair is merged, never recomputed."""
+
+    def __init__(self, fn: AggFunction, *states: Expression):
+        super().__init__(*states)
+        self.fn = fn
+
+    def fingerprint(self):
+        kids = ",".join(c.fingerprint() for c in self.children)
+        return f"MergedState({self.fn.fingerprint()};{kids})"
+
+    def transform(self, f):
+        # `fn`'s own children belong to the level below: only the state
+        # references are this level's
+        return MergedState(self.fn, *[c.transform(f) for c in self.children])
+
+    def result_type(self):
+        return self.fn.result_type()
+
+    def state_schema(self):
+        return self.fn.state_schema()
+
+    def update_ops(self):
+        return [(op, j) for j, op in enumerate(self.fn.merge_ops())]
+
+    def merge_ops(self):
+        return self.fn.merge_ops()
+
+    def evaluate_tpu(self, state_cols, n_groups):
+        return self.fn.evaluate_tpu(state_cols, n_groups)
+
+    def eval_cpu_groups(self, inputs, gid, n_groups):
+        """The CPU path: each state merged by the pandas reduction of its
+        merge op, then `fn`'s own evaluation of the merged states."""
+        from spark_rapids_tpu.exec.cpu_backend import _agg_by_gid
+        from spark_rapids_tpu.expr.core import BoundRef
+        states = []
+        for j, ((_, sdt), op) in enumerate(zip(self.fn.state_schema(),
+                                               self.fn.merge_ops())):
+            merge = Sum if op == "sum" else {"min": Min, "max": Max,
+                                             "first": First,
+                                             "last": Last}[op]
+            if not len(gid):  # no rows: every state empty
+                states.append(CpuCol(sdt, np.zeros(n_groups, sdt.np_dtype),
+                                     np.zeros(n_groups, np.bool_)))
+                continue
+            fn = merge(BoundRef(0, inputs[j].dtype, "s"))
+            states.append(_agg_by_gid(NamedAgg(fn, "s"), [inputs[j]], gid,
+                                      n_groups))
+        res = self.fn.evaluate_tpu(
+            [ColumnVector(sdt, jnp.asarray(c.values.astype(sdt.np_dtype)),
+                          jnp.asarray(c.valid))
+             for c, (_, sdt) in zip(states, self.fn.state_schema())],
+            n_groups)
+        valid = np.ones(n_groups, np.bool_) if res.validity is None \
+            else np.asarray(res.validity)
+        return CpuCol(self.result_type(), np.asarray(res.data), valid)
 
 
 # ---------------------------------------------------------------------------

@@ -277,6 +277,10 @@ class WindowExpr(Expression):
 
 def over(fn_or_agg, spec: WindowSpec) -> WindowExpr:
     if isinstance(fn_or_agg, AggFunction):
+        if fn_or_agg.distinct:
+            from spark_rapids_tpu.expr.core import SparkException
+            raise SparkException(
+                "distinct window functions are not supported")
         fn_or_agg = WindowAgg(fn_or_agg)
     if not isinstance(fn_or_agg, WindowFunction):
         raise TypeError(f"not a window function: {fn_or_agg!r}")

@@ -18,7 +18,7 @@ from typing import List, Optional, Sequence, Tuple
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.expr.core import (
-    Alias, BoundRef, Col, Expression, Literal,
+    Alias, BoundRef, Col, Expression, Literal, SparkException,
 )
 from spark_rapids_tpu.expr.aggregates import AggFunction, NamedAgg
 
@@ -418,6 +418,10 @@ class Aggregate(PlanNode):
 
     def __init__(self, group_exprs: List[Expression], aggs: List[NamedAgg],
                  child: PlanNode):
+        if any(a.fn.distinct for a in aggs):
+            raise SparkException(
+                "distinct aggregates are planned by "
+                "plan.distinct.plan_aggregate, not as one Aggregate")
         self.children = [child]
         self.raw_group_exprs = group_exprs
         self.group_exprs = [bind_expr(e, child.schema) for e in group_exprs]
@@ -433,6 +437,9 @@ class Aggregate(PlanNode):
 
     #: what the planner did to this node, shown after its description
     note = ""
+    #: the lower level of a distinct aggregate (plan/distinct.py): keys
+    #: plus the distinct value
+    distinct_level = False
 
     def describe(self):
         return (f"Aggregate[keys=[{', '.join(self.group_names)}], "

@@ -730,6 +730,11 @@ def tag_expression(e: E.Expression, conf, reasons: List[str], where: str) -> Non
 
 
 def tag_agg(fn: A.AggFunction, conf, reasons: List[str], where: str) -> None:
+    if isinstance(fn, (A.PartialState, A.MergedState)):
+        # a level of a distinct aggregate runs where the aggregate it
+        # carries would; a merge level's own inputs are state columns
+        tag_agg(fn.fn, conf, reasons, where)
+        return
     rule = AGG_RULES.get(type(fn))
     if rule is None:
         reasons.append(f"{where}: aggregate {type(fn).__name__} is not supported on TPU")

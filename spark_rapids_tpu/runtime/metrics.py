@@ -69,6 +69,18 @@ WINDOW_SORT_DEVICE_TIME = "windowSortDeviceTime"
 #: unmarked program its child enqueued just before them (device_mark); and
 #: over a HashAggregateExec's update a batch, then its merge and evaluate
 AGG_DEVICE_TIME = "aggDeviceTime"
+#: ns the distinct level of a distinct aggregate (plan/distinct.py) spent
+#: issuing its input program, the compaction its count sizes (the wait for
+#: that count included) and its group-by; inside opTime, in place of
+#: aggTime
+DISTINCT_AGG_TIME = "distinctAggTime"
+#: ns the DEVICE took over those programs (device_mark, at the compaction's
+#: count and the read-back after the group-by), in place of aggDeviceTime
+DISTINCT_AGG_DEVICE_TIME = "distinctAggDeviceTime"
+#: live rows the distinct level took in, and the (keys, value) groups it
+#: put out, from the counts its compactions read
+DISTINCT_INPUT_ROWS = "distinctInputRows"
+DISTINCT_KEYS = "distinctKeys"
 #: ns the DEVICE took over a mask-through inner, semi or anti join's
 #: probe: the cut to the build keys' span and its compaction where taken,
 #: the look-up and the gathers of the build's columns (device_mark);

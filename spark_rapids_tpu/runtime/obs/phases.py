@@ -64,7 +64,13 @@ the epilogue the account becomes one record in the obs ring
                               the host has), host_syncs (the syncs
                               below, counted), pipeline_stalls
                               (pipelineStalls: times a scan pipeline's
-                              consumer found its queue empty and waited)
+                              consumer found its queue empty and waited),
+                              distinct_input_rows, distinct_keys
+                              (distinctInputRows, distinctKeys: live
+                              rows the distinct levels of the query's
+                              distinct aggregates took in, and the
+                              (keys, value) groups they put out, from
+                              the counts their compactions read)
     syncs                     {site: [count, ns]}: each door of SYNC_SITES
                               the query's threads waited at, how often and
                               how long (only the sites that synced)
@@ -87,7 +93,7 @@ sum of the record's `syncs` counts and `timers_ns.deviceWaitTime` the sum
 of their nanoseconds, summed over threads.
 
 `timers_ns.*DeviceTime` (joinDeviceTime, aggDeviceTime,
-windowSortDeviceTime, stringMatchDeviceTime) are the DEVICE's time for a step whose span times
+windowSortDeviceTime, stringMatchDeviceTime, distinctAggDeviceTime) are the DEVICE's time for a step whose span times
 only its enqueue (`device_mark()`): the step names one of its outputs, and
 the next `device_wait()` of the thread, before its own wait, waits for
 each named output in turn and stamps the host's clock. A mark's time runs
@@ -107,7 +113,8 @@ import time
 from typing import Dict, Optional
 
 from spark_rapids_tpu.runtime.metrics import (
-    AGG_GROUPS, ESSENTIAL, EXCHANGE_BYPASSED, EXPAND_ROWS, JOIN_OUTPUT_ROWS, MESH_PUT_BYTES,
+    AGG_GROUPS, DISTINCT_INPUT_ROWS, DISTINCT_KEYS, ESSENTIAL, EXCHANGE_BYPASSED, EXPAND_ROWS,
+    JOIN_OUTPUT_ROWS, MESH_PUT_BYTES,
     NUM_SCAN_COLUMNS, NUM_SCAN_COLUMNS_PRUNED, PIPELINE_STALLS, SHARD_WAVES, STRING_MATCH_BYTES,
     UPLOAD_BYTES, GpuMetric, _Timer, walk_exec_tree,
 )
@@ -121,7 +128,9 @@ COUNTERS = {"upload_bytes": UPLOAD_BYTES, "shard_waves": SHARD_WAVES,
             "expand_rows": EXPAND_ROWS,
             "string_match_bytes": STRING_MATCH_BYTES,
             "join_output_rows": JOIN_OUTPUT_ROWS,
-            "pipeline_stalls": PIPELINE_STALLS}
+            "pipeline_stalls": PIPELINE_STALLS,
+            "distinct_input_rows": DISTINCT_INPUT_ROWS,
+            "distinct_keys": DISTINCT_KEYS}
 
 #: phase -> the span that times it
 SPANS = {"parse": "sql.parse", "admit": "query.admit",

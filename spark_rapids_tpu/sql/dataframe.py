@@ -9,6 +9,7 @@ from spark_rapids_tpu import config as C
 from spark_rapids_tpu.expr import core as E
 from spark_rapids_tpu.expr.aggregates import AggFunction, NamedAgg
 from spark_rapids_tpu.plan import nodes as P
+from spark_rapids_tpu.plan.distinct import plan_aggregate
 from spark_rapids_tpu import types as T
 
 
@@ -757,7 +758,7 @@ class GroupedData:
                 raise TypeError(f"not an aggregate: {a!r}")
         if self.grouping_sets is not None:
             return self._agg_grouping_sets(named)
-        return DataFrame(P.Aggregate(self.keys, named, self.df.plan),
+        return DataFrame(plan_aggregate(self.keys, named, self.df.plan),
                          self.df.session)
 
     def _agg_grouping_sets(self, named: List[NamedAgg]) -> DataFrame:
@@ -823,8 +824,8 @@ class GroupedData:
                 real.append(na)
                 post.append(E.col(na.name))
         grouped = DataFrame(
-            P.Aggregate([E.col(n) for n in gk] + [E.col("__grouping_id")],
-                        real, expanded.plan), df.session)
+            plan_aggregate([E.col(n) for n in gk] + [E.col("__grouping_id")],
+                           real, expanded.plan), df.session)
         out_keys = [E.Alias(E.col(gk[j]), P.expr_name(keys[j], j))
                     for j in range(nk)]
         return grouped.select(*out_keys, *post)

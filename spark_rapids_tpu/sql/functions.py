@@ -48,6 +48,19 @@ def avg(c):
 mean = avg
 
 
+def count_distinct(c):
+    """count(DISTINCT c): planned as Spark's one-distinct aggregation."""
+    return A.distinct_of(A.Count(_e(c)))
+
+
+def sum_distinct(c):
+    """sum(DISTINCT c)."""
+    return A.distinct_of(A.Sum(_e(c)))
+
+
+countDistinct, sumDistinct = count_distinct, sum_distinct
+
+
 def min(c):  # noqa: A001
     return A.Min(_e(c))
 

@@ -321,13 +321,17 @@ class _Parser:
             while self.op(","):
                 args.append(self._scalar_or_expr())
             self.expect_op(")")
-        if distinct:
-            raise SparkException(
-                f"SQL: DISTINCT inside {name}() is not supported")
         fn = getattr(F, name.lower(), None)
         if fn is None or not callable(fn):
             raise SparkException(f"SQL: unknown function {name!r}")
+        if distinct and (name.lower() not in ("count", "sum", "avg", "mean")
+                         or len(args) != 1):
+            raise SparkException(
+                f"SQL: DISTINCT inside {name}() is not supported")
         out = fn(*args)
+        if distinct:
+            from spark_rapids_tpu.expr.aggregates import distinct_of
+            out = distinct_of(out)
         if self.kw("over"):
             out = self._over(out)
         return out

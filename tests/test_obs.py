@@ -678,7 +678,9 @@ def test_recent_queries_one_record_per_top_level_action():
                                       "expand_rows", "agg_groups",
                                       "string_match_bytes",
                                       "join_output_rows", "host_syncs",
-                                      "pipeline_stalls"}
+                                      "pipeline_stalls",
+                                      "distinct_input_rows",
+                                      "distinct_keys"}
         assert r["counters"]["scan_columns_read"] == 0  # no Parquet scan
         assert "mesh" not in r  # no sharded stage ran
         assert r["counters"]["upload_bytes"] > 0  # the in-memory scan's

@@ -1100,18 +1100,34 @@ def q27(s, d):
             .limit(100))
 
 
+#: query 28's bands: quantity from, to; list price, coupon, wholesale cost
+_Q28_BANDS = ((0, 5, 8, 459, 57), (6, 10, 90, 2323, 31),
+              (11, 15, 142, 12214, 79), (16, 20, 135, 6071, 38),
+              (21, 25, 122, 836, 17), (26, 30, 154, 7326, 7))
+
+
 def q28(s, d):
-    """six list-price-bucket stats in one conditional-agg pass."""
-    aggs = []
-    for i, (lo, hi) in enumerate([(0, 50), (51, 100), (101, 150),
-                                  (151, 200), (201, 250), (251, 300)], 1):
-        cond = (col("ss_list_price") >= lit(float(lo))) & \
-            (col("ss_list_price") <= lit(float(hi)))
-        aggs.append(F.avg(F.when(cond, col("ss_list_price")))
-                    .alias(f"b{i}_lp"))
-        aggs.append(F.count(F.when(cond, col("ss_list_price")))
-                    .alias(f"b{i}_cnt"))
-    return d["store_sales"].agg(*aggs)
+    """six quantity bands, each kept by an OR of three price ranges with
+    the average, count and distinct count of its list prices, cross
+    joined into one row."""
+    def within(name, lo, width):
+        return (col(name) >= lit(float(lo))) & \
+            (col(name) <= lit(float(lo + width)))
+
+    out = None
+    for i, (qlo, qhi, lp, cp, wc) in enumerate(_Q28_BANDS, 1):
+        band = (d["store_sales"]
+                .filter((col("ss_quantity") >= lit(qlo))
+                        & (col("ss_quantity") <= lit(qhi))
+                        & (within("ss_list_price", lp, 10)
+                           | within("ss_coupon_amt", cp, 1000)
+                           | within("ss_wholesale_cost", wc, 20)))
+                .agg(F.avg(col("ss_list_price")).alias(f"b{i}_lp"),
+                     F.count(col("ss_list_price")).alias(f"b{i}_cnt"),
+                     F.count_distinct(col("ss_list_price"))
+                     .alias(f"b{i}_cntd")))
+        out = band if out is None else out.join(band, how="cross")
+    return out.limit(100)
 
 
 def q29(s, d):
