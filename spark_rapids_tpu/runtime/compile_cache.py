@@ -101,6 +101,8 @@ _STATS = {
     # a tiny-bucket aggregate's float sums and counts contracted as exact
     # integer digits on the MXU in one pass (ops/groupby.bucket_digit_agg)
     "bucket_digit_traced": 0,
+    # live rows' positions found 32 rows a word (ops/kernels._compact_indices)
+    "compact_words_traced": 0,
 }
 
 #: set while a fresh entry's first call runs on this thread: the
@@ -475,4 +477,5 @@ def doc() -> Dict[str, object]:
         "vocab_predicates_traced": s["vocab_predicates_traced"],
         "dict_flattens_traced": s["dict_flattens_traced"],
         "bucket_digit_traced": s["bucket_digit_traced"],
+        "compact_words_traced": s["compact_words_traced"],
     }
